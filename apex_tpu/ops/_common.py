@@ -46,13 +46,9 @@ def mix_seed(seed, n):
 
 
 def _vma_of(x):
-    """The varying-axes set of a value, or empty on JAX versions without
-    ``jax.typeof``/vma tracking (pre-0.6 releases: shard_map there has no
-    vma checking, so "varies over no axes" is the correct answer)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return frozenset(getattr(typeof(x), "vma", ()))
+    """The varying-axes set of a value (empty outside ``shard_map`` and
+    under ``check_vma=False``)."""
+    return frozenset(jax.typeof(x).vma)
 
 
 def use_jnp_fallback(*arrays) -> bool:
@@ -90,7 +86,4 @@ def out_struct(shape, dtype, *like):
     vma = frozenset()
     for r in like:
         vma |= _vma_of(r)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # older jax without the vma kwarg
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

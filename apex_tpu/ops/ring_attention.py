@@ -103,7 +103,7 @@ def ring_attention(q, k, v, key_mask=None, causal: bool = False,
     # (plus whatever axes q/k/v already vary over)
     vma = frozenset({axis_name})
     for ref in (q, k, v):
-        vma |= frozenset(getattr(jax.typeof(ref), "vma", None) or ())
+        vma |= jax.typeof(ref).vma
     mark = tuple(vma)
 
     if key_mask is None:

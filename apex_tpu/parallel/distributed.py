@@ -27,15 +27,21 @@ buckets. The knobs keep their reference meaning:
 - ``num_allreduce_streams``: accepted for parity; XLA schedules collective
   streams itself.
 
-shard_map autodiff note: differentiating wrt a *replicated* (``P()``)
-param pytree inside ``shard_map`` already yields the cross-device SUM of
-per-device gradients — the transpose of the implicit broadcast is a psum
-inserted by autodiff. Such gradients are "unvarying" over the mesh axis
-(empty ``vma``); psum-ing them again would multiply by the world size.
-``allreduce_grads`` therefore inspects each bucket's varying-axes set and
-reduces only device-varying data, then applies the averaging divisor
-either way — so it is correct both for autodiff-produced grads and for
-manually assembled per-device values.
+shard_map autodiff note: under ``jax.shard_map(check_vma=True)``,
+differentiating wrt a *replicated* (``P()``) param pytree already yields
+the cross-device SUM of per-device gradients — the transpose of the
+implicit broadcast is a psum inserted by autodiff. Such gradients are
+"unvarying" over the mesh axis (empty ``vma``); psum-ing them again would
+multiply by the world size. Under ``check_vma=False`` (every in-repo
+``shard_map``, see :func:`apex_tpu.utils.collectives.compat_shard_map`)
+no varying-axes set is tracked, autodiff inserts no psum, and an empty
+``vma`` says nothing: the same gradient is device-local. So
+``allreduce_grads`` first asks whether the axis is tracked at all
+(:func:`~apex_tpu.utils.collectives.vma_tracked`). Where it is, it reduces
+only device-varying leaves; where it is not, it reduces every leaf. The
+averaging divisor is applied either way — so it is correct both for
+autodiff-produced grads and for manually assembled per-device values, in
+both modes, and a gradient can never pass through unsummed.
 """
 
 from __future__ import annotations
@@ -46,7 +52,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.utils.collectives import group_size, psum_groups
+from apex_tpu.utils.collectives import group_size, psum_groups, vma_tracked
 from apex_tpu.utils.pytree import flatten_buckets, ravel_list, unravel_list
 
 
@@ -63,15 +69,12 @@ class DistributedDataParallel:
     axis_index_groups: Optional[tuple] = None  # subgroup reduction support
 
     def _is_varying(self, x) -> bool:
-        """True if ``x`` still differs across the mesh axis (needs a psum).
-
-        Autodiff-produced grads wrt replicated params come back already
-        summed (empty vma) — see module docstring."""
-        try:
-            vma = jax.typeof(x).vma
-        except (AttributeError, TypeError):
-            return True  # pmap / older tracer: assume varying
-        return self.axis_name in vma
+        """True if ``x`` may still differ across the mesh axis (needs a
+        psum): always where varying axes are not tracked, else by its
+        ``vma`` — see module docstring."""
+        if not vma_tracked(self.axis_name):
+            return True
+        return self.axis_name in jax.typeof(x).vma
 
     def _reduce_flat(self, flat, needs_psum: bool):
         orig_dtype = flat.dtype

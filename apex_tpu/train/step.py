@@ -534,6 +534,14 @@ class TrainStep:
 
     __call__ = step
 
+    def lower(self, state: TrainState, batch):
+        """AOT-lower the jitted step for ``state``/``batch`` (arrays or
+        sharded ``ShapeDtypeStruct``s): nothing is dispatched and a
+        donating step's state is not consumed. ``.compile()`` on the
+        result gives the program's text and memory analysis."""
+        _check_batch(batch, self.accum_steps)
+        return self._jitted.lower(state, batch)
+
     @property
     def program(self):
         """The raw (unjitted, un-shard_mapped) step function

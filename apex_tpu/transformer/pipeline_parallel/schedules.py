@@ -115,7 +115,7 @@ def _infer_carry_mark(fn, probe_params, microbatches, axis, name):
     from apex_tpu.utils.collectives import mark_varying
 
     mb_shape = microbatches.shape[1:]
-    mb_vma = frozenset(getattr(jax.typeof(microbatches), "vma", None) or ())
+    mb_vma = jax.typeof(microbatches).vma
     vma = frozenset({axis}) | mb_vma  # injected microbatches carry their own
     converged = False
     for it in range(4):  # the varying-set only grows and mesh axes are few

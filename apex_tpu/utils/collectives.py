@@ -10,34 +10,32 @@ import numpy as np
 
 
 def compat_shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across the JAX vintages this repo runs on, with the
-    replication check OFF on every vintage.
+    """``jax.shard_map`` with the varying-axes check OFF — the one
+    wrapper every in-repo ``shard_map`` goes through.
 
-    Newer JAX exposes ``jax.shard_map`` (vma-checked via ``check_vma``);
-    the 0.4.x line only has ``jax.experimental.shard_map.shard_map``
-    (``check_rep``), whose pass cannot infer replication through a
-    ``lax.scan`` carry (it aborts with "Scan carry input and output got
-    mismatched replication types"). The check is disabled on BOTH APIs
-    — not just the broken one — because the vma-marking discipline the
-    two vintages expect differs, and a program that must trace on both
-    cannot satisfy either checker portably. Callers therefore OWN their
-    replication discipline: every in-repo user replicates state in,
-    explicitly psums/pmeans/all_gathers anything device-varying before
-    an ``out_specs=P()`` output, and certifies the result in tests
-    (tests/test_train_step.py drives the composed step on the 8-device
-    mesh). Do not route an out_specs=P() output through this wrapper
-    without one of those collectives."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ("check_vma", "check_rep"):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{kw: False})
-        except TypeError:
-            continue
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    With ``check_vma=False`` JAX tracks no varying-axes sets inside the
+    body: ``jax.typeof(x).vma`` is empty for every value, no ``pvary``
+    is inserted at the boundary, and so autodiff inserts no psum either
+    — the gradient of a replicated (``P()``) input is the DEVICE-LOCAL
+    gradient. Callers therefore OWN their replication discipline: every
+    in-repo user replicates state in, explicitly psums/pmeans/
+    all_gathers anything device-varying before an ``out_specs=P()``
+    output, and certifies the result in tests (tests/test_train_step.py
+    drives the composed step on the 8-device mesh). Code that must
+    behave under both settings asks :func:`vma_tracked` first. Do not
+    route an ``out_specs=P()`` output through this wrapper without one
+    of those collectives."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def vma_tracked(axis_name: str) -> bool:
+    """Whether the enclosing ``shard_map`` tracks varying axes for
+    ``axis_name`` (``check_vma=True``). ``axis_index`` differs on every
+    device by construction, so its type carries the axis exactly when
+    types carry axes at all; under ``check_vma=False`` every ``vma`` is
+    empty and says nothing about the value."""
+    return axis_name in jax.typeof(jax.lax.axis_index(axis_name)).vma
 
 
 def mark_varying(x, axis_names):
@@ -48,10 +46,7 @@ def mark_varying(x, axis_names):
         axis_names = (axis_names,)
 
     def one(a):
-        try:
-            vma = jax.typeof(a).vma
-        except (AttributeError, TypeError):
-            vma = frozenset()
+        vma = jax.typeof(a).vma
         missing = tuple(ax for ax in axis_names if ax not in vma)
         if not missing:
             return a

@@ -877,12 +877,32 @@ def test_bench_diff_disappeared_section_fails(tmp_path):
     assert bd.main([new, old]) == 0
 
 
-def test_bench_diff_parses_real_pre_section_artifacts():
+def test_bench_diff_parses_pre_section_artifacts(tmp_path):
+    """An artifact of the older shape — metric lines only, no section
+    records, a log line in the tail, the last record repeated under
+    ``parsed`` — still parses, and the missing sections are reported,
+    not failed on."""
     bd = _load_tool("bench_diff.py")
-    repo = Path(__file__).resolve().parents[1]
-    old = bd.parse_artifact(str(repo / "BENCH_r03.json"))
-    new = bd.parse_artifact(str(repo / "BENCH_r04.json"))
-    assert old["metrics"] and new["metrics"]
+
+    def pre_section(name, lamb):
+        path = _artifact(
+            tmp_path, name, {},
+            {"bert_large_pretrain_s512_samples_per_sec_per_chip":
+                 {"value": 80.0, "unit": "samples/sec",
+                  "vs_baseline": 5.9},
+             "fused_lamb_step_speedup_vs_per_leaf_eager":
+                 {"value": lamb, "unit": "x", "vs_baseline": lamb}})
+        doc = json.loads(Path(path).read_text())
+        doc["tail"] = "# B=16 S=512: a log line, not a record\n" + doc["tail"]
+        doc["parsed"] = {"metric": "ddp_sync_efficiency", "value": 1.0,
+                         "unit": "ratio", "vs_baseline": 1.0}
+        Path(path).write_text(json.dumps(doc))
+        return path
+
+    old = bd.parse_artifact(pre_section("old.json", 1.097))
+    new = bd.parse_artifact(pre_section("new.json", 3.246))
+    assert len(old["metrics"]) == 3 and len(new["metrics"]) == 3
+    assert not old["sections"] and not new["sections"]
     rc, lines = bd.diff(old, new)
     assert rc == 0                          # no sections -> no liveness
     assert any("pre-PR-6" in ln for ln in lines)

@@ -1,7 +1,7 @@
 """Out-of-process replica certification (tier-1, CPU): the ISSUE 16
 layer (docs/fleet.md, "Process replicas").
 
-The wire protocol's failure taxonomy (round trip, clean close,
+The wire protocol's failure classes (round trip, clean close,
 truncation, rot, bad JSON, oversize refusal at both ends, timeout —
 every damaged frame an ``IntegrityError``, never a silent mis-parse);
 the seeded ``"wire"`` fault site (truncating/rotting chaos hook,

@@ -1,0 +1,294 @@
+"""Compile-only checks against a DESCRIBED TPU v5e (no chip attached).
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+topology that is described, not attached. These tests hand it the
+training path's Pallas kernels at BERT-large widths (B=16, S=512,
+hidden 1024, 16 heads x 64) and assert each one compiles to a Mosaic
+``tpu_custom_call`` — what interpret mode can never show: fast-memory
+limits, tile alignment, head-group blocking, and whether a kernel can
+sit inside ``shard_map`` over a four-chip mesh.
+
+Nothing runs, so nothing here is a result or a time. The kernels pick
+``interpret`` from ``jax.default_backend()`` at trace time (the CPU
+here), so the ``compiled_kernels`` fixture steers ``_interpret`` of each
+kernel module to False for the duration of a test — in the test, not
+through an option of the program.
+
+All of it lives in ONE file, and the topology is described inside a
+module-scoped fixture: only the xdist worker that is handed this file
+loads libtpu (one process at a time may), every worker collects the
+same tests, and a topology that cannot be described skips instead of
+failing collection.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+B, S, NH, D = 16, 512, 16, 64
+HID = NH * D
+ROWS = B * S
+
+
+def _hbm_bytes():
+    from apex_tpu.utils.chip_peaks import chip_peaks
+
+    return chip_peaks("TPU v5 lite").hbm_bytes
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # a described-device executable is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache off here
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / lock held by another process
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Trace the Pallas kernels as the chip would: ``interpret=False``."""
+    import importlib
+
+    # by module path: ``apex_tpu.ops`` re-exports functions under the
+    # same names as its submodules
+    for name in ("dropout", "flash_attention", "layer_norm", "softmax"):
+        mod = importlib.import_module(f"apex_tpu.ops.{name}")
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    return compiled, compiled.as_text()
+
+
+def _n_kernels(text):
+    return text.count("tpu_custom_call")
+
+
+# -- each kernel of the BERT-large train path, forward + backward ----------
+
+
+def _ln_case(sh):
+    from apex_tpu.ops.layer_norm import fused_layer_norm_affine
+
+    def f(x, w, b):
+        return jax.value_and_grad(
+            lambda x, w, b: fused_layer_norm_affine(x, w, b, 1e-12)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(x, w, b)
+
+    # one kernel: under autodiff the forward is XLA-fused by design
+    # (ops/layer_norm.py ``_ln_fwd_mode``), the backward is Pallas
+    return f, (_spec((ROWS, HID), jnp.bfloat16, sh),
+               _spec((HID,), jnp.float32, sh),
+               _spec((HID,), jnp.float32, sh)), 1
+
+
+def _flash_case(sh):
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    def f(q, k, v, mask, seed):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, mask, False, D ** -0.5, 0.1, seed)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    qkv = _spec((B, NH, S, D), jnp.bfloat16, sh)
+    return f, (qkv, qkv, qkv, _spec((B, S), jnp.bool_, sh),
+               _spec((), jnp.int32, sh)), 2
+
+
+def _flash_bsh_case(sh):
+    from apex_tpu.ops.flash_attention import flash_attention_bsh
+
+    def f(q, k, v, mask, seed):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention_bsh(
+                q, k, v, mask, NH, False, D ** -0.5, 0.1, seed)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    qkv = _spec((B, S, HID), jnp.bfloat16, sh)
+    return f, (qkv, qkv, qkv, _spec((B, S), jnp.bool_, sh),
+               _spec((), jnp.int32, sh)), 2
+
+
+def _keep_mask_case(sh):
+    from apex_tpu.ops.flash_attention import flash_dropout_keep_mask
+
+    def f(seed):
+        return flash_dropout_keep_mask(B, NH, S, S, 0.1, seed)
+
+    return f, (_spec((), jnp.int32, sh),), 1
+
+
+def _softmax_case(sh):
+    from apex_tpu.ops.softmax import scaled_masked_softmax
+
+    def f(x, mask):
+        return jax.value_and_grad(
+            lambda x: scaled_masked_softmax(x, mask, D ** -0.5)
+            .astype(jnp.float32).sum())(x)
+
+    return f, (_spec((B, NH, S, S), jnp.bfloat16, sh),
+               _spec((B, 1, 1, S), jnp.bool_, sh)), 2
+
+
+def _dropout_case(sh):
+    from apex_tpu.ops.dropout import fused_dropout
+
+    def f(x, seed):
+        return jax.value_and_grad(
+            lambda x: fused_dropout(x, 0.1, seed)
+            .astype(jnp.float32).sum())(x)
+
+    return f, (_spec((ROWS, HID), jnp.bfloat16, sh),
+               _spec((), jnp.int32, sh)), 2
+
+
+_CASES = {
+    "layer_norm": _ln_case,
+    "flash_attention": _flash_case,
+    "flash_attention_bsh": _flash_bsh_case,
+    "flash_dropout_keep_mask": _keep_mask_case,
+    "scaled_masked_softmax": _softmax_case,
+    "fused_dropout": _dropout_case,
+}
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, compiled_kernels):
+    fn, specs, min_kernels = _CASES[name](one_chip)
+    compiled, text = _compile(fn, *specs)
+    assert _n_kernels(text) >= min_kernels, (
+        f"{name}: expected >= {min_kernels} Mosaic kernels (fwd + bwd) "
+        f"in the v5e program, found {_n_kernels(text)}")
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < _hbm_bytes()
+
+
+@pytest.mark.parametrize("name", ["layer_norm", "flash_attention_bsh",
+                                  "fused_dropout"])
+def test_kernel_compiles_inside_shard_map_on_four_chips(
+        name, mesh4, compiled_kernels):
+    """The data-parallel step runs these kernels INSIDE ``shard_map``;
+    on the CPU that context always took the jnp references, so this is
+    the only place the compiled kernels meet the partitioner."""
+    from apex_tpu.utils.collectives import compat_shard_map
+
+    fn, specs, min_kernels = _CASES[name](None)
+    n_grads = len(jax.tree.leaves(jax.eval_shape(fn, *specs))) - 1
+    rep, split = NamedSharding(mesh4, P()), NamedSharding(mesh4, P("data"))
+    # activations (leading dim = batch or rows) shard over the mesh,
+    # weights and seeds replicate
+    in_specs = tuple(P("data") if len(s.shape) >= 2 else P() for s in specs)
+    specs = tuple(
+        _spec(s.shape, s.dtype, split if len(s.shape) >= 2 else rep)
+        for s in specs)
+
+    def body(*args):
+        # (value, grads wrt the leading args); the value and a
+        # replicated weight's grad are device-local under
+        # check_vma=False, so they are summed here
+        return tuple(g if g.ndim >= 2 else jax.lax.psum(g, "data")
+                     for g in jax.tree.leaves(fn(*args)))
+
+    sm = compat_shard_map(body, mesh4, in_specs=in_specs,
+                          out_specs=(P(),) + in_specs[:n_grads])
+    _, text = _compile(sm, *specs)
+    assert _n_kernels(text) >= min_kernels
+    if name == "layer_norm":
+        assert "all-reduce" in text  # the dw/db psum over the four chips
+
+
+# -- the whole step: does BERT-large B=16 S=512 fit one chip? --------------
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("donate", [True, False])
+def test_bert_large_train_step_fits_one_v5e(donate, one_chip,
+                                            compiled_kernels):
+    """The headline step of ``chip_smoke.py`` (24 x 1024, S=512, B=16,
+    amp O2 + FusedLAMB through ``build_train_step``) compiled from
+    shapes for one described chip: its kernels are compiled in and,
+    with donation, its memory fits 16 GB. Slow (a minute or two): run by
+    hand before a chip call, not in tier 1."""
+    import chip_smoke
+
+    run = chip_smoke.build_bert_trainer(
+        chip_smoke.bert_large_config(), batch=B, seq=S, donate=donate,
+        abstract_on=one_chip)
+    compiled = run.step.lower(run.state, run.batch).compile()
+    text = compiled.as_text()
+    assert _n_kernels(text) > 0
+    mem = compiled.memory_analysis()
+    live = chip_smoke.live_bytes(mem)
+    print(f"donate={donate}: args {mem.argument_size_in_bytes / 2**30:.2f} "
+          f"GiB, out {mem.output_size_in_bytes / 2**30:.2f}, alias "
+          f"{mem.alias_size_in_bytes / 2**30:.2f}, temp "
+          f"{mem.temp_size_in_bytes / 2**30:.2f}, live {live / 2**30:.2f}; "
+          f"tpu_custom_call x{_n_kernels(text)}")
+    if donate:
+        assert mem.alias_size_in_bytes > 0
+        assert live < _hbm_bytes()
+
+
+@pytest.mark.slow
+def test_bert_large_ddp_step_compiles_for_four_v5e(mesh4, compiled_kernels):
+    """The four-chip phase of ``chip_smoke.py`` (``--four-chips``):
+    BERT-large through ``build_train_step(ddp=..., mesh=...)`` at
+    per-chip batch 4, compiled for the four described chips. The
+    kernels sit inside ``shard_map``, one flat all-reduce carries the
+    fp32 gradient bytes, and each chip's share fits 16 GB."""
+    import chip_smoke
+    from apex_tpu.parallel import DistributedDataParallel
+    from apex_tpu.utils.hlo_audit import collective_stats
+
+    run = chip_smoke.build_bert_trainer(
+        chip_smoke.bert_large_config(), batch=16, seq=S,
+        ddp=DistributedDataParallel("data", delay_allreduce=True),
+        mesh=mesh4, abstract_on=NamedSharding(mesh4, P()))
+    compiled = run.step.lower(run.state, run.batch).compile()
+    text = compiled.as_text()
+    stats = collective_stats(text)
+    live = chip_smoke.live_bytes(compiled.memory_analysis())
+    print(f"ddp x4: all-reduce {stats['all-reduce']}, tpu_custom_call "
+          f"x{_n_kernels(text)}, live per chip {live / 2**30:.2f} GiB")
+    assert _n_kernels(text) > 0
+    assert stats["all-reduce"]["bytes"] >= 4 * run.n_params
+    assert live < _hbm_bytes()
