@@ -29,6 +29,7 @@ from typing import NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.amp import _amp_state
 from apex_tpu.utils.pytree import all_finite, tree_select
 
@@ -98,9 +99,10 @@ class LossScaler:
         traced bool. Non-finite grads are passed through unscaled-but-
         harmless; the caller must skip the step when ``found_inf``.
         """
-        inv = (1.0 / state.loss_scale).astype(jnp.float32)
-        found_inf = jnp.logical_not(all_finite(grads))
-        unscaled = jax.tree.map(lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype), grads)
+        with jax.named_scope(profiler.AMP_UNSCALE):
+            inv = (1.0 / state.loss_scale).astype(jnp.float32)
+            found_inf = jnp.logical_not(all_finite(grads))
+            unscaled = jax.tree.map(lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype), grads)
         return unscaled, found_inf
 
     def update(self, state: ScalerState, found_inf) -> ScalerState:
@@ -211,7 +213,9 @@ class LossScaler:
                 loss, aux = out
             else:
                 loss, aux = out, None
-            return self.scale(loss, state), (loss, aux)
+            with jax.named_scope(profiler.AMP_SCALE_LOSS):
+                scaled = self.scale(loss, state)
+            return scaled, (loss, aux)
 
         vg = jax.value_and_grad(scaled_fn, has_aux=True)
 

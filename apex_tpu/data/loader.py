@@ -19,6 +19,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from apex_tpu import profiler
 from apex_tpu._native import build_ctypes_lib
 
 _LIB = None
@@ -160,7 +161,8 @@ class _PrefetchIterator:
         return self
 
     def __next__(self):
-        item = self._q.get()
+        with profiler.annotate(profiler.DATA_WAIT):
+            item = self._q.get()
         if item is self._DONE:
             raise StopIteration
         if isinstance(item, BaseException):

@@ -27,6 +27,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.normalization import FusedLayerNorm
 from apex_tpu.transformer.functional import AttnMaskType, FusedScaleMaskSoftmax
 
@@ -390,27 +391,33 @@ class BertForPreTraining(nn.Module):
         cfg = self.cfg
         x, pooled = BertModel(cfg, name="bert")(
             input_ids, token_type_ids, attention_mask, deterministic)
-        if masked_positions is not None:
-            x = jnp.take_along_axis(
-                x, masked_positions[..., None].astype(jnp.int32), axis=1)
-        h = _dense(cfg, cfg.hidden_size, "mlm_transform")(x)
-        h = nn.gelu(h)
-        h = _norm(cfg, "mlm_ln")(h)
-        if cfg.use_tensor_parallel:
-            from apex_tpu.transformer.tensor_parallel import ColumnParallelLinear
+        with jax.named_scope(profiler.MLM_HEAD):
+            if masked_positions is not None:
+                x = jnp.take_along_axis(
+                    x, masked_positions[..., None].astype(jnp.int32), axis=1)
+            h = _dense(cfg, cfg.hidden_size, "mlm_transform")(x)
+            h = nn.gelu(h)
+            h = _norm(cfg, "mlm_ln")(h)
+            if cfg.use_tensor_parallel:
+                from apex_tpu.transformer.tensor_parallel import (
+                    ColumnParallelLinear,
+                )
 
-            # local-vocab-shard logits, consumed by vocab_parallel_cross_entropy
-            mlm_logits = ColumnParallelLinear(
-                input_size=cfg.hidden_size, output_size=cfg.vocab_size,
-                gather_output=False, init_method=_BERT_INIT,
-                name="mlm_decoder",
-            )(h.reshape(-1, cfg.hidden_size)).reshape(*h.shape[:-1], -1)
-        else:
-            mlm_logits = _dense(cfg, cfg.vocab_size, "mlm_decoder")(h)
-        nsp_logits = _dense(cfg, 2, "nsp")(pooled)
+                # local-vocab-shard logits, consumed by
+                # vocab_parallel_cross_entropy
+                mlm_logits = ColumnParallelLinear(
+                    input_size=cfg.hidden_size, output_size=cfg.vocab_size,
+                    gather_output=False, init_method=_BERT_INIT,
+                    name="mlm_decoder",
+                )(h.reshape(-1, cfg.hidden_size)).reshape(*h.shape[:-1], -1)
+            else:
+                mlm_logits = _dense(cfg, cfg.vocab_size, "mlm_decoder")(h)
+        with jax.named_scope(profiler.NSP_HEAD):
+            nsp_logits = _dense(cfg, 2, "nsp")(pooled)
         return mlm_logits, nsp_logits
 
 
+@jax.named_scope(profiler.PRETRAINING_LOSS)
 def pretraining_loss(mlm_logits, nsp_logits, mlm_labels, nsp_labels,
                      mlm_weights=None, vocab_parallel: bool = False):
     """Masked-LM + next-sentence loss, fp32 (the MLPerf BERT objective).

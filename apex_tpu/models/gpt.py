@@ -56,6 +56,7 @@ from apex_tpu.models._dropout import (
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.normalization import FusedLayerNorm
 
 _INIT = nn.initializers.normal(stddev=0.02)
@@ -676,13 +677,15 @@ class GPTLMHeadModel(nn.Module):
                 kv_cache=kv_cache, block_tables=block_tables,
                 cache_positions=cache_positions, seq_lens=seq_lens,
                 write_start=write_start)
-            logits = jnp.einsum("bsh,vh->bsv", x, wte.astype(x.dtype),
-                                preferred_element_type=jnp.float32)
+            with jax.named_scope(profiler.LM_HEAD):
+                logits = jnp.einsum("bsh,vh->bsv", x, wte.astype(x.dtype),
+                                    preferred_element_type=jnp.float32)
             return logits, new_cache
         x, wte = GPTModel(self.cfg, name="transformer")(
             input_ids, deterministic, position_offset)
-        return jnp.einsum("bsh,vh->bsv", x, wte.astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope(profiler.LM_HEAD):
+            return jnp.einsum("bsh,vh->bsv", x, wte.astype(x.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def moe_losses_total(collections):
@@ -697,6 +700,7 @@ def moe_losses_total(collections):
     return total
 
 
+@jax.named_scope(profiler.LM_LOSS)
 def lm_loss(logits, labels, ignore_index: int = -1):
     """Shifted next-token cross-entropy via the fused logsumexp identity
     (same memory rationale as bert.pretraining_loss)."""

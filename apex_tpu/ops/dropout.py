@@ -69,6 +69,7 @@ def _call(x2, drop_in, rate):
         in_specs=[pl.BlockSpec((1, R, C), lambda i: (i, 0, 0)), extra_spec],
         out_specs=pl.BlockSpec((1, R, C), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
+        name="dropout_apply",
         interpret=_interpret(),
     )(x2, drop_in)
 

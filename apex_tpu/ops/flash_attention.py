@@ -429,6 +429,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk,
                 out_struct((B, H, Sq, D), q.dtype, q, k, v),
                 out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
             ),
+            name="flash_fwd",
             interpret=_interpret(),
         )(q, k, v, mask, *extra)
     kernel = functools.partial(
@@ -458,6 +459,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk,
             pltpu.VMEM((bq, LANE), jnp.float32),
             pltpu.VMEM((bq, LANE), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=_interpret(),
     )(q, k, v, mask, *extra)
     return out, lse
@@ -497,6 +499,7 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
                 out_struct((B, H, Sk, D), k.dtype, q, k, v, do),
                 out_struct((B, H, Sk, D), v.dtype, q, k, v, do),
             ),
+            name="flash_bwd",
             interpret=_interpret(),
         )(q, k, v, mask, do, lse, delta, *extra)
 
@@ -519,6 +522,7 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
         out_specs=_spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=out_struct((B, H, Sq, D), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, mask, do, lse, delta, *extra)
 
@@ -550,6 +554,7 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, mask, do, lse, delta, *extra)
     return dq, dk, dv
@@ -661,6 +666,7 @@ def flash_dropout_keep_mask(B, H, Sq, Sk, dropout_rate, seed):
         out_specs=pl.BlockSpec((1, 1, bq, bk),
                                lambda b, h, iq, ik: (b, h, iq, ik)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sqp, Skp), jnp.float32),
+        name="dropout_mask",
         interpret=_interpret(),
     )(jnp.asarray(seed, jnp.int32).reshape((1,)))
     return (keep > 0.5)[:, :, :Sq, :Sk]
@@ -1107,6 +1113,7 @@ def _flash_fwd_call_bsh(q, k, v, mask, *, scale, causal, bq, bk, NH, D,
             out_struct((B, Sp, NH * D), q.dtype, q, k, v),
             out_struct((B, NH, 1, Sp), jnp.float32, q, k, v),
         ),
+        name="flash_fwd",
         interpret=_interpret(),
     )(q, k, v, mask, *extra)
 
@@ -1142,6 +1149,7 @@ def _flash_bwd_call_bsh(q, k, v, mask, do, lse, delta, *, scale, causal,
             out_struct((B, Sp, NH * D), k.dtype, q, k, v, do),
             out_struct((B, Sp, NH * D), v.dtype, q, k, v, do),
         ),
+        name="flash_bwd",
         interpret=_interpret(),
     )(q, k, v, mask, do, lse, delta, *extra)
 

@@ -189,6 +189,7 @@ def _pallas_forward(x2, weight, bias, *, eps, true_h, rms):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, hpad), lambda i: (i, 0)),
         out_shape=out_struct((n, hpad), x2.dtype, *args),
+        name="layer_norm_fwd",
         interpret=_interpret(),
     )(*args)
 
@@ -224,6 +225,7 @@ def _pallas_backward(g2, x2, weight, *, eps, true_h, rms):
             pltpu.VMEM((8, hpad), jnp.float32),
             pltpu.VMEM((8, hpad), jnp.float32),
         ],
+        name="layer_norm_bwd",
         interpret=_interpret(),
     )(g2, x2, weight)
     return dx, dw_part.sum(axis=0), db_part.sum(axis=0)

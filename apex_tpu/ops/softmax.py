@@ -103,6 +103,7 @@ def _pallas_softmax_fwd(x2, m2=None, *, scale, causal, sq, true_k,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, kpad), lambda i: (i, 0)),
         out_shape=out_struct((n, kpad), x2.dtype, x2),
+        name="softmax_fwd",
         interpret=_interpret(),
     )(*args)
 
@@ -119,6 +120,7 @@ def _pallas_softmax_bwd(g2, y2, *, scale):
         ],
         out_specs=pl.BlockSpec((br, kpad), lambda i: (i, 0)),
         out_shape=out_struct((n, kpad), g2.dtype, g2, y2),
+        name="softmax_bwd",
         interpret=_interpret(),
     )(g2, y2)
 
@@ -185,6 +187,7 @@ def _pallas_softmax_fwd4(x, m, *, scale, causal, mask_mode):
         out_specs=pl.BlockSpec((1, 1, br, kpad),
                                lambda b, h, j: (b, h, j, 0)),
         out_shape=out_struct((B, H, sqp, kpad), x.dtype, x, m),
+        name="softmax_fwd",
         interpret=_interpret(),
     )(xp, mp)
     return yp[:, :, :Sq, :K]

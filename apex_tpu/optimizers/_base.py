@@ -24,6 +24,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.utils.pytree import tree_select
 
 
@@ -129,12 +130,13 @@ class FusedOptimizer:
                                 lr=lr, grad_scale=grad_scale)
                 new_params, new_state = out[0], out[1]
             else:
-                inv = 1.0 / jnp.asarray(grad_scale, jnp.float32)
-                grads = jax.tree.map(
-                    lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
-                    grads)
                 from apex_tpu.utils.pytree import all_finite
-                found = jnp.logical_not(all_finite(grads))
+                with jax.named_scope(profiler.AMP_UNSCALE):
+                    inv = 1.0 / jnp.asarray(grad_scale, jnp.float32)
+                    grads = jax.tree.map(
+                        lambda g: (g.astype(jnp.float32) * inv
+                                   ).astype(g.dtype), grads)
+                    found = jnp.logical_not(all_finite(grads))
                 skip_if = (found if skip_if is None
                            else jnp.logical_or(skip_if, found))
                 new_params, new_state = self.step(grads, state, params,

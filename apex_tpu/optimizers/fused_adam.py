@@ -19,6 +19,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.multi_tensor_apply import multi_tensor_applier
 from apex_tpu.ops.multi_tensor import (
     ADAM_MODE_ADAMW,
@@ -84,20 +85,21 @@ class FusedAdam(FusedOptimizer):
             lists.append(leaves_of(state.master))
 
         sr_key = self._sr_key(step, 0xADA3)
-        out = multi_tensor_applier(
-            multi_tensor_adam,
-            None,
-            lists,
-            lr,
-            self.betas[0],
-            self.betas[1],
-            self.eps,
-            step,
-            ADAM_MODE_ADAMW if self.adam_w_mode else ADAM_MODE_L2,
-            self.bias_correction,
-            self.weight_decay,
-            sr_key=sr_key,
-        )
+        with jax.named_scope(profiler.ADAM_UPDATE):
+            out = multi_tensor_applier(
+                multi_tensor_adam,
+                None,
+                lists,
+                lr,
+                self.betas[0],
+                self.betas[1],
+                self.eps,
+                step,
+                ADAM_MODE_ADAMW if self.adam_w_mode else ADAM_MODE_L2,
+                self.bias_correction,
+                self.weight_decay,
+                sr_key=sr_key,
+            )
         new_p = like_tree(out[0], params)
         new_state = AdamState(
             step=step,

@@ -18,6 +18,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.multi_tensor_apply import multi_tensor_applier
 from apex_tpu.ops.multi_tensor import (
     multi_tensor_l2norm,
@@ -103,9 +104,10 @@ class FusedLAMB(FusedOptimizer):
         v = leaves_of(state.exp_avg_sq)
 
         # Stage 0: global grad norm (one fused reduction pass).
-        global_norm, _ = multi_tensor_applier(
-            multi_tensor_l2norm, None, [g], False
-        )
+        with jax.named_scope(profiler.LAMB_GRAD_NORM):
+            global_norm, _ = multi_tensor_applier(
+                multi_tensor_l2norm, None, [g], False
+            )
         pre_scale = 1.0
         found_inf = None
         if grad_scale is not None:
@@ -123,30 +125,32 @@ class FusedLAMB(FusedOptimizer):
                 pre_scale, step, lr, skip_if, found_inf)
 
         # Stage 1: clip + moments + update directions.
-        updates, new_m, new_v = multi_tensor_applier(
-            multi_tensor_lamb_stage1,
-            None,
-            [g, p_src, m, v],
-            self.betas[0],
-            self.betas[1],
-            self.eps,
-            step,
-            self.bias_correction,
-            self.weight_decay,
-            self.grad_averaging,
-            global_norm,
-            self.max_grad_norm,
-            pre_scale,
-        )
+        with jax.named_scope(profiler.LAMB_STAGE1):
+            updates, new_m, new_v = multi_tensor_applier(
+                multi_tensor_lamb_stage1,
+                None,
+                [g, p_src, m, v],
+                self.betas[0],
+                self.betas[1],
+                self.eps,
+                step,
+                self.bias_correction,
+                self.weight_decay,
+                self.grad_averaging,
+                global_norm,
+                self.max_grad_norm,
+                pre_scale,
+            )
 
         # Stage 2: per-tensor trust ratios + parameter step.
         lists = [p_model, updates]
         if self.master_weights:
             lists.append(p_src)
-        out = multi_tensor_applier(
-            multi_tensor_lamb_stage2, None, lists, lr, self.weight_decay,
-            self.use_nvlamb,
-        )
+        with jax.named_scope(profiler.LAMB_STAGE2):
+            out = multi_tensor_applier(
+                multi_tensor_lamb_stage2, None, lists, lr, self.weight_decay,
+                self.use_nvlamb,
+            )
         if self.master_weights:
             new_p_leaves, new_master_leaves = out
             new_master = like_tree(new_master_leaves, state.master)
@@ -196,39 +200,42 @@ class FusedLAMB(FusedOptimizer):
                 bc1, bc2, self.eps, self.weight_decay)
 
         # Pass A: moments (rounded) + per-tensor ||u||, ||p|| reductions
-        new_m, new_v, u_sq, p_sq = [], [], [], []
-        for i, (gi, pi, mi, vi) in enumerate(zip(g, p_src, m, v)):
-            g32 = gi.astype(jnp.float32) * clip
-            p32 = pi.astype(jnp.float32)
-            m32 = b1 * mi.astype(jnp.float32) + beta3 * g32
-            v32 = b2 * vi.astype(jnp.float32) + (1.0 - b2) * g32 * g32
-            if key is not None:
-                mo = stochastic_round(m32, mdt, jax.random.fold_in(key, 2 * i))
-                vo = stochastic_round(v32, mdt,
-                                      jax.random.fold_in(key, 2 * i + 1))
+        with jax.named_scope(profiler.LAMB_STAGE1):
+            new_m, new_v, u_sq, p_sq = [], [], [], []
+            for i, (gi, pi, mi, vi) in enumerate(zip(g, p_src, m, v)):
+                g32 = gi.astype(jnp.float32) * clip
+                p32 = pi.astype(jnp.float32)
+                m32 = b1 * mi.astype(jnp.float32) + beta3 * g32
+                v32 = b2 * vi.astype(jnp.float32) + (1.0 - b2) * g32 * g32
+                if key is not None:
+                    mo = stochastic_round(m32, mdt,
+                                          jax.random.fold_in(key, 2 * i))
+                    vo = stochastic_round(v32, mdt,
+                                          jax.random.fold_in(key, 2 * i + 1))
+                else:
+                    mo, vo = m32.astype(mdt), v32.astype(mdt)
+                new_m.append(mo)
+                new_v.append(vo)
+                u32 = u_of(mo, vo, p32)
+                u_sq.append(jnp.sum(u32 * u32))
+                p_sq.append(jnp.sum(p32 * p32))
+
+        with jax.named_scope(profiler.LAMB_STAGE2):
+            apply_ratio = self.use_nvlamb or self.weight_decay != 0.0
+            if apply_ratio:
+                ratios = lamb_trust_ratio(jnp.sqrt(jnp.stack(p_sq)),
+                                          jnp.sqrt(jnp.stack(u_sq)))
             else:
-                mo, vo = m32.astype(mdt), v32.astype(mdt)
-            new_m.append(mo)
-            new_v.append(vo)
-            u32 = u_of(mo, vo, p32)
-            u_sq.append(jnp.sum(u32 * u32))
-            p_sq.append(jnp.sum(p32 * p32))
+                ratios = jnp.ones((len(g),), jnp.float32)
 
-        apply_ratio = self.use_nvlamb or self.weight_decay != 0.0
-        if apply_ratio:
-            ratios = lamb_trust_ratio(jnp.sqrt(jnp.stack(p_sq)),
-                                      jnp.sqrt(jnp.stack(u_sq)))
-        else:
-            ratios = jnp.ones((len(g),), jnp.float32)
-
-        # Pass B: recompute u from the stored rounded moments + step
-        new_p, new_master = [], []
-        for i, pi in enumerate(p_src):
-            p32 = pi.astype(jnp.float32)
-            stepped = p32 - lr * ratios[i] * u_of(new_m[i], new_v[i], p32)
-            new_p.append(stepped.astype(p_model[i].dtype))
-            if self.master_weights:
-                new_master.append(stepped)
+            # Pass B: recompute u from the stored rounded moments + step
+            new_p, new_master = [], []
+            for i, pi in enumerate(p_src):
+                p32 = pi.astype(jnp.float32)
+                stepped = p32 - lr * ratios[i] * u_of(new_m[i], new_v[i], p32)
+                new_p.append(stepped.astype(p_model[i].dtype))
+                if self.master_weights:
+                    new_master.append(stepped)
 
         new_state = LambState(
             step=step,

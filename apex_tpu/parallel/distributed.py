@@ -52,6 +52,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.utils.collectives import group_size, psum_groups, vma_tracked
 from apex_tpu.utils.pytree import flatten_buckets, ravel_list, unravel_list
 
@@ -115,18 +116,20 @@ class DistributedDataParallel:
             if not group_ids:
                 continue
             group = [leaves[i] for i in group_ids]
-            if self.delay_allreduce:
-                # flat-buffer path: one allreduce over the whole group
-                flat, meta = ravel_list(group)
-                pieces = unravel_list(self._reduce_flat(flat, needs_psum), meta)
-                for piece, i in zip(pieces, group_ids):
-                    out[i] = piece
-            else:
-                for indices, flat, meta in flatten_buckets(group, self.message_size):
+            with jax.named_scope(profiler.DDP_FLATTEN):
+                if self.delay_allreduce:
+                    # flat-buffer path: one allreduce over the whole group
+                    flat, meta = ravel_list(group)
+                    buckets = [(range(len(group)), flat, meta)]
+                else:
+                    buckets = flatten_buckets(group, self.message_size)
+            for indices, flat, meta in buckets:
+                with jax.named_scope(profiler.DDP_ALLREDUCE):
                     flat = self._reduce_flat(flat, needs_psum)
+                with jax.named_scope(profiler.DDP_UNFLATTEN):
                     pieces = unravel_list(flat, meta)
-                    for piece, pos in zip(pieces, indices):
-                        out[group_ids[pos]] = piece
+                for piece, pos in zip(pieces, indices):
+                    out[group_ids[pos]] = piece
         return jax.tree.unflatten(treedef, out)
 
     def allreduce_accumulated(self, acc, accum_steps: int):

@@ -3,14 +3,70 @@
 users migrated to).
 
 The reference's pyprof parsed nvprof SQLite dumps to attribute kernels
-to model ops. On TPU the equivalent workflow is ``jax.profiler``: traces
-land in TensorBoard/Perfetto with XLA-op attribution built in. This
-module provides the thin, apex-shaped surface:
+to model ops. On TPU the equivalent workflow is ``jax.profiler``: a
+capture holds one event per executed HLO op on the device plane and the
+host's threads on the host plane, on one clock. What ties a device op
+back to the code is the op's ``op_name`` metadata in the compiled HLO
+(``jit(step)/<named scopes>/<flax module path>/<primitive>``) and, for
+a Pallas kernel, the instruction's own name. The train path names both;
+the names are this module's constants, so a reader of a trace has ONE
+table to learn (docs/observability.md, "Training: scopes and
+annotations"):
+
+================== ======================================== ==========================
+scope              what runs under it                       emitted in
+================== ======================================== ==========================
+train_fwd_bwd      forward, recomputed forward, backward of train/step.py (microbatch)
+                   one microbatch. JAX itself marks the
+                   backward ops ``transpose(jvp(...))`` and
+                   the recomputed ones
+                   ``checkpoint/rematted_computation``
+train_accumulate   the zeroed fp32 accumulator, the          train/step.py
+                   accumulate, the microbatch loop's own
+                   plumbing
+train_reduce       average over microbatches (+ DDP sync)   train/step.py (apply)
+train_metrics      loss mean, gradient norm, aux gather,     train/step.py (apply)
+                   the step counter
+amp_scale_loss     loss x scale                              amp/scaler.py
+amp_unscale        gradients / scale + finite check          amp/scaler.py
+amp_found_inf      the global overflow flag                  train/step.py (apply)
+amp_update_scale   the scaler's state update                 train/step.py (apply)
+optimizer_update   the ``lax.cond`` and both its branches    train/step.py (apply)
+lamb_grad_norm     LAMB stage 0: global gradient norm        optimizers/fused_lamb.py
+lamb_stage1        LAMB clip + moments + update directions   optimizers/fused_lamb.py
+lamb_stage2        LAMB trust ratios + parameter step        optimizers/fused_lamb.py
+adam_update        the one fused Adam/AdamW update           optimizers/fused_adam.py
+ddp_flatten        gradient leaves -> flat buffer(s)         parallel/distributed.py
+ddp_allreduce      predivide, psum, average                  parallel/distributed.py
+ddp_unflatten      flat buffer(s) -> gradient leaves         parallel/distributed.py
+lm_head            GPT's tied vocabulary einsum              models/gpt.py
+lm_loss            shifted cross-entropy (fp32 logsumexp)    models/gpt.py
+mlm_head           BERT gather + transform + LN + decoder    models/bert.py
+nsp_head           BERT next-sentence classifier             models/bert.py
+pretraining_loss   MLM + NSP loss                            models/bert.py
+================== ======================================== ==========================
+
+Pallas kernels carry a stable ``name=`` that says kernel and direction,
+never the caller (:data:`KERNEL_NAMES`); the name becomes the HLO
+instruction's name and so the device event's: ``flash_fwd``,
+``flash_bwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``layer_norm_fwd``,
+``layer_norm_bwd``, ``softmax_fwd``, ``softmax_bwd``, ``dropout_apply``,
+``dropout_mask``.
+
+Host annotations (``jax.profiler.TraceAnnotation``, on the host plane
+of the same capture; a flag test when no capture runs):
+``train_dispatch`` and ``train_fetch`` (:class:`apex_tpu.train.TrainLoop`)
+and ``data_wait`` (the loaders of :mod:`apex_tpu.data.loader`).
+
+A scope is a ``jax.named_scope`` (metadata only: the compiled arithmetic
+is unchanged). Its name holds no ``.`` and no ``/``: a scope's last
+component can become an HLO instruction's name.
+
+The apex-shaped surface:
 
 - :func:`trace`: context manager around ``jax.profiler.trace`` (the
   ``pyprof.nvtx.init()`` analog: one line around the training loop);
-- :func:`annotate`: named trace region (``torch.cuda.nvtx.range`` /
-  pyprof op-annotation analog) for attributing loop phases;
+- :func:`annotate`: named host region (``torch.cuda.nvtx.range`` analog);
 - :class:`StepTimer`: host-side per-step wall timing with warmup
   exclusion and a summary dict — the "per-step timing surface" SURVEY
   prescribes, usable on runtimes where the full profiler is unavailable.
@@ -26,6 +82,48 @@ import jax
 
 from apex_tpu.observability.metrics import percentile
 
+# -- the scope vocabulary of the train step (table in the docstring) ----------
+TRAIN_FWD_BWD = "train_fwd_bwd"
+TRAIN_ACCUMULATE = "train_accumulate"
+TRAIN_REDUCE = "train_reduce"
+TRAIN_METRICS = "train_metrics"
+AMP_SCALE_LOSS = "amp_scale_loss"
+AMP_UNSCALE = "amp_unscale"
+AMP_FOUND_INF = "amp_found_inf"
+AMP_UPDATE_SCALE = "amp_update_scale"
+OPTIMIZER_UPDATE = "optimizer_update"
+LAMB_GRAD_NORM = "lamb_grad_norm"
+LAMB_STAGE1 = "lamb_stage1"
+LAMB_STAGE2 = "lamb_stage2"
+ADAM_UPDATE = "adam_update"
+DDP_FLATTEN = "ddp_flatten"
+DDP_ALLREDUCE = "ddp_allreduce"
+DDP_UNFLATTEN = "ddp_unflatten"
+LM_HEAD = "lm_head"
+LM_LOSS = "lm_loss"
+MLM_HEAD = "mlm_head"
+NSP_HEAD = "nsp_head"
+PRETRAINING_LOSS = "pretraining_loss"
+
+STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
+               AMP_SCALE_LOSS, AMP_UNSCALE, AMP_FOUND_INF, AMP_UPDATE_SCALE,
+               OPTIMIZER_UPDATE)
+OPTIMIZER_SCOPES = (LAMB_GRAD_NORM, LAMB_STAGE1, LAMB_STAGE2, ADAM_UPDATE)
+DDP_SCOPES = (DDP_FLATTEN, DDP_ALLREDUCE, DDP_UNFLATTEN)
+MODEL_SCOPES = (LM_HEAD, LM_LOSS, MLM_HEAD, NSP_HEAD, PRETRAINING_LOSS)
+SCOPES = STEP_SCOPES + OPTIMIZER_SCOPES + DDP_SCOPES + MODEL_SCOPES
+
+# -- Pallas kernel names (``pl.pallas_call(name=...)``) ------------------------
+KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
+                "layer_norm_fwd", "layer_norm_bwd", "softmax_fwd",
+                "softmax_bwd", "dropout_apply", "dropout_mask")
+
+# -- host annotations ----------------------------------------------------------
+TRAIN_DISPATCH = "train_dispatch"
+TRAIN_FETCH = "train_fetch"
+DATA_WAIT = "data_wait"
+ANNOTATIONS = (TRAIN_DISPATCH, TRAIN_FETCH, DATA_WAIT)
+
 
 @contextlib.contextmanager
 def trace(log_dir: str, create_perfetto_link: bool = False):
@@ -37,7 +135,8 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
 
 
 def annotate(name: str):
-    """Named region inside a trace (shows up on the op timeline)."""
+    """Named host region inside a capture (a line of the host plane, on
+    the device ops' clock); a flag test when no capture is running."""
     return jax.profiler.TraceAnnotation(name)
 
 

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu import profiler
 from apex_tpu.utils.faults import guarded_call
 
 
@@ -60,6 +61,12 @@ def _to_host(metrics) -> Dict[str, Any]:
         return arr.item() if arr.ndim == 0 else arr
 
     return jax.tree.map(unwrap, fetched)
+
+
+def _fetch(metrics) -> Dict[str, Any]:
+    """The loop's blocking fetch, as a ``train_fetch`` host annotation."""
+    with profiler.annotate(profiler.TRAIN_FETCH):
+        return _to_host(metrics)
 
 
 class NonFiniteLossError(RuntimeError):
@@ -174,10 +181,11 @@ class TrainLoop:
                            attempt=attempt)
                 obs.inc("retries")
 
-        (new_state, metrics), nan_hit = guarded_call(
-            self._train_step, self.state, batch, plan=self._faults,
-            site="train_step", retries=self._max_retries,
-            backoff_s=self._retry_backoff_s, on_retry=count)
+        with profiler.annotate(profiler.TRAIN_DISPATCH):
+            (new_state, metrics), nan_hit = guarded_call(
+                self._train_step, self.state, batch, plan=self._faults,
+                site="train_step", retries=self._max_retries,
+                backoff_s=self._retry_backoff_s, on_retry=count)
         self.state = new_state
         self._steps_dispatched += 1
         if nan_hit:
@@ -187,7 +195,7 @@ class TrainLoop:
             metrics[self._watchdog.loss_key if self._watchdog is not None
                     else "loss"] = float("nan")
         prev, self._pending = self._pending, metrics
-        out = None if prev is None else _to_host(prev)
+        out = None if prev is None else _fetch(prev)
         if obs is not None:
             # the deferred-metrics host span: this step's dispatch plus
             # the PREVIOUS step's fetch — exactly what the loop's
@@ -219,7 +227,7 @@ class TrainLoop:
         threshold first crossed by the LAST step's metrics still
         halts instead of returning a wedged run as success."""
         prev, self._pending = self._pending, None
-        out = None if prev is None else _to_host(prev)
+        out = None if prev is None else _fetch(prev)
         if out is not None:
             self._observe(out, raise_on_halt=raise_on_halt)
         return out

@@ -46,6 +46,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import profiler
 from apex_tpu.amp.handle import AmpHandle
 from apex_tpu.amp.scaler import LossScaler, ScalerState
 from apex_tpu.utils.collectives import compat_shard_map
@@ -308,23 +309,26 @@ class _StepCore:
         acc, loss_sum, inf_any = carry[:3]
         vg = self.scaler.value_and_grad(
             lambda p: self.loss_fn(p, mb), sst, has_aux=self.has_aux)
-        if self.has_aux:
-            (loss, found, aux), grads = vg(params)
-        else:
-            (loss, found), grads = vg(params)
-            aux = None
-        acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                           acc, grads)
-        loss_sum = loss_sum + loss.astype(jnp.float32)
-        inf_any = jnp.logical_or(inf_any, found)
+        with jax.named_scope(profiler.TRAIN_FWD_BWD):
+            if self.has_aux:
+                (loss, found, aux), grads = vg(params)
+            else:
+                (loss, found), grads = vg(params)
+                aux = None
+        with jax.named_scope(profiler.TRAIN_ACCUMULATE):
+            acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
+                               acc, grads)
+            loss_sum = loss_sum + loss.astype(jnp.float32)
+            inf_any = jnp.logical_or(inf_any, found)
         return (acc, loss_sum, inf_any), aux
 
     def zero_carry(self, params):
-        acc = jax.tree.map(lambda p: jnp.zeros(jnp.shape(p), jnp.float32),
-                           params)
-        if self.acc_constraint is not None:
-            acc = self.acc_constraint(acc)
-        return acc, jnp.zeros((), jnp.float32), jnp.zeros((), bool)
+        with jax.named_scope(profiler.TRAIN_ACCUMULATE):
+            acc = jax.tree.map(
+                lambda p: jnp.zeros(jnp.shape(p), jnp.float32), params)
+            if self.acc_constraint is not None:
+                acc = self.acc_constraint(acc)
+            return acc, jnp.zeros((), jnp.float32), jnp.zeros((), bool)
 
     # -- post-accumulation tail (identical in fused and reference) -------
 
@@ -343,14 +347,16 @@ class _StepCore:
     def apply(self, state: TrainState, acc, loss_sum, inf_any, aux=None):
         """Reduce, globalize the overflow flag, optimizer update, scaler
         update, metrics. Returns ``(new_state, metrics)``."""
-        grads = self.reduce_grads(acc)
+        with jax.named_scope(profiler.TRAIN_REDUCE):
+            grads = self.reduce_grads(acc)
         # Globalize the skip decision: a non-finite grad on ANY device /
         # microbatch is already non-finite in the reduced tree (inf
         # survives both the fp32 accumulate and the psum), so this one
         # check makes every device skip in lockstep — per-device local
         # flags alone would let replicas diverge under DDP.
-        found = jnp.logical_or(inf_any,
-                               jnp.logical_not(all_finite(grads)))
+        with jax.named_scope(profiler.AMP_FOUND_INF):
+            found = jnp.logical_or(inf_any,
+                                   jnp.logical_not(all_finite(grads)))
         lr = (None if self.lr_schedule is None
               else self.lr_schedule(state.step))
 
@@ -374,40 +380,43 @@ class _StepCore:
             _, ost, p = operands
             return p, ost
 
-        new_params, new_opt = jax.lax.cond(
-            found, _skip_branch, _apply_branch,
-            (grads, state.opt_state, state.params))
-        new_sst = self.scaler.update(state.scaler_state, found)
-        loss = loss_sum / jnp.asarray(self.accum_steps, jnp.float32)
-        if self.ddp is not None:
-            loss = jax.lax.pmean(loss, self.ddp.axis_name)
-        metrics = {
-            "loss": loss,
-            "loss_scale": state.scaler_state.loss_scale,  # scale USED
-            "skipped": found,
-            "steps_skipped": new_sst.steps_skipped,
-            "step": state.step + 1,
-        }
-        if self.with_grad_norm:
-            metrics["grad_norm"] = global_norm(grads)
-        if aux is not None:
+        with jax.named_scope(profiler.OPTIMIZER_UPDATE):
+            new_params, new_opt = jax.lax.cond(
+                found, _skip_branch, _apply_branch,
+                (grads, state.opt_state, state.params))
+        with jax.named_scope(profiler.AMP_UPDATE_SCALE):
+            new_sst = self.scaler.update(state.scaler_state, found)
+        with jax.named_scope(profiler.TRAIN_METRICS):
+            loss = loss_sum / jnp.asarray(self.accum_steps, jnp.float32)
             if self.ddp is not None:
-                # aux is device-varying (per-example values of THIS
-                # device's shard); the metrics out_spec is replicated, so
-                # without a gather one undefined device's slice would
-                # silently survive. Gather to an explicit leading device
-                # axis: [world, accum, ...local] — lossless and
-                # shape-predictable for any user aux pytree.
-                aux = jax.tree.map(
-                    lambda a: jax.lax.all_gather(a, self.ddp.axis_name),
-                    aux)
-            metrics["aux"] = aux
-        new_state = TrainState(
-            step=state.step + 1,
-            params=new_params,
-            opt_state=new_opt,
-            scaler_state=new_sst,
-        )
+                loss = jax.lax.pmean(loss, self.ddp.axis_name)
+            metrics = {
+                "loss": loss,
+                "loss_scale": state.scaler_state.loss_scale,  # scale USED
+                "skipped": found,
+                "steps_skipped": new_sst.steps_skipped,
+                "step": state.step + 1,
+            }
+            if self.with_grad_norm:
+                metrics["grad_norm"] = global_norm(grads)
+            if aux is not None:
+                if self.ddp is not None:
+                    # aux is device-varying (per-example values of THIS
+                    # device's shard); the metrics out_spec is replicated,
+                    # so without a gather one undefined device's slice
+                    # would silently survive. Gather to an explicit leading
+                    # device axis: [world, accum, ...local] — lossless and
+                    # shape-predictable for any user aux pytree.
+                    aux = jax.tree.map(
+                        lambda a: jax.lax.all_gather(a, self.ddp.axis_name),
+                        aux)
+                metrics["aux"] = aux
+            new_state = TrainState(
+                step=state.step + 1,
+                params=new_params,
+                opt_state=new_opt,
+                scaler_state=new_sst,
+            )
         return new_state, metrics
 
     # -- the fused single-dispatch program -------------------------------
@@ -431,8 +440,11 @@ class _StepCore:
             # already a barrier) and keeps the certification honest.
             return jax.lax.optimization_barrier(new_carry), aux
 
-        (acc, loss_sum, inf_any), aux = jax.lax.scan(
-            body, self.zero_carry(params), batch)
+        # the microbatch loop IS the accumulation: its own plumbing (carry
+        # copies, microbatch slices) is filed under the accumulate scope
+        with jax.named_scope(profiler.TRAIN_ACCUMULATE):
+            (acc, loss_sum, inf_any), aux = jax.lax.scan(
+                body, self.zero_carry(params), batch)
         if not self.has_aux:
             aux = None
         return self.apply(state, acc, loss_sum, inf_any, aux=aux)
