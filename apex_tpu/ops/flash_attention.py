@@ -15,7 +15,32 @@ TPU design notes:
 - The padding mask is a per-key boolean (True = masked), folded in with
   the same finite ``-30000`` fill the reference kernels use (finite so
   fully-masked rows degrade to a uniform distribution instead of NaN,
-  matching ``scaled_masked_softmax`` semantics).
+  matching ``scaled_masked_softmax`` semantics). Under ``causal=True``
+  past one tile, a row whose every causally visible key is user-masked
+  degrades to uniform over the keys of its LIVE tiles (below), not over
+  all Sk keys as the composed reference does; ``mha_reference`` is the
+  specification for every row with at least one visible key.
+- Causal attention past one tile skips the tiles the mask kills. The
+  mask is ``row >= col`` on absolute indices, so from ``program_id`` and
+  the static block sizes each score tile (iq, ik) of the multi-tile
+  kernels (fwd, dq, dkv) is dead (``ik*bk > iq*bq + bq - 1``), full
+  (``ik*bk + bk - 1 <= iq*bq``) or diagonal (``causal_tile_classes``
+  counts them: S=1024 at 512-blocks (dead 1, diagonal 2, full 1); 2048:
+  (6, 4, 6); 640 -> 768 at 384-blocks: (1, 2, 1)). On a dead tile the
+  body does not run (``pl.when``), and the index maps of the operands
+  that vary along the inner grid axis re-name the nearest live block (k,
+  v, key mask and the interpret-mode dropout bits in fwd/dq; q, do, lse,
+  delta and the bits in dkv), so the pipeline sees an unchanged block
+  index and issues no DMA. Such a tile contributed exactly 0
+  (``p = exp(FILL - m) = 0`` in fp32), so outputs, lse and gradients are
+  bit-identical. Full tiles run the diagonal tiles' body: a maskless
+  second body measured slower on the v5e (see the comment at
+  ``_causal_dead``). The grid's shape and order, ``_tile_id`` and so the
+  dropout stream are those of the unskipped kernel;
+  ``_init``/``_finish`` stay tied to the first and last inner step, dead
+  or not.
+- "No key mask" is static: with ``key_mask=None`` and no key padding the
+  multi-tile kernels are built without the two key-mask selects.
 - Forward also emits the per-row logsumexp; backward recomputes score
   tiles from (q, k, lse) instead of saving probabilities — the flash
   rematerialization. Two kernels: dq (grid over q blocks, accumulating
@@ -114,17 +139,107 @@ def _keep_mask(drop_ref, tile_id, bq, bk, dropout_rate, native_prng,
 
 
 # ---------------------------------------------------------------------------
+# causal tile classes (multi-tile kernels)
+# ---------------------------------------------------------------------------
+#
+# Under ``causal=True`` the mask is ``row >= col`` on absolute indices, so a
+# (bq, bk) score tile (iq, ik) is dead (every element masked), full (none
+# masked) or diagonal. The kernels act on ``dead`` alone: a full tile runs
+# the diagonal tile's body (its mask select is the identity). A second,
+# maskless body for full tiles was built and measured on the v5e (PR 28):
+# the mask ops are hidden under the tile step's real bound, and the second
+# body cost 0.3% of GPT-2-medium's step and 12.7 MB of program memory.
+# The predicates take Python ints (the static count below, the tests) or
+# traced ``program_id``s (kernel bodies, index maps).
+
+def _causal_dead(iq, ik, bq, bk):
+    """Tile (iq, ik) lies wholly above the diagonal: its smallest column
+    is beyond its largest row."""
+    return ik * bk > iq * bq + bq - 1
+
+
+def _causal_full(iq, ik, bq, bk):
+    """Tile (iq, ik) lies wholly on or below the diagonal: its largest
+    column is not beyond its smallest row."""
+    return ik * bk + bk - 1 <= iq * bq
+
+
+def causal_tile_classes(Sq, Sk, bq, bk):
+    """``(dead, diagonal, full)`` tile counts of one head's causal score
+    matrix at block sizes (bq, bk): the multi-tile kernels skip the dead
+    ones (no compute, no DMA) and run the rest. Static in the shapes."""
+    tiles = [(iq, ik) for iq in range(-(-Sq // bq))
+             for ik in range(-(-Sk // bk))]
+    dead = sum(bool(_causal_dead(iq, ik, bq, bk)) for iq, ik in tiles)
+    full = sum(bool(_causal_full(iq, ik, bq, bk)) for iq, ik in tiles)
+    return dead, len(tiles) - dead - full, full
+
+
+def _live_k(causal, iq, ik, bq, bk):
+    """Key-block index the q-major kernels (fwd, dq) fetch at step
+    (iq, ik): a dead step re-names the row's last live block, so the
+    pipeline sees an unchanged block index and issues no DMA."""
+    if not causal:
+        return ik
+    return jnp.where(_causal_dead(iq, ik, bq, bk),
+                     (iq * bq + bq - 1) // bk, ik)
+
+
+def _live_q(causal, iq, ik, bq, bk, nq):
+    """Query-block index the k-major kernel (dkv) fetches at step
+    (ik, iq): a dead step re-names the column's first live block (kept
+    inside the grid where Sq < Sk leaves a key block no live tile)."""
+    if not causal:
+        return iq
+    return jnp.where(_causal_dead(iq, ik, bq, bk),
+                     jnp.minimum((ik * bk) // bq, nq - 1), iq)
+
+
+def _on_live_tile(causal, iq, ik, bq, bk, body):
+    """Run ``body()`` for tile (iq, ik) unless the causal mask kills it."""
+    if not causal:
+        body()
+    else:
+        pl.when(jnp.logical_not(_causal_dead(iq, ik, bq, bk)))(body)
+
+
+def _score_tile(q, k, mask_ref, iq, ik, *, scale, causal, bq, bk, has_mask):
+    """fp32 (bq, bk) masked scores of tile (iq, ik), and the key-mask row
+    (None when the call has neither a user mask nor key padding).
+
+    mask codes: 0 = live, 1 = user-masked (finite FILL — a fully-masked
+    row degrades to uniform over the TRUE keys), 2 = wrapper padding
+    (excluded from the distribution entirely, else an unaligned Sk
+    inflates the denominator by Skp/Sk)."""
+    s = _dot(q, k, ((1,), (1,)), _prec(q.dtype)) * scale
+    mrow = None
+    if has_mask:
+        mrow = mask_ref[0, 0][None, :]             # (1, bk) -> broadcast
+        s = jnp.where(mrow != 0, FILL, s)
+    if causal:
+        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
+        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
+        s = jnp.where(row >= col, s, FILL)
+    return s, mrow
+
+
+def _zero_padded_keys(p, mrow):
+    """Padded keys (code 2) get p exactly 0."""
+    return p if mrow is None else jnp.where(mrow >= 2, 0.0, p)
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
-                dropout_rate=0.0, native_prng=True):
+                has_mask=True, dropout_rate=0.0, native_prng=True):
     if dropout_rate > 0.0:
         drop_ref, o_ref, lse_ref, acc_s, m_s, l_s = rest
     else:
         drop_ref, (o_ref, lse_ref, acc_s, m_s, l_s) = None, rest
     b, hh = pl.program_id(0), pl.program_id(1)
-    ik = pl.program_id(3)
+    iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
     @pl.when(ik == 0)
@@ -133,44 +248,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    q = q_ref[0, 0]                                # (bq, D)
-    k = k_ref[0, 0]                                # (bk, D)
-    prec = _prec(q.dtype)
-    s = _dot(q, k, ((1,), (1,)), prec) * scale     # (bq, bk)
+    def _tile():
+        q = q_ref[0, 0]                            # (bq, D)
+        k = k_ref[0, 0]                            # (bk, D)
+        prec = _prec(q.dtype)
+        s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
+                              causal=causal, bq=bq, bk=bk, has_mask=has_mask)
 
-    # mask codes: 0 = live, 1 = user-masked (finite FILL — a fully-masked
-    # row degrades to uniform over the TRUE keys), 2 = wrapper padding
-    # (excluded from the distribution entirely, else an unaligned Sk
-    # inflates the denominator by Skp/Sk)
-    mrow = mask_ref[0, 0][None, :]                 # (1, bk) -> broadcast
-    s = jnp.where(mrow != 0, FILL, s)
-    if causal:
-        iq = pl.program_id(2)
-        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
-        s = jnp.where(row >= col, s, FILL)
+        m_prev = m_s[:, :1]                        # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = _zero_padded_keys(jnp.exp(s - m_new), mrow)   # (bq, bk)
+        alpha = jnp.exp(m_prev - m_new)            # (bq, 1)
+        l_new = alpha * l_s[:, :1] + jnp.sum(p, axis=1, keepdims=True)
 
-    m_prev = m_s[:, :1]                            # (bq, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                         # (bq, bk)
-    p = jnp.where(mrow >= 2, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)                # (bq, 1)
-    l_new = alpha * l_s[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, 0]                            # (bk, D)
+        # dropout multiplies only the p @ v path; m/l/lse stay pre-dropout
+        # so the final acc/l equals composed dropout(softmax) @ v by
+        # linearity
+        if dropout_rate > 0.0:
+            tid = _tile_id(b, hh, iq, ik, pl.num_programs(1),
+                           pl.num_programs(2), nk)
+            keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate,
+                              native_prng)
+            p_av = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
+        else:
+            p_av = p
+        pv = _dot(p_av.astype(v.dtype), v, ((1,), (0,)), prec)
+        acc_s[:] = acc_s[:] * alpha + pv
+        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
-    v = v_ref[0, 0]                                # (bk, D)
-    # dropout multiplies only the p @ v path; m/l/lse stay pre-dropout so
-    # the final acc/l equals composed dropout(softmax) @ v by linearity
-    if dropout_rate > 0.0:
-        tid = _tile_id(b, hh, pl.program_id(2), ik, pl.num_programs(1),
-                       pl.num_programs(2), nk)
-        keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate, native_prng)
-        p_av = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
-    else:
-        p_av = p
-    pv = _dot(p_av.astype(v.dtype), v, ((1,), (0,)), prec)
-    acc_s[:] = acc_s[:] * alpha + pv
-    m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-    l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+    _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -224,48 +332,45 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                   *rest, scale, causal, bq, bk,
+                   *rest, scale, causal, bq, bk, has_mask=True,
                    dropout_rate=0.0, native_prng=True):
     if dropout_rate > 0.0:
         drop_ref, dq_ref, dq_s = rest
     else:
         drop_ref, (dq_ref, dq_s) = None, rest
     b, hh = pl.program_id(0), pl.program_id(1)
-    ik = pl.program_id(3)
+    iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
     @pl.when(ik == 0)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    prec = _prec(q.dtype)
-    s = _dot(q, k, ((1,), (1,)), prec) * scale
-    mrow = mask_ref[0, 0][None, :]
-    s = jnp.where(mrow != 0, FILL, s)
-    if causal:
-        iq = pl.program_id(2)
-        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
-        s = jnp.where(row >= col, s, FILL)
+    def _tile():
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        prec = _prec(q.dtype)
+        s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
+                              causal=causal, bq=bq, bk=bk, has_mask=has_mask)
 
-    lse = lse_ref[0, 0, 0][:, None]                # (bq, 1)
-    p = jnp.exp(s - lse)                           # (bq, bk)
-    p = jnp.where(mrow >= 2, 0.0, p)               # padded keys: p exactly 0
-    do = do_ref[0, 0]                              # (bq, D)
-    v = v_ref[0, 0]                                # (bk, D)
-    dp = _dot(do, v, ((1,), (1,)), prec)
-    if dropout_rate > 0.0:
-        # replay the forward's exact keep-mask onto dp (dP = mask/keep *
-        # dO·V); delta already carries the dropout through O
-        tid = _tile_id(b, hh, pl.program_id(2), ik, pl.num_programs(1),
-                       pl.num_programs(2), nk)
-        keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate, native_prng)
-        dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout_rate))
-    delta = delta_ref[0, 0, 0][:, None]            # (bq, 1)
-    ds = p * (dp - delta) * scale                  # (bq, bk)
-    dq_s[:] = dq_s[:] + _dot(ds.astype(k.dtype), k, ((1,), (0,)), prec)
+        lse = lse_ref[0, 0, 0][:, None]            # (bq, 1)
+        p = _zero_padded_keys(jnp.exp(s - lse), mrow)     # (bq, bk)
+        do = do_ref[0, 0]                          # (bq, D)
+        v = v_ref[0, 0]                            # (bk, D)
+        dp = _dot(do, v, ((1,), (1,)), prec)
+        if dropout_rate > 0.0:
+            # replay the forward's exact keep-mask onto dp (dP = mask/keep
+            # * dO·V); delta already carries the dropout through O
+            tid = _tile_id(b, hh, iq, ik, pl.num_programs(1),
+                           pl.num_programs(2), nk)
+            keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate,
+                              native_prng)
+            dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout_rate))
+        delta = delta_ref[0, 0, 0][:, None]        # (bq, 1)
+        ds = p * (dp - delta) * scale              # (bq, bk)
+        dq_s[:] = dq_s[:] + _dot(ds.astype(k.dtype), k, ((1,), (0,)), prec)
+
+    _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -322,14 +427,14 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                    *rest, scale, causal, bq, bk,
+                    *rest, scale, causal, bq, bk, has_mask=True,
                     dropout_rate=0.0, native_prng=True):
     if dropout_rate > 0.0:
         drop_ref, dk_ref, dv_ref, dk_s, dv_s = rest
     else:
         drop_ref, (dk_ref, dv_ref, dk_s, dv_s) = None, rest
     b, hh = pl.program_id(0), pl.program_id(1)
-    iq = pl.program_id(3)
+    ik, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
 
     @pl.when(iq == 0)
@@ -337,41 +442,39 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    q = q_ref[0, 0]                                # (bq, D)
-    k = k_ref[0, 0]                                # (bk, D)
-    prec = _prec(q.dtype)
-    s = _dot(q, k, ((1,), (1,)), prec) * scale
-    mrow = mask_ref[0, 0][None, :]
-    s = jnp.where(mrow != 0, FILL, s)
-    if causal:
-        ik = pl.program_id(2)
-        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
-        s = jnp.where(row >= col, s, FILL)
+    def _tile():
+        q = q_ref[0, 0]                            # (bq, D)
+        k = k_ref[0, 0]                            # (bk, D)
+        prec = _prec(q.dtype)
+        s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
+                              causal=causal, bq=bq, bk=bk, has_mask=has_mask)
 
-    lse = lse_ref[0, 0, 0][:, None]
-    p = jnp.exp(s - lse)                           # (bq, bk)
-    p = jnp.where(mrow >= 2, 0.0, p)               # padded keys: p exactly 0
-    do = do_ref[0, 0]                              # (bq, D)
-    v = v_ref[0, 0]
-    dp = _dot(do, v, ((1,), (1,)), prec)
-    if dropout_rate > 0.0:
-        # seed with (iq, ik) — the same tile coordinates the forward
-        # used — even though this kernel's grid iterates (ik, iq)
-        tid = _tile_id(b, hh, iq, pl.program_id(2), pl.num_programs(1),
-                       nq, pl.num_programs(2))
-        keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate, native_prng)
-        inv_keep = 1.0 / (1.0 - dropout_rate)
-        p_av = jnp.where(keep, p, 0.0) * inv_keep
-        dp = jnp.where(keep, dp, 0.0) * inv_keep
-    else:
-        p_av = p
-    # dv += dropout(p)^T @ do
-    dv_s[:] = dv_s[:] + _dot(p_av.astype(do.dtype), do, ((0,), (0,)), prec)
-    delta = delta_ref[0, 0, 0][:, None]
-    ds = p * (dp - delta) * scale                  # (bq, bk)
-    # dk += ds^T @ q
-    dk_s[:] = dk_s[:] + _dot(ds.astype(q.dtype), q, ((0,), (0,)), prec)
+        lse = lse_ref[0, 0, 0][:, None]
+        p = _zero_padded_keys(jnp.exp(s - lse), mrow)     # (bq, bk)
+        do = do_ref[0, 0]                          # (bq, D)
+        v = v_ref[0, 0]
+        dp = _dot(do, v, ((1,), (1,)), prec)
+        if dropout_rate > 0.0:
+            # seed with (iq, ik) — the same tile coordinates the forward
+            # used — even though this kernel's grid iterates (ik, iq)
+            tid = _tile_id(b, hh, iq, ik, pl.num_programs(1), nq,
+                           pl.num_programs(2))
+            keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate,
+                              native_prng)
+            inv_keep = 1.0 / (1.0 - dropout_rate)
+            p_av = jnp.where(keep, p, 0.0) * inv_keep
+            dp = jnp.where(keep, dp, 0.0) * inv_keep
+        else:
+            p_av = p
+        # dv += dropout(p)^T @ do
+        dv_s[:] = dv_s[:] + _dot(p_av.astype(do.dtype), do, ((0,), (0,)),
+                                 prec)
+        delta = delta_ref[0, 0, 0][:, None]
+        ds = p * (dp - delta) * scale              # (bq, bk)
+        # dk += ds^T @ q
+        dk_s[:] = dk_s[:] + _dot(ds.astype(q.dtype), q, ((0,), (0,)), prec)
+
+    _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -399,11 +502,26 @@ def _drop_arg(drop_in, bq, bk, index_map):
     return [drop_in], [pl.BlockSpec((1, 1, bq, bk), index_map)]
 
 
-def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk,
+def _q_major_maps(causal, bq, bk):
+    """Index maps on the q-major grid ``(b, h, iq, ik)`` of fwd and dq for
+    the operands blocked along keys: (k / v, key mask, interpret-mode
+    dropout bits). Under ``causal`` a dead step keeps the row's last live
+    block (``_live_k``)."""
+    def live(iq, ik):
+        return _live_k(causal, iq, ik, bq, bk)
+
+    return (lambda b, h, iq, ik: (b, h, live(iq, ik), 0),
+            lambda b, h, iq, ik: (b, 0, live(iq, ik)),
+            lambda b, h, iq, ik: (b, h, iq, live(iq, ik)))
+
+
+def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
                     dropout_rate=0.0, drop_in=None):
+    """``has_mask=False`` (static: no user key mask, no key padding, so
+    ``mask`` is all zeros) builds the multi-tile kernel without its two
+    key-mask selects; the single-tile kernel ignores it."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    grid = (B, H, Sq // bq, Sk // bk)
     native = drop_in is not None and drop_in.ndim == 1
 
     if Sq == bq and Sk == bk:
@@ -432,19 +550,18 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk,
             name="flash_fwd",
             interpret=_interpret(),
         )(q, k, v, mask, *extra)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-        dropout_rate=dropout_rate, native_prng=native)
-    extra, extra_specs = _drop_arg(drop_in, bq, bk,
-                                   lambda b, h, iq, ik: (b, h, iq, ik))
+    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk)
+    extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
+                          bk=bk, has_mask=has_mask,
+                          dropout_rate=dropout_rate, native_prng=native),
+        grid=(B, H, Sq // bq, Sk // bk),
         in_specs=[
             _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
-            _spec4(bk, D, lambda b, h, iq, ik: (b, h, ik, 0)),
-            _spec4(bk, D, lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, iq, ik: (b, 0, ik)),
+            _spec4(bk, D, kv_map),
+            _spec4(bk, D, kv_map),
+            pl.BlockSpec((1, 1, bk), mask_map),
         ] + extra_specs,
         out_specs=(
             _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
@@ -466,7 +583,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk,
 
 
 def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
-                    dropout_rate=0.0, drop_in=None):
+                    has_mask=True, dropout_rate=0.0, drop_in=None):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     native = drop_in is not None and drop_in.ndim == 1
@@ -503,18 +620,20 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             interpret=_interpret(),
         )(q, k, v, mask, do, lse, delta, *extra)
 
-    extra, extra_specs = _drop_arg(drop_in, bq, bk,
-                                   lambda b, h, iq, ik: (b, h, iq, ik))
+    nq = Sq // bq
+    kern = dict(scale=scale, causal=causal, bq=bq, bk=bk, has_mask=has_mask,
+                dropout_rate=dropout_rate, native_prng=native)
+
+    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk)
+    extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, dropout_rate=dropout_rate,
-                          native_prng=native),
-        grid=(B, H, Sq // bq, Sk // bk),
+        functools.partial(_bwd_dq_kernel, **kern),
+        grid=(B, H, nq, Sk // bk),
         in_specs=[
             _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
-            _spec4(bk, D, lambda b, h, iq, ik: (b, h, ik, 0)),
-            _spec4(bk, D, lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, iq, ik: (b, 0, ik)),
+            _spec4(bk, D, kv_map),
+            _spec4(bk, D, kv_map),
+            pl.BlockSpec((1, 1, bk), mask_map),
             _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
@@ -526,21 +645,30 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
         interpret=_interpret(),
     )(q, k, v, mask, do, lse, delta, *extra)
 
-    extra, extra_specs = _drop_arg(drop_in, bq, bk,
-                                   lambda b, h, ik, iq: (b, h, iq, ik))
+    # k-major grid (ik outer, iq inner): a dead step keeps the column's
+    # first live q/do/lse/delta/bits block
+    def live(ik, iq):
+        return _live_q(causal, iq, ik, bq, bk, nq)
+
+    def q_map(b, h, ik, iq):
+        return (b, h, live(ik, iq), 0)
+
+    def row_map(b, h, ik, iq):
+        return (b, h, 0, live(ik, iq))
+
+    extra, extra_specs = _drop_arg(
+        drop_in, bq, bk, lambda b, h, ik, iq: (b, h, live(ik, iq), ik))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, dropout_rate=dropout_rate,
-                          native_prng=native),
-        grid=(B, H, Sk // bk, Sq // bq),
+        functools.partial(_bwd_dkv_kernel, **kern),
+        grid=(B, H, Sk // bk, nq),
         in_specs=[
-            _spec4(bq, D, lambda b, h, ik, iq: (b, h, iq, 0)),
+            _spec4(bq, D, q_map),
             _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
             _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk), lambda b, h, ik, iq: (b, 0, ik)),
-            _spec4(bq, D, lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, ik, iq: (b, h, 0, iq)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, ik, iq: (b, h, 0, iq)),
+            _spec4(bq, D, q_map),
+            pl.BlockSpec((1, 1, 1, bq), row_map),
+            pl.BlockSpec((1, 1, 1, bq), row_map),
         ] + extra_specs,
         out_specs=(
             _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
@@ -588,6 +716,12 @@ def _pad_inputs(q, k, v, key_mask, bq, bk):
         # row's uniform fallback, matching the composed reference)
         mask = jnp.pad(mask, ((0, 0), (0, 0), (0, Skp - Sk)), constant_values=2)
     return q, k, v, mask
+
+
+def _has_mask(key_mask, Sk, bk):
+    """Static: does ``_pad_inputs``' mask hold anything but zeros? Not
+    when the caller gave no key mask and Sk needs no padding."""
+    return key_mask is not None or Sk % bk != 0
 
 
 # Relative per-FLOP cost of a block size (v5e measurement: 512-blocks
@@ -761,8 +895,9 @@ def _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate=0.0,
     drop_in = _drop_input(dropout_rate, dropout_seed, B, H,
                           qp.shape[2], kp.shape[2])
     out, lse = _flash_fwd_call(qp, kp, vp, mask, scale=scale, causal=causal,
-                               bq=bq, bk=bk, dropout_rate=dropout_rate,
-                               drop_in=drop_in)
+                               bq=bq, bk=bk,
+                               has_mask=_has_mask(key_mask, Sk, bk),
+                               dropout_rate=dropout_rate, drop_in=drop_in)
     return out[:, :, :Sq, :D], lse
 
 
@@ -804,6 +939,7 @@ def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
         delta = delta - glp.astype(jnp.float32)
     dq, dk, dv = _flash_bwd_call(qp, kp, vp, mask, gp, lse_padded, delta,
                                  scale=scale, causal=causal, bq=bq, bk=bk,
+                                 has_mask=_has_mask(key_mask, Sk, bk),
                                  dropout_rate=dropout_rate, drop_in=drop_in)
     return (match_vma(dq[:, :, :Sq, :D].astype(q.dtype), q),
             match_vma(dk[:, :, :Sk, :D].astype(k.dtype), k),

@@ -9,8 +9,9 @@ import pytest
 
 # Interpret-mode Pallas kernels on CPU are the suite's dominant cost
 # (~5 min for this tier alone); fast CI runs -m "not slow", the full
-# run and the on-TPU tier keep the coverage.
-pytestmark = pytest.mark.slow
+# run and the on-TPU tier keep the coverage. The causal multi-tile
+# cases at the end of the file are NOT slow-marked: they run in tier 1.
+slow = pytest.mark.slow
 
 from apex_tpu.ops.flash_attention import flash_attention, mha_reference
 
@@ -34,6 +35,7 @@ def _max_err(a, b):
     ((1, 1, 37, 32), True, False),       # unaligned seq + head dim
     ((1, 2, 640, 64), False, True),      # multi-block online softmax
 ])
+@slow
 def test_parity_fwd_bwd(shape, causal, use_mask):
     B, H, S, D = shape
     q, k, v = _mk(B, H, S, S, D)
@@ -58,6 +60,7 @@ def test_parity_fwd_bwd(shape, causal, use_mask):
         assert _max_err(a, b) < 3e-4
 
 
+@slow
 @pytest.mark.parametrize("S", [128, 100, 37])
 def test_fully_masked_rows_are_finite(S):
     """All keys masked -> uniform distribution (finite), matching the
@@ -73,6 +76,7 @@ def test_fully_masked_rows_are_finite(S):
     assert _max_err(out, ref) < 2e-5
 
 
+@slow
 def test_bf16_io_fp32_accumulation():
     q, k, v = _mk(2, 2, 256, 256, 64, jnp.bfloat16)
     out = flash_attention(q, k, v, None, False, 0.125)
@@ -82,6 +86,7 @@ def test_bf16_io_fp32_accumulation():
     assert _max_err(out, ref) < 0.02
 
 
+@slow
 def test_bert_model_flash_matches_composed():
     """Model-level: BertModel with the flash path forced on vs off."""
     from apex_tpu.models import BertConfig, BertForPreTraining
@@ -120,6 +125,7 @@ def test_bert_model_flash_matches_composed():
     assert max(jax.tree.leaves(errs)) < 5e-3
 
 
+@slow
 def test_flash_attention_with_lse_fwd_bwd():
     """(out, lse) variant: lse matches composed logsumexp, and grads are
     correct INCLUDING a live lse cotangent (the ring-merge consumer)."""
@@ -151,6 +157,7 @@ def test_flash_attention_with_lse_fwd_bwd():
 
 # ------------------------------------------------------------- dropout
 
+@slow
 def test_dropout_parity_with_extracted_mask():
     """Fused dropout == composed attention using the kernel's OWN
     keep-mask (flash_dropout_keep_mask reproduces the in-kernel bits
@@ -186,6 +193,7 @@ def test_dropout_parity_with_extracted_mask():
         assert _max_err(a, b) < 3e-4
 
 
+@slow
 def test_dropout_parity_unaligned_multiblock():
     """Dropout mask replay across tile boundaries: unaligned S forces
     padding, S=640 forces the multi-block online-softmax recurrence."""
@@ -220,6 +228,7 @@ def test_dropout_parity_unaligned_multiblock():
             assert _max_err(a, b) < 3e-4
 
 
+@slow
 def test_dropout_mask_statistics_and_seed_sensitivity():
     """Keep-rate ~= 1-rate; different seeds give different masks; the
     same seed is deterministic."""
@@ -236,6 +245,7 @@ def test_dropout_mask_statistics_and_seed_sensitivity():
     assert abs(keep_frac - (1 - rate)) < 0.01
 
 
+@slow
 def test_dropout_zero_rate_matches_no_dropout():
     B, H, S, D = 1, 2, 128, 64
     q, k, v = _mk(B, H, S, S, D)
@@ -244,6 +254,7 @@ def test_dropout_zero_rate_matches_no_dropout():
     assert _max_err(a, b) == 0.0
 
 
+@slow
 def test_dropout_requires_seed():
     B, H, S, D = 1, 1, 128, 64
     q, k, v = _mk(B, H, S, S, D)
@@ -269,6 +280,7 @@ def _bsh_ref(q, k, v, NH, causal, scale, rate=0.0, seed=None, km=None):
     return out.transpose(0, 2, 1, 3).reshape(B, S, H)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("rate,seed", [(0.0, None), (0.1, 42)])
 def test_bsh_entry_matches_transposed(causal, rate, seed):
@@ -298,6 +310,7 @@ def test_bsh_entry_matches_transposed(causal, rate, seed):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@slow
 def test_bsh_entry_unaligned_seq_and_mask():
     from apex_tpu.ops.flash_attention import flash_attention_bsh
 
@@ -311,6 +324,7 @@ def test_bsh_entry_unaligned_seq_and_mask():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+@slow
 def test_bsh_entry_fallback_paths():
     """Configs the head-group kernels can't take (odd NH at D=64, or a
     multi-tile sequence) must transparently fall back to the transposed
@@ -336,3 +350,148 @@ def test_bsh_entry_fallback_paths():
     ref = _bsh_ref(q, k, v, NH, True, 0.125, 0.1, 7)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
+
+
+# ------------------------------------- causal tile classes (multi-tile)
+
+def _no_tile_skipping(monkeypatch):
+    """The parent's kernels: every tile computed under the causal mask,
+    every block fetched. Reached by patching the predicate the kernel
+    bodies and the index maps consult - not an option of the program.
+    jit caches traces by function, so this hands back a wrapper that is
+    traced anew, under the patched predicate."""
+    import importlib
+
+    # by module path: ``apex_tpu.ops`` re-exports the function under the
+    # module's name
+    mod = importlib.import_module("apex_tpu.ops.flash_attention")
+    monkeypatch.setattr(mod, "_causal_dead", lambda iq, ik, bq, bk: False)
+    return lambda f: jax.jit(lambda *args: f(*args))
+
+
+def _causal_case(Sq, Sk, use_mask, rate, masked_keys=()):
+    from apex_tpu.ops.flash_attention import flash_attention_with_lse
+
+    B, H, D = 1, 1, 64
+    q, k, v = _mk(B, H, Sq, Sk, D, seed=11)
+    g = jax.random.normal(jax.random.PRNGKey(12), q.shape, q.dtype)
+    km = None
+    if use_mask:
+        # key 0 stays visible: every row keeps a causally visible key
+        km = (jax.random.uniform(jax.random.PRNGKey(9), (B, Sk)) < 0.3
+              ).at[:, 0].set(False)
+    if masked_keys:
+        km = jnp.zeros((B, Sk), bool).at[:, jnp.asarray(masked_keys)].set(
+            True)
+    seed = 77 if rate else None
+    scale = 1.0 / np.sqrt(D)
+
+    def kernel_all(q, k, v):
+        """(out, lse, dq, dk, dv) with a live lse cotangent."""
+        (o, lse), vjp = jax.vjp(
+            lambda q, k, v: flash_attention_with_lse(
+                q, k, v, km, True, scale, rate, seed), q, k, v)
+        return (o, lse) + vjp((g, jnp.cos(lse)))
+
+    return (B, H, D, q, k, v, g, km, seed, scale), kernel_all
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.1])
+@pytest.mark.parametrize("use_mask", [False, True])
+@pytest.mark.parametrize("Sq,Sk", [
+    (1024, 1024),   # (512, 512) blocks: 1 dead, 2 diagonal, 1 full
+    (640, 640),     # pads to 768 at 384-blocks: 1 dead, 2 diagonal, 1 full
+    (1024, 512),    # Sq > Sk: no dead tile, the second row block is full
+    (512, 1024),    # Sq < Sk: the second key block has no live tile
+])
+def test_causal_multi_tile_parity_and_bit_equality(Sq, Sk, use_mask, rate,
+                                                   monkeypatch):
+    """Causal attention past one tile: dead tiles skipped (no compute, no
+    fetch), no key-mask selects without a mask - forward, lse and the
+    three gradients against the composed reference, and bit-equal to the
+    parent's kernels."""
+    from apex_tpu.ops.flash_attention import (
+        _with_lse_reference,
+        flash_dropout_keep_mask,
+        mha_with_mask_reference,
+    )
+
+    (B, H, D, q, k, v, g, km, seed, scale), kernel_all = _causal_case(
+        Sq, Sk, use_mask, rate)
+    got = jax.jit(kernel_all)(q, k, v)
+
+    keep = (flash_dropout_keep_mask(B, H, Sq, Sk, rate, seed) if rate
+            else jnp.ones((B, H, Sq, Sk), bool))
+
+    def ref_out(q, k, v):
+        if not rate:
+            return mha_reference(q, k, v, km, True, scale)
+        return mha_with_mask_reference(q, k, v, keep, km, True, scale, rate)
+
+    assert _max_err(got[0], ref_out(q, k, v)) < 2e-5
+    _, ref_lse = _with_lse_reference(q, k, v, km, True, scale)
+    assert got[1].shape == (B, H, 1, Sq)
+    assert _max_err(got[1], ref_lse) < 2e-5
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) * 1.3)
+
+    gk = jax.jit(jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, km, True, scale, rate, seed)), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(ref_out), argnums=(0, 1, 2)))(q, k, v)
+    for a, b in zip(gk, gr):
+        assert _max_err(a, b) < 3e-4
+
+    parent = _no_tile_skipping(monkeypatch)(kernel_all)(q, k, v)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, parent):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+def test_causal_row_with_no_visible_key_is_uniform_over_its_live_tiles(
+        monkeypatch):
+    """Keys 0..9 user-masked under causal=True: rows 0..9 see no key.
+    They degrade to uniform over the keys of their LIVE tiles (key block
+    0 of two) - the composed reference's uniform runs over all 1024 keys,
+    future ones included, as the parent's kernels did (which also shows
+    that ``_no_tile_skipping`` reaches them); every other row matches it."""
+    S = 1024
+    (_, _, _, q, k, v, _, km, _, scale), kernel_all = _causal_case(
+        S, S, False, 0.0, masked_keys=range(10))
+    out = jax.jit(kernel_all)(q, k, v)[0]
+    assert bool(jnp.all(jnp.isfinite(out)))
+    ref = mha_reference(q, k, v, km, True, scale)
+    assert _max_err(out[:, :, 10:], ref[:, :, 10:]) < 2e-5
+    uniform = jnp.mean(v[:, :, :512], axis=2, keepdims=True)
+    assert _max_err(out[:, :, :10], jnp.broadcast_to(
+        uniform, out[:, :, :10].shape)) < 2e-5
+    parent = _no_tile_skipping(monkeypatch)(kernel_all)(q, k, v)[0]
+    assert _max_err(parent, ref) < 2e-5
+    assert _max_err(parent[:, :, :10], out[:, :, :10]) > 1e-3
+
+
+@pytest.mark.parametrize("Sq,Sk,bq,bk,expected", [
+    (1024, 1024, 512, 512, (1, 2, 1)),
+    (2048, 2048, 512, 512, (6, 4, 6)),
+    (640, 640, 384, 384, (1, 2, 1)),      # 640 pads to 768
+    (1024, 512, 512, 512, (0, 1, 1)),
+    (512, 1024, 512, 512, (1, 1, 0)),
+    (1024, 1024, 256, 512, None),
+    (1536, 1024, 384, 512, None),
+    (768, 1280, 384, 256, None),
+    (1024, 1024, 512, 128, None),
+])
+def test_causal_tile_classes_match_brute_force(Sq, Sk, bq, bk, expected):
+    """The predicates (the kernels skip on ``dead``), counted over the
+    grid, against a classification read off the ``row >= col`` matrix."""
+    from apex_tpu.ops.flash_attention import causal_tile_classes
+
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    visible = np.arange(nq * bq)[:, None] >= np.arange(nk * bk)[None, :]
+    tiles = visible.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    dead = int((~tiles.any(axis=(2, 3))).sum())
+    full = int(tiles.all(axis=(2, 3)).sum())
+    brute = (dead, nq * nk - dead - full, full)
+    assert causal_tile_classes(Sq, Sk, bq, bk) == brute
+    if expected is not None:
+        assert brute == expected

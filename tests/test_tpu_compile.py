@@ -134,6 +134,22 @@ def _flash_case(sh):
                _spec((), jnp.int32, sh)), 2
 
 
+def _flash_causal_1024_case(sh):
+    """GPT-2-medium's attention call (``gpt2_medium.lm1024``): causal,
+    no key mask, past one tile — forward + the split dq / dkv backward,
+    each with its dead tiles skipped and their DMA clamped away."""
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    def f(q, k, v, seed):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, None, True, D ** -0.5, 0.1, seed)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    qkv = _spec((8, NH, 1024, D), jnp.bfloat16, sh)
+    return f, (qkv, qkv, qkv, _spec((), jnp.int32, sh)), 3
+
+
 def _flash_bsh_case(sh):
     from apex_tpu.ops.flash_attention import flash_attention_bsh
 
@@ -184,6 +200,7 @@ def _dropout_case(sh):
 _CASES = {
     "layer_norm": _ln_case,
     "flash_attention": _flash_case,
+    "flash_attention_causal_1024": _flash_causal_1024_case,
     "flash_attention_bsh": _flash_bsh_case,
     "flash_dropout_keep_mask": _keep_mask_case,
     "scaled_masked_softmax": _softmax_case,
