@@ -16,7 +16,12 @@ comparison, at the cell's own size, on several seeds in one process.
   sound runs the lower readings come from. Needs the cell's chips.
 
 The benchmark's own runs never run this. It needs one chip at most (the
-shards of a four-chip cell are taken one after the other).
+shards of a four-chip cell are taken one after the other), but for
+``program``. So that a four-chip machine is held for the program alone,
+``--no-reference --save DIR`` runs the named variants only and writes
+each side's numbers to ``DIR/<cell>.s<seed>.<side>.json``; a later call
+on one chip with ``--load DIR`` holds what it finds there against the
+reference it computes.
 """
 
 from __future__ import annotations
@@ -96,12 +101,47 @@ class _Program:
                 "grad": finish(metrics[0]), "change": change}
 
 
+def _save(directory, cell, seed, side, numbers):
+    directory.mkdir(parents=True, exist_ok=True)
+    plain = {"loss": [float(x) for x in numbers["loss"]]}
+    for part in ("grad", "change"):
+        plain[part] = {n: np.asarray(a).tolist()
+                       for n, a in numbers[part].items()}
+    (directory / f"{cell}.s{seed}.{side}.json").write_text(json.dumps(plain))
+
+
+def _load(directory, cell, seed, side):
+    path = directory / f"{cell}.s{seed}.{side}.json"
+    if not path.exists():
+        return None
+    got = json.loads(path.read_text())
+    for part in ("grad", "change"):
+        got[part] = {n: np.asarray(a) for n, a in got[part].items()}
+    return got
+
+
+def _report(seed, side, verdict, seconds):
+    numbers = verdict["numbers"]
+    print(f"seed {seed} {side}: correct={verdict['correct']} "
+          + json.dumps({n: r["value"] for n, r in numbers.items()})
+          + f" worst leaves {numbers['grad_worst_leaf']['leaf']}"
+          + f" {numbers['change_worst_leaf']['leaf']}"
+          + f" ({seconds:.1f} s)", flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--variants", default="fp8,half_batch,no_exchange")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--save", type=Path,
+                    help="write each side's numbers there, one file each")
+    ap.add_argument("--load", type=Path,
+                    help="hold the program's numbers saved there against "
+                         "the reference computed here")
+    ap.add_argument("--no-reference", action="store_true",
+                    help="run the named variants only (needs --save)")
     args = ap.parse_args(argv)
     if args.rehearse:
         import os
@@ -133,35 +173,44 @@ def main(argv=None) -> int:
     if "program" in variants:
         variants.remove("program")
         program = _Program(config, params, builder, reference, chips)
+    now = time.perf_counter
     for seed in (int(s) for s in args.seeds.split(",")):
         batches = first_batches(config, params, seed, chips,
                                 runner.FIRST_STEPS)
         key = runner.weights_key(seed)
-        t0 = time.perf_counter()
-        ref = train.run(reference, config, config["optimizer"], key, batches,
-                        masks)
-        print(f"seed {seed}: reference losses {ref['loss'].tolist()} "
-              f"({time.perf_counter() - t0:.1f} s)", flush=True)
+        sides = {}          # side -> (its numbers, seconds it took)
+        loaded = args.load and _load(args.load, args.workload, seed,
+                                     "program")
         if program is not None:
-            t0 = time.perf_counter()
-            verdict = check.compare(program.first_steps(seed, batches), ref,
-                                    params["limits"])
-            print(f"seed {seed} program: correct={verdict['correct']} "
-                  + json.dumps({n: r["value"] for n, r in
-                                verdict["numbers"].items()})
-                  + f" worst leaves {verdict['numbers']['grad_worst_leaf']['leaf']}"
-                  + f" {verdict['numbers']['change_worst_leaf']['leaf']}"
-                  + f" ({time.perf_counter() - t0:.1f} s)", flush=True)
-        for v in variants:
-            kw = {"precision": "fp8"} if v == "fp8" else {"fault": v}
-            t0 = time.perf_counter()
-            got = train.run(reference, config, config["optimizer"], key,
-                            batches, masks, **kw)
-            verdict = check.compare(got, ref, params["limits"])
-            print(f"seed {seed} {v}: correct={verdict['correct']} "
-                  + json.dumps({n: r["value"] for n, r in
-                                verdict["numbers"].items()})
-                  + f" ({time.perf_counter() - t0:.1f} s)", flush=True)
+            t0 = now()
+            sides["program"] = (program.first_steps(seed, batches),
+                                now() - t0)
+        elif loaded:
+            sides["program"] = (loaded, 0.0)
+        ref = None
+        if not args.no_reference:
+            t0 = now()
+            ref = train.run(reference, config, config["optimizer"], key,
+                            batches, masks)
+            print(f"seed {seed}: reference losses {ref['loss'].tolist()} "
+                  f"({now() - t0:.1f} s)", flush=True)
+            for v in variants:
+                kw = {"precision": "fp8"} if v == "fp8" else {"fault": v}
+                t0 = now()
+                sides[v] = (train.run(reference, config, config["optimizer"],
+                                      key, batches, masks, **kw), now() - t0)
+        for side, (numbers, seconds) in sides.items():
+            if args.save:
+                _save(args.save, args.workload, seed, side, numbers)
+            if ref is not None:
+                _report(seed, side,
+                        check.compare(numbers, ref, params["limits"]),
+                        seconds)
+            else:
+                print(f"seed {seed} {side}: losses {list(numbers['loss'])} "
+                      f"saved, not compared ({seconds:.1f} s)", flush=True)
+        if args.save and ref is not None:
+            _save(args.save, args.workload, seed, "reference", ref)
     return 0
 
 

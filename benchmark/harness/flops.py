@@ -1,20 +1,56 @@
 """Operations and bytes the ALGORITHM needs, from shapes: matmul by
 matmul, forward plus backward, recomputation not counted, causal
-attention at half. ``work(config, traffic)`` dispatches on the
-configuration's ``builder``.
+attention at half.
 
-A matmul of ``m x k`` by ``k x n`` is ``2 m k n`` operations; the backward
-pass of a matmul is two matmuls of that size, so a trained step is three
-times its forward matmuls. Embedding lookups, LayerNorm, softmax, GELU
-and the optimizer are not matmuls and are not counted as operations.
+What one model family needs is that family's own file, found by the
+configuration's ``builder`` key: ``benchmark/counts/<builder>.py`` with
+
+* ``forward_flops(config, traffic, rows) -> int``: the matmul operations
+  of ONE forward pass over ``rows`` sequences of ``traffic["seq"]`` (the
+  whole traffic is handed over: a head over gathered positions needs
+  more of it than the length; an expert layer needs only its
+  configuration: experts held, experts per token);
+* ``attention_shape(config) -> dict or None``: ``query_heads``,
+  ``kv_heads``, ``head_size``, ``causal`` of the attention calls, or None
+  for a family with no attention kernel.
+
+The rules stay here, the same for every family. A matmul of ``m x k`` by
+``k x n`` is ``2 m k n`` operations; the backward pass of a matmul is two
+matmuls of that size, so a trained step is three times its forward
+matmuls. Embedding lookups, LayerNorm, softmax, GELU and the optimizer
+are not matmuls and are not counted as operations.
 """
 
 from __future__ import annotations
 
+import functools
+
+from .manifest import BENCH_DIR, load_module
+
 BF16 = 2   # bytes
 
 
-def _encoder_layer(rows, seq, hidden, inter, causal):
+def counts(config: dict):
+    """The count file of a configuration's family. A family without one
+    is an error that names the file to add (never a default count)."""
+    return _count_file(config["builder"])
+
+
+@functools.lru_cache(maxsize=None)
+def _count_file(builder: str):
+    path = BENCH_DIR / "counts" / f"{builder}.py"
+    if not path.exists():
+        raise FileNotFoundError(
+            f"no operation count for builder {builder!r}: add "
+            f"benchmark/counts/{builder}.py with forward_flops(config, "
+            f"traffic, rows) and attention_shape(config)")
+    return load_module(path, f"benchmark_counts_{builder}")
+
+
+def encoder_layer(rows, seq, hidden, inter, causal):
+    """Forward matmul operations of one transformer layer whose attention
+    is as wide as its hidden size: four projections, a two-matmul MLP,
+    QK^T and PV."""
     tokens = rows * seq
     proj = 4 * 2 * tokens * hidden * hidden          # q, k, v, out
     mlp = 2 * 2 * tokens * hidden * inter
@@ -24,52 +60,20 @@ def _encoder_layer(rows, seq, hidden, inter, causal):
     return proj + mlp + attn
 
 
-def bert_forward_flops(c: dict, rows: int, seq: int, predictions: int) -> int:
-    h, v = c["hidden_size"], c["vocab_size"]
-    layers = c["num_hidden_layers"] * _encoder_layer(
-        rows, seq, h, c["intermediate_size"], causal=False)
-    picked = rows * predictions
-    head = 2 * picked * h * h + 2 * picked * h * v     # transform, decoder
-    pooled = 2 * rows * h * h + 2 * rows * h * 2       # pooler, NSP
-    return layers + head + pooled
-
-
-def gpt_forward_flops(c: dict, rows: int, seq: int) -> int:
-    h = c["n_embd"]
-    layers = c["n_layer"] * _encoder_layer(rows, seq, h, 4 * h, causal=True)
-    head = 2 * rows * (seq - 1) * h * c["vocab_size"]  # tied head
-    return layers + head
-
-
 def step_flops(config: dict, traffic: dict, chips: int) -> int:
     """Model operations of one global step (all chips), forward and
     backward."""
-    rows, seq = traffic["rows_per_chip"] * chips, traffic["seq"]
-    if config["builder"] == "bert":
-        fwd = bert_forward_flops(config, rows, seq,
-                                 traffic["mlm"]["max_predictions"])
-    elif config["builder"] == "gpt":
-        fwd = gpt_forward_flops(config, rows, seq)
-    else:
-        raise KeyError(f"no operation count for builder "
-                       f"{config['builder']!r}: add one to flops.py's "
-                       f"successor file")
-    return 3 * fwd
-
-
-def sizes(config: dict):
-    """(hidden, heads, causal) of a configuration."""
-    if config["builder"] == "bert":
-        return config["hidden_size"], config["num_attention_heads"], False
-    return config["n_embd"], config["n_head"], True
+    rows = traffic["rows_per_chip"] * chips
+    return 3 * counts(config).forward_flops(config, traffic, rows)
 
 
 ATTENTION_CALLS = {
-    # kind of call: (matmuls of the forward's two counted, tensors moved)
-    "attention_forward": (1, 4),        # q, k, v in; o out
-    "attention_backward": (2, 8),       # q, k, v, o, do in; dq, dk, dv out
-    "attention_backward_dq": (1, 5),    # q, k, v, do in; dq out
-    "attention_backward_dkv": (1, 6),   # q, k, v, do in; dk, dv out
+    # kind of call: (matmul pairs of the forward's one counted,
+    #                tensors moved at the query width, at the key/value width)
+    "attention_forward": (1, 2, 2),        # q in, o out; k, v in
+    "attention_backward": (2, 4, 4),       # q, o, do in, dq out; k, v in, dk, dv out
+    "attention_backward_dq": (1, 3, 2),    # q, do in, dq out; k, v in
+    "attention_backward_dkv": (1, 2, 4),   # q, do in; k, v in, dk, dv out
 }
 
 
@@ -78,11 +82,19 @@ def attention_call(config: dict, rows: int, seq: int, kind: str):
     Forward is QK^T and PV. Backward is the four matmuls the gradient
     needs (dP, dV, dQ, dK): all four in a fused backward call, two each
     in the split ``dq`` and ``dkv`` calls; the recomputation of the scores
-    inside a flash backward is not counted. Bytes are what must cross HBM
-    when no score tensor is written, in bfloat16."""
-    hidden, _, causal = sizes(config)
-    pair = 2 * 2 * rows * seq * seq * hidden      # two matmuls
-    if causal:
+    inside a flash backward is not counted. Operations go by the query
+    heads (grouping keys and values saves none). Bytes are what must
+    cross HBM when no score tensor is written, in bfloat16: q, o and
+    their gradients at the query width, k, v and theirs at the key/value
+    width."""
+    shape = counts(config).attention_shape(config)
+    if shape is None:
+        raise LookupError(f"builder {config['builder']!r} states no "
+                          f"attention call (attention_shape is None)")
+    q_width = shape["query_heads"] * shape["head_size"]
+    kv_width = shape["kv_heads"] * shape["head_size"]
+    pair = 2 * 2 * rows * seq * seq * q_width      # two matmuls
+    if shape["causal"]:
         pair //= 2
-    pairs, tensors = ATTENTION_CALLS[kind]
-    return pairs * pair, tensors * rows * seq * hidden * BF16
+    pairs, at_q, at_kv = ATTENTION_CALLS[kind]
+    return pairs * pair, rows * seq * (at_q * q_width + at_kv * kv_width) * BF16

@@ -10,6 +10,8 @@ later PR adds files and edits none:
 * cell ``w``           -> ``benchmark/workloads/<w>.json`` (traffic
   parameters and the limits of its comparison);
 * per-layer metric ``m`` -> ``benchmark/metrics/<m>.py`` with ``read(ctx)``;
+* a family's operation and attention counts ->
+  ``benchmark/counts/<builder>.py`` (``harness/flops.py`` looks it up);
 * kernel-name patterns of a roofline metric ``m`` ->
   every ``benchmark/patterns/<m>/*.txt`` (one regular expression each).
 """
@@ -27,6 +29,15 @@ ROOT = BENCH_DIR.parent
 def load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: Path, label: str):
+    """A file of the benchmark loaded by path: a metric's name may hold
+    dots, which a package import would split."""
+    spec = importlib.util.spec_from_file_location(label, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Manifest:
@@ -61,16 +72,12 @@ class Manifest:
                 if cell_name in m.get("workloads", [cell_name])]
 
     def module(self, kind: str, name: str):
-        """``benchmark/<kind>/<name>.py`` loaded by path: a metric's name
-        may hold dots, which a package import would split."""
+        """``benchmark/<kind>/<name>.py``, loaded by path."""
         path = self.bench / kind / f"{name}.py"
         if not path.exists():
             raise FileNotFoundError(f"{kind} {name!r}: no {path}")
-        spec = importlib.util.spec_from_file_location(
-            f"benchmark_{kind}_{name.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return load_module(
+            path, f"benchmark_{kind}_{name.replace('.', '_')}")
 
     def patterns(self, metric: str) -> list:
         """The regular expressions, one per file, that say which trace

@@ -23,8 +23,13 @@ PEAKS = {
 }
 
 
+class UnknownChip(KeyError):
+    """No published peaks for this ``device_kind`` (the CPU of a
+    rehearsal, or a chip nobody has added with its source)."""
+
+
 def peaks(device_kind: str) -> Peaks:
     if device_kind not in PEAKS:
-        raise KeyError(f"no published peaks for device_kind {device_kind!r}; "
+        raise UnknownChip(f"no published peaks for device_kind {device_kind!r}; "
                        f"known: {sorted(PEAKS)}. Add a row with its source.")
     return PEAKS[device_kind]

@@ -21,7 +21,8 @@ from pathlib import Path
 
 import numpy as np
 
-from . import check, masks as masks_mod, traffic as traffic_mod
+from . import check, masks as masks_mod, peaks as peaks_mod, \
+    traffic as traffic_mod
 from .manifest import Manifest
 
 FIRST_STEPS = 3          # steps the reference follows
@@ -322,6 +323,7 @@ def run_cell(manifest: Manifest, cell_name: str, *, seed: int,
         if os.environ.get("BENCH_DESCRIBE_TRACE"):
             (Path(out_dir) / "trace_described.txt").write_text(
                 trace_mod.describe(trace_dir))
+            (Path(out_dir) / "program.hlo.txt").write_text(program["hlo"])
         # off the chip there is no device plane to reduce: the rehearsal
         # reads the spans and counters only
         ctx["trace"] = None if rehearsal else trace_mod.load(trace_dir,
@@ -334,7 +336,7 @@ def run_cell(manifest: Manifest, cell_name: str, *, seed: int,
             reader = manifest.module("metrics", entry["name"])
             try:
                 value = reader.read(ctx)
-            except KeyError as e:
+            except peaks_mod.UnknownChip as e:
                 if not rehearsal:     # on the chip an unknown peak is fatal
                     raise
                 log(f"[rehearsal] {entry['name']} not read off the chip: {e}")

@@ -260,17 +260,22 @@ def whole_runs(modules) -> list:
     return runs
 
 
+def in_whole_runs(events, modules, steps):
+    """(the events inside the capture's whole runs, their number); where
+    the trace has no module line, all events and ``steps``."""
+    runs = whole_runs(modules)
+    if not runs:
+        return events, steps
+    slack = 1e-6
+    return [e for e in events
+            if any(r.start - slack <= e.start and e.end <= r.end + slack
+                   for r in runs)], len(runs)
+
+
 def per_step(events, modules, steps, program: Program, table: Table):
     """:func:`split` of one chip in seconds a step: over the events inside
-    the capture's whole runs and their number; where the trace has no
-    module line, over all events and ``steps``."""
-    runs = whole_runs(modules)
-    if runs:
-        slack = 1e-6
-        events = [e for e in events
-                  if any(r.start - slack <= e.start and e.end <= r.end + slack
-                         for r in runs)]
-        steps = len(runs)
+    the capture's whole runs and their number."""
+    events, steps = in_whole_runs(events, modules, steps)
     if not steps:
         return {}
     return {k: v / steps for k, v in split(events, program, table).items()}

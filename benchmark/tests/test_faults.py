@@ -12,6 +12,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from benchmark.harness import check, masks, runner
@@ -53,39 +54,51 @@ def state_unchanged(stage, built):
 
 
 def half_batch(stage, built):
-    """The second half of the rows never reaches the step: the first half
-    stands in its place, so every mean is taken over the first half."""
+    """The second half of every chip's rows never reaches the step: the
+    first half stands in its place, so every mean is taken over the
+    first half."""
     if stage != "after_build":
         return built
 
-    def call(step, state, batch):
-        def fold(x):
-            if x.ndim < 2 or x.shape[1] < 2:
-                return x
-            half = x.shape[1] // 2
-            return jnp.concatenate([x[:, :half], x[:, :half]], axis=1)
+    def place(batch):
+        shards = batch["seed"].shape[1]
 
-        return step(state, {k: (v if k == "seed" else fold(v))
+        def fold(x):
+            x = np.asarray(x)
+            per = x.reshape(x.shape[0], shards, -1, *x.shape[2:])
+            half = per.shape[2] // 2
+            return np.concatenate([per[:, :, :half], per[:, :, :half]],
+                                  axis=2).reshape(x.shape)
+
+        return built.place({k: (v if k == "seed" else fold(v))
                             for k, v in batch.items()})
 
-    return built._replace(step=_Wrapped(built.step, call))
+    return built._replace(place=place)
+
+
+_UNDO = []      # what a sabotage patched, put back after each test
+
+
+@pytest.fixture(autouse=True)
+def undo_patches():
+    yield
+    while _UNDO:
+        _UNDO.pop()()
 
 
 def no_exchange(stage, built):
     """The gradient exchange between the chips is left out: every chip
-    updates from its own shard's gradient."""
+    updates from its own shard's gradient. (Patched for the whole run:
+    the step is traced at its first call, after the build.)"""
     if stage == "before_build":
-        from apex_tpu.parallel import DistributedDataParallel
+        from apex_tpu.parallel import DistributedDataParallel as DDP
 
         def alone(self, acc, accum_steps=1):
             return jax.tree.map(lambda a: a / accum_steps, acc)
 
-        no_exchange.saved = DistributedDataParallel.allreduce_accumulated
-        DistributedDataParallel.allreduce_accumulated = alone
-        return None
-    from apex_tpu.parallel import DistributedDataParallel
-
-    DistributedDataParallel.allreduce_accumulated = no_exchange.saved
+        saved = DDP.allreduce_accumulated
+        _UNDO.append(lambda: setattr(DDP, "allreduce_accumulated", saved))
+        DDP.allreduce_accumulated = alone
     return built
 
 
@@ -98,6 +111,8 @@ CASES = [
     ("gpt2_medium.lm1024", half_batch, False),
     ("bert_large.phase2_ddp4", None, True),
     ("bert_large.phase2_ddp4", no_exchange, False),
+    ("bert_large.phase2_ddp4", half_batch, False),
+    ("bert_large.phase2_ddp4", state_unchanged, False),
 ]
 
 
@@ -113,7 +128,8 @@ def test_fault_under_the_timed_path_reads_not_correct(cell, fault, expected):
     assert list(result)[-1] == "compared"
 
 
-@pytest.mark.parametrize("cell", ["bert_large.phase2", "gpt2_medium.lm1024"])
+@pytest.mark.parametrize("cell", ["bert_large.phase2", "gpt2_medium.lm1024",
+                                  "bert_large.phase2_ddp4"])
 def test_control_in_float8_fails_the_comparison(cell):
     if cell not in [w["name"] for w in M.doc["workloads"]]:
         pytest.skip(f"{cell} is not a cell of this benchmark")
@@ -123,7 +139,8 @@ def test_control_in_float8_fails_the_comparison(cell):
     config, params = runner._apply_rehearsal(
         M.config(M.cell(cell)["config"]), M.traffic(cell))
     _, reference = runner.family(config)
-    batches = control.first_batches(config, params, 5, 1, runner.FIRST_STEPS)
+    batches = control.first_batches(config, params, 5, M.cell(cell)["chips"],
+                                    runner.FIRST_STEPS)
     key = runner.weights_key(5)
     ref = train.run(reference, config, config["optimizer"], key, batches,
                     masks)

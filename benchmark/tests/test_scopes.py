@@ -182,6 +182,40 @@ def test_a_step_is_a_whole_run_of_the_program():
     assert scopes.per_step(ops, [], 0, prog, table) == {}
 
 
+def test_exposed_all_reduce_is_per_whole_run_and_the_median_chip():
+    """Four planes: the capture clips every chip's first run at another
+    point, and the clipped tail holds an all-reduce too (it runs after
+    the backward); the reader counts the whole runs only and gives the
+    median chip's time, in ms a step."""
+    M = "jit_fused_step(1)"
+    reader = Manifest().module("metrics", "parallel.allreduce_exposed_ms")
+
+    def chip(clip, alone):
+        # a clipped run of `clip` s, then two whole runs of 10 s; in each
+        # run the all-reduce is exposed for `alone` s and hidden for 1 s
+        runs = [E(M, 10.0 - clip, 10.0), E(M, 10.0, 20.0), E(M, 20.0, 30.0)]
+        ops = []
+        for end in (10.0, 20.0, 30.0):
+            ops += [E("fusion.1", end - 9.0, end - 3.0),
+                    E("psum.12", end - 4.0, end - 3.0 + alone),
+                    E("psum.13", end - 1.0, end - 0.5)]
+        return ops, runs
+
+    planes = {i: chip(clip, alone) for i, (clip, alone) in enumerate(
+        [(5.0, 2.0), (6.0, 2.0), (4.5, 2.25), (7.0, 1.0)])}
+    ctx = {"slice_steps": 2, "trace": Trace(
+        {i: ops for i, (ops, _) in planes.items()},
+        {i: runs for i, (_, runs) in planes.items()}, [], 0.0, 0.0)}
+    # per chip (2.0 + 0.5), (2.0 + 0.5), (2.25 + 0.5), (1.0 + 0.5) s a step
+    assert reader.read(ctx) == pytest.approx(2500.0)
+    # over everything and the harness's 2 steps it would read 3 runs' worth
+    assert 1e3 * trace.exposed(planes[0][0]) / 2 == pytest.approx(3750.0)
+    no_collective = {**ctx, "trace": Trace({0: [E("fusion.1", 0.0, 1.0)]},
+                                           {0: []}, [], 0.0, 0.0)}
+    assert reader.read(no_collective) is None
+    assert reader.read({**ctx, "trace": None}) is None
+
+
 def test_a_program_without_scopes_reads_100_and_none_never_0():
     import re
 
