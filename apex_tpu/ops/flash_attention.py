@@ -502,7 +502,31 @@ def _drop_arg(drop_in, bq, bk, index_map):
     return [drop_in], [pl.BlockSpec((1, 1, bq, bk), index_map)]
 
 
-def _q_major_maps(causal, bq, bk):
+def _kv_head(q, k):
+    """Query head -> the key/value head of its group. Grouped-query
+    attention hands k and v over at their own head count ``Hkv`` (``H %
+    Hkv == 0``, query heads ``g * H // Hkv .. (g + 1) * H // Hkv - 1`` read
+    group ``g``): the kernels reach a group through this index map, so no
+    repeated copy of k and v exists. With ``Hkv == H`` it is the identity
+    and the index maps are what they were."""
+    H, Hkv = q.shape[1], k.shape[1]
+    if H % Hkv:
+        raise ValueError(f"{H} query heads are not a multiple of {Hkv} "
+                         f"key/value heads")
+    group = H // Hkv
+    return (lambda h: h) if group == 1 else (lambda h: h // group)
+
+
+def _sum_groups(dk, k):
+    """Per-query-head ``dk`` / ``dv`` ``(B, H, Sk, D)`` summed over each
+    group to ``k``'s ``(B, Hkv, Sk, D)``, outside the kernel."""
+    B, Hkv, Sk, D = k.shape
+    if dk.shape[1] == Hkv:
+        return dk
+    return dk.reshape(B, Hkv, -1, Sk, D).sum(axis=2)
+
+
+def _q_major_maps(causal, bq, bk, kv_head=lambda h: h):
     """Index maps on the q-major grid ``(b, h, iq, ik)`` of fwd and dq for
     the operands blocked along keys: (k / v, key mask, interpret-mode
     dropout bits). Under ``causal`` a dead step keeps the row's last live
@@ -510,7 +534,7 @@ def _q_major_maps(causal, bq, bk):
     def live(iq, ik):
         return _live_k(causal, iq, ik, bq, bk)
 
-    return (lambda b, h, iq, ik: (b, h, live(iq, ik), 0),
+    return (lambda b, h, iq, ik: (b, kv_head(h), live(iq, ik), 0),
             lambda b, h, iq, ik: (b, 0, live(iq, ik)),
             lambda b, h, iq, ik: (b, h, iq, live(iq, ik)))
 
@@ -523,6 +547,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     native = drop_in is not None and drop_in.ndim == 1
+    kv_head = _kv_head(q, k)
 
     if Sq == bq and Sk == bk:
         extra, extra_specs = _drop_arg(drop_in, bq, bk,
@@ -535,8 +560,8 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
             grid=(B, H),
             in_specs=[
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, h, 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, h, 0, 0)),
+                _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
+                _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
                 pl.BlockSpec((1, 1, bk), lambda b, h: (b, 0, 0)),
             ] + extra_specs,
             out_specs=(
@@ -550,7 +575,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
             name="flash_fwd",
             interpret=_interpret(),
         )(q, k, v, mask, *extra)
-    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk)
+    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk, kv_head)
     extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
@@ -587,6 +612,13 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     native = drop_in is not None and drop_in.ndim == 1
+    kv_head = _kv_head(q, k)
+    # grouped keys/values: the kernels write dk, dv per QUERY head (in
+    # float32, to be summed over each group by the caller); multi-head
+    # attention writes them in k's and v's dtype as before
+    grouped = k.shape[1] != H
+    dk_dtype, dv_dtype = ((jnp.float32, jnp.float32) if grouped
+                          else (k.dtype, v.dtype))
 
     if Sq == bq and Sk == bk:
         # whole attention row in one tile: fused dq+dk+dv kernel
@@ -599,8 +631,8 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             grid=(B, H),
             in_specs=[
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, h, 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, h, 0, 0)),
+                _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
+                _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
                 pl.BlockSpec((1, 1, bk), lambda b, h: (b, 0, 0)),
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
                 pl.BlockSpec((1, 1, 1, bq), lambda b, h: (b, h, 0, 0)),
@@ -613,8 +645,8 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             ),
             out_shape=(
                 out_struct((B, H, Sq, D), q.dtype, q, k, v, do),
-                out_struct((B, H, Sk, D), k.dtype, q, k, v, do),
-                out_struct((B, H, Sk, D), v.dtype, q, k, v, do),
+                out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
+                out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
             ),
             name="flash_bwd",
             interpret=_interpret(),
@@ -624,7 +656,7 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
     kern = dict(scale=scale, causal=causal, bq=bq, bk=bk, has_mask=has_mask,
                 dropout_rate=dropout_rate, native_prng=native)
 
-    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk)
+    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk, kv_head)
     extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kern),
@@ -663,8 +695,8 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
         grid=(B, H, Sk // bk, nq),
         in_specs=[
             _spec4(bq, D, q_map),
-            _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
-            _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
+            _spec4(bk, D, lambda b, h, ik, iq: (b, kv_head(h), ik, 0)),
+            _spec4(bk, D, lambda b, h, ik, iq: (b, kv_head(h), ik, 0)),
             pl.BlockSpec((1, 1, bk), lambda b, h, ik, iq: (b, 0, ik)),
             _spec4(bq, D, q_map),
             pl.BlockSpec((1, 1, 1, bq), row_map),
@@ -675,8 +707,8 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
         ),
         out_shape=(
-            out_struct((B, H, Sk, D), k.dtype, q, k, v, do),
-            out_struct((B, H, Sk, D), v.dtype, q, k, v, do),
+            out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
+            out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
         ),
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
@@ -806,8 +838,14 @@ def flash_dropout_keep_mask(B, H, Sq, Sk, dropout_rate, seed):
     return (keep > 0.5)[:, :, :Sq, :Sk]
 
 
+def _repeat_groups(k, H):
+    """k or v at ``Hkv`` heads repeated to the ``H`` query heads."""
+    return k if k.shape[1] == H else jnp.repeat(k, H // k.shape[1], axis=1)
+
+
 def _scores(q, k, key_mask, causal, scale):
     """(B, H, Sq, Sk) fp32 masked scores — shared by every composed path."""
+    k = _repeat_groups(k, q.shape[1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if key_mask is not None:
@@ -838,7 +876,8 @@ def mha_reference(q, k, v, key_mask=None, causal=False, scale=1.0,
             1.0 - dropout_rate, p.shape)
         p = jnp.where(keep, p, 0.0) / (1.0 - dropout_rate)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
+                      _repeat_groups(v, q.shape[1]).astype(jnp.float32)
+                      ).astype(q.dtype)
 
 
 def mha_with_mask_reference(q, k, v, keep, key_mask=None, causal=False,
@@ -859,6 +898,11 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
 
     Args:
       q, k, v: ``(B, H, S, D)`` (any floating dtype; fp32 accumulation).
+        Grouped-query attention: k and v may carry fewer heads
+        ``(B, Hkv, S, D)``, ``H % Hkv == 0``; query head ``h`` reads group
+        ``h // (H // Hkv)`` through the kernels' index maps (no repeated
+        copy of k, v). The backward kernels write dk, dv per query head
+        in float32 and the sum over each group is taken outside them.
       key_mask: optional ``(B, Sk)`` boolean, True = key position masked
         (the reference's padding-mask convention).
       causal: apply the upper-triangular causal mask in-kernel.
@@ -941,6 +985,7 @@ def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
                                  scale=scale, causal=causal, bq=bq, bk=bk,
                                  has_mask=_has_mask(key_mask, Sk, bk),
                                  dropout_rate=dropout_rate, drop_in=drop_in)
+    dk, dv = _sum_groups(dk, kp), _sum_groups(dv, vp)
     return (match_vma(dq[:, :, :Sq, :D].astype(q.dtype), q),
             match_vma(dk[:, :, :Sk, :D].astype(k.dtype), k),
             match_vma(dv[:, :, :Sk, :D].astype(v.dtype), v),

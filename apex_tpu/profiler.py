@@ -44,14 +44,36 @@ lm_loss            shifted cross-entropy (fp32 logsumexp)    models/gpt.py
 mlm_head           BERT gather + transform + LN + decoder    models/bert.py
 nsp_head           BERT next-sentence classifier             models/bert.py
 pretraining_loss   MLM + NSP loss                            models/bert.py
+ssm_in_proj        Mamba-2 input projection (z, x, B, C, dt) models/nemotron_h.py
+ssm_conv           depthwise causal conv + SiLU              models/nemotron_h.py
+ssm_scan           the chunked selective scan                ops/ssd_scan.py
+ssm_out            gated grouped RMSNorm + output projection models/nemotron_h.py
+moe_router         fp32 scores, top-k, normalised weights    transformer/moe.py
+moe_dispatch       sort by expert, gather the held rows      transformer/moe.py
+moe_experts        the held experts' grouped matmuls         transformer/moe.py
+moe_shared         the shared expert (every token)           models/nemotron_h.py
+moe_combine        weighted rows back to token order         transformer/moe.py
+gqa_attention      q/k/v projections, grouped-query flash,   models/nemotron_h.py
+                   output projection
 ================== ======================================== ==========================
+
+The model scopes of the last ten rows sit INSIDE ``train_fwd_bwd`` (a
+phase reader files their ops by that ancestor).
 
 Pallas kernels carry a stable ``name=`` that says kernel and direction,
 never the caller (:data:`KERNEL_NAMES`); the name becomes the HLO
 instruction's name and so the device event's: ``flash_fwd``,
 ``flash_bwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``layer_norm_fwd``,
 ``layer_norm_bwd``, ``softmax_fwd``, ``softmax_bwd``, ``dropout_apply``,
-``dropout_mask``.
+``dropout_mask``. The dropless expert layer runs the grouped-matmul
+kernels that ship with JAX (``jax.experimental.pallas.ops.tpu.megablox``),
+which name themselves: ``gmm`` (forward and the rows' gradient) and
+``tgmm`` (the weights' gradient) (:data:`LIBRARY_KERNEL_NAMES`); they sit
+under the ``moe_experts`` scope, which is what a reader should match.
+
+A model may report step counters beside its loss
+(``build_train_step(has_aux=True)``; they arrive with the loss in
+``metrics["aux"]``, no extra sync): :data:`STEP_COUNTERS`.
 
 Host annotations (``jax.profiler.TraceAnnotation``, on the host plane
 of the same capture; a flag test when no capture runs):
@@ -104,6 +126,16 @@ LM_LOSS = "lm_loss"
 MLM_HEAD = "mlm_head"
 NSP_HEAD = "nsp_head"
 PRETRAINING_LOSS = "pretraining_loss"
+SSM_IN_PROJ = "ssm_in_proj"
+SSM_CONV = "ssm_conv"
+SSM_SCAN = "ssm_scan"
+SSM_OUT = "ssm_out"
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_SHARED = "moe_shared"
+MOE_COMBINE = "moe_combine"
+GQA_ATTENTION = "gqa_attention"
 
 STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
                AMP_SCALE_LOSS, AMP_UNSCALE, AMP_FOUND_INF, AMP_UPDATE_SCALE,
@@ -111,12 +143,28 @@ STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
 OPTIMIZER_SCOPES = (LAMB_GRAD_NORM, LAMB_STAGE1, LAMB_STAGE2, ADAM_UPDATE)
 DDP_SCOPES = (DDP_FLATTEN, DDP_ALLREDUCE, DDP_UNFLATTEN)
 MODEL_SCOPES = (LM_HEAD, LM_LOSS, MLM_HEAD, NSP_HEAD, PRETRAINING_LOSS)
+# scopes of single layers, always nested in ``train_fwd_bwd``: a reader
+# of phases files their ops by that ancestor, so they are no phase of
+# their own and stay out of ``SCOPES``
+LAYER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_OUT, MOE_ROUTER,
+                MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE,
+                GQA_ATTENTION)
 SCOPES = STEP_SCOPES + OPTIMIZER_SCOPES + DDP_SCOPES + MODEL_SCOPES
+
+# -- step metrics a model reports beside its loss (``has_aux``) ----------------
+MOE_ASSIGNMENTS_HELD = "moe_assignments_held"
+MOE_LOAD_MAX_OVER_MEAN = "moe_load_max_over_mean"
+MOE_TOKENS_DROPPED = "moe_tokens_dropped"
+STEP_COUNTERS = (MOE_ASSIGNMENTS_HELD, MOE_LOAD_MAX_OVER_MEAN,
+                 MOE_TOKENS_DROPPED)
 
 # -- Pallas kernel names (``pl.pallas_call(name=...)``) ------------------------
 KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "layer_norm_fwd", "layer_norm_bwd", "softmax_fwd",
                 "softmax_bwd", "dropout_apply", "dropout_mask")
+
+# kernels of a library the train path calls (named by the library)
+LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
 
 # -- host annotations ----------------------------------------------------------
 TRAIN_DISPATCH = "train_dispatch"

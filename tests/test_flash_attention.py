@@ -495,3 +495,118 @@ def test_causal_tile_classes_match_brute_force(Sq, Sk, bq, bk, expected):
     assert causal_tile_classes(Sq, Sk, bq, bk) == brute
     if expected is not None:
         assert brute == expected
+
+
+# ------------------------------------------- grouped-query attention
+
+def _gqa_inputs(S, H, Hkv, dtype=jnp.float32, seed=0, B=2, D=64):
+    ks = jax.random.split(jax.random.PRNGKey(seed + S + Hkv), 4)
+    q = jax.random.normal(ks[0], (B, H, S, D), dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
+    do = jax.random.normal(ks[3], (B, H, S, D), dtype)
+    return q, k, v, do
+
+
+# S = 256: the single-tile kernels (fused backward); S = 1024: the
+# multi-tile path with its split backward and skipped causal tiles
+@pytest.mark.parametrize("S", [256, 1024])
+@pytest.mark.parametrize("Hkv", [1, 2])
+def test_grouped_query_matches_reference_on_repeated_kv(S, Hkv):
+    """4 query heads on 1 and on 2 key/value heads, forward and backward,
+    against ``mha_reference`` on k, v repeated to 4 heads (whose gradient
+    sums over each group)."""
+    H = 4
+    q, k, v, do = _gqa_inputs(S, H, Hkv)
+
+    def rep(t):
+        return jnp.repeat(t, H // Hkv, axis=1)
+
+    def flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, True, 0.125) * do)
+
+    def composed(q, k, v):
+        return jnp.sum(mha_reference(q, rep(k), rep(v), None, True, 0.125)
+                       * do)
+
+    with jax.default_matmul_precision("highest"):
+        out = flash_attention(q, k, v, None, True, 0.125)
+        ref = mha_reference(q, rep(k), rep(v), None, True, 0.125)
+        got = jax.grad(flash, (0, 1, 2))(q, k, v)
+        want = jax.grad(composed, (0, 1, 2))(q, k, v)
+    assert out.shape == q.shape
+    assert _max_err(out, ref) < 2e-5
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name          # dk, dv at Hkv heads
+        assert _max_err(a, b) < 1e-4, name
+
+
+def test_grouped_query_dropout_and_bf16():
+    """bf16 with in-kernel dropout: dk, dv are summed over the group in
+    float32 before the cast; the mask is per query head."""
+    from apex_tpu.ops.flash_attention import (flash_dropout_keep_mask,
+                                              mha_with_mask_reference)
+
+    H, Hkv, S = 4, 2, 640
+    q, k, v, do = _gqa_inputs(S, H, Hkv, jnp.bfloat16, seed=3, B=1)
+    keep = flash_dropout_keep_mask(1, H, S, S, 0.1, 11)
+
+    def flash(q, k, v):
+        return jnp.sum((flash_attention(q, k, v, None, True, 0.125, 0.1, 11)
+                        * do).astype(jnp.float32))
+
+    def composed(q, k, v):
+        rep = lambda t: jnp.repeat(t, 2, axis=1)  # noqa: E731
+        return jnp.sum((mha_with_mask_reference(
+            q, rep(k), rep(v), keep, None, True, 0.125, 0.1) * do
+        ).astype(jnp.float32))
+
+    got = jax.grad(flash, (0, 1, 2))(q, k, v)
+    want = jax.grad(composed, (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert _max_err(a, b) < 0.05 * float(jnp.max(jnp.abs(
+            b.astype(jnp.float32)))), name
+
+
+def test_heads_must_be_a_multiple_of_groups():
+    q, k, v, _ = _gqa_inputs(128, 4, 3)
+    with pytest.raises(ValueError, match="not a multiple"):
+        flash_attention(q, k, v, None, True, 0.125)
+
+
+@pytest.mark.parametrize("S,causal,rate", [(256, True, 0.0), (512, False, 0.1),
+                                           (1024, True, 0.1), (640, True, 0.0)])
+def test_multi_head_is_bit_identical_to_the_parent(monkeypatch, S, causal,
+                                                   rate):
+    """With as many key/value heads as query heads the kernels are the
+    parent's: its index maps (the identity on heads) and its dk, dv in
+    k's dtype with no sum outside. The parent is reached by putting its
+    two pieces back - ``_kv_head`` always the identity, ``_sum_groups`` a
+    no-op - and the multi-head result must not differ in one bit. (PR 30
+    also held the results against the parent's module file itself, out
+    and three gradients, float32 and bfloat16, 10 cases: bit-identical.)"""
+    import importlib
+
+    # the package exports a function of the module's own name
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+    q, k, v, do = _gqa_inputs(S, 4, 4, jnp.bfloat16, seed=5, B=1)
+    seed = 7 if rate else None
+
+    def both():
+        def loss(q, k, v):
+            return jnp.sum((fa.flash_attention(q, k, v, None, causal, 0.125,
+                                               rate, seed) * do
+                            ).astype(jnp.float32))
+
+        return (fa.flash_attention(q, k, v, None, causal, 0.125, rate, seed),
+                ) + jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    new = both()
+    monkeypatch.setattr(fa, "_kv_head", lambda q, k: (lambda h: h))
+    monkeypatch.setattr(fa, "_sum_groups", lambda dk, k: dk)
+    parent = both()
+    for name, a, b in zip(("out", "dq", "dk", "dv"), new, parent):
+        assert a.dtype == b.dtype == jnp.bfloat16, name
+        assert np.array_equal(np.asarray(a.astype(jnp.float32)),
+                              np.asarray(b.astype(jnp.float32))), name

@@ -403,3 +403,124 @@ def test_gpt_moe_block_end_to_end():
     # expert weights exist and received gradient
     moe_g = g["params"]["transformer"]["h_0"]["moe_mlp"]["w1"]
     assert float(jnp.abs(moe_g).sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# DroplessMoE: one rank's share of a dropless layer
+# ---------------------------------------------------------------------------
+
+from apex_tpu.transformer.moe import DroplessMoE  # noqa: E402
+
+_H, _F, _E, _K = 32, 48, 16, 3
+
+
+def _dropless(held, first=0, **kw):
+    return DroplessMoE(_H, _F, _E, _K, held, first, 2.5, dtype=jnp.float32,
+                       **kw)
+
+
+def _uncut_reference(p, x, k=_K, scale=2.5, shared=None):
+    """The whole layer in plain jax.numpy: every expert, a 0/1 mask."""
+    t = x.reshape(-1, _H)
+    s = jax.nn.sigmoid(t @ p["router"])
+    _, idx = jax.lax.top_k(s, k)
+    w = jnp.take_along_axis(s, idx, -1)
+    w = w / w.sum(-1, keepdims=True) * scale
+    y = 0.0 if shared is None else shared(t)
+    for e in range(p["w_up"].shape[0]):
+        mine = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
+        y = y + mine[:, None] * (
+            jnp.square(jax.nn.relu(t @ p["w_up"][e])) @ p["w_down"][e])
+    return y.reshape(x.shape)
+
+
+@pytest.fixture(scope="module")
+def dropless_params():
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 20, _H))
+    p = _dropless(_E).init(jax.random.PRNGKey(0), x)["params"]
+    return jax.tree.map(lambda a: a * 10.0, p), x
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer(dropless_params):
+    """16 ranks of one expert each, the shared expert counted once, give
+    what the uncut reference gives for the whole layer."""
+    p, x = dropless_params
+    ws = jax.random.normal(jax.random.PRNGKey(2), (_H, _H)) * 0.1
+    shared = lambda t: jnp.square(jax.nn.relu(t @ ws)) @ ws.T  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        whole = _uncut_reference(p, x, shared=shared)
+        total = shared(x.reshape(-1, _H)).reshape(x.shape)   # every rank alike
+        pairs = 0.0
+        for rank in range(16):
+            mine = {"router": p["router"],
+                    "w_up": p["w_up"][rank:rank + 1],
+                    "w_down": p["w_down"][rank:rank + 1]}
+            y, counters = _dropless(1, rank).apply({"params": mine}, x)
+            total = total + y
+            pairs += float(counters["moe_assignments_held"])
+            assert float(counters["moe_tokens_dropped"]) == 0.0
+    assert pairs == x.shape[0] * x.shape[1] * _K       # each pair once
+    assert float(jnp.max(jnp.abs(total - whole))) < 1e-4 * float(
+        jnp.max(jnp.abs(whole)))
+
+
+def test_dropless_gradients_match_the_uncut_layer(dropless_params):
+    p, x = dropless_params
+    layer = _dropless(_E)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+            layer.apply({"params": p}, x)[0])), argnums=(0, 1))(p, x)
+        ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+            _uncut_reference(p, x))), argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * float(
+            jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("favourite,held,first,expect", [
+    (5, 4, 4, "all"),      # every token chooses the same held expert
+    (5, 4, 8, "none"),     # no token chooses a held one
+])
+def test_no_token_dropped_under_the_worst_routing(dropless_params, favourite,
+                                                  held, first, expect):
+    """A selection bias that sends every token's top-1 to one expert (and
+    the rest to experts 0 and 1): the static-capacity layer would drop
+    most of them; here every assignment on a held expert is computed."""
+    p, x = dropless_params
+    tokens = x.shape[0] * x.shape[1]
+    bias = jnp.zeros((_E,)).at[favourite].set(100.0).at[0].set(50.0).at[
+        1].set(25.0)
+    mine = {"router": p["router"], "w_up": p["w_up"][first:first + held],
+            "w_down": p["w_down"][first:first + held]}
+    with jax.default_matmul_precision("highest"):
+        y, counters = _dropless(held, first).apply({"params": mine}, x, bias)
+    assert float(counters["moe_tokens_dropped"]) == 0.0
+    if expect == "none":
+        assert float(counters["moe_assignments_held"]) == 0.0
+        assert float(jnp.max(jnp.abs(y))) == 0.0
+        return
+    assert float(counters["moe_assignments_held"]) == tokens
+    assert float(counters["moe_load_max_over_mean"]) == pytest.approx(held)
+    # every token got expert `favourite`'s output at its normalised weight
+    t = x.reshape(-1, _H)
+    s = jax.nn.sigmoid(t @ p["router"])
+    w = s[:, favourite] / (s[:, favourite] + s[:, 0] + s[:, 1]) * 2.5
+    with jax.default_matmul_precision("highest"):
+        want = w[:, None] * (jnp.square(jax.nn.relu(
+            t @ p["w_up"][favourite])) @ p["w_down"][favourite])
+    assert float(jnp.max(jnp.abs(y.reshape(-1, _H) - want))) < 1e-4 * float(
+        jnp.max(jnp.abs(want)))
+
+
+def test_dropless_work_follows_assignments_not_experts():
+    """No (T, E, C) tensor: nothing in the layer's jaxpr is as large as
+    tokens x experts x hidden."""
+    x = jnp.zeros((1, 64, _H))
+    layer = _dropless(2)
+    p = layer.init(jax.random.PRNGKey(0), x)["params"]
+    jaxpr = jax.make_jaxpr(lambda p, x: layer.apply({"params": p}, x))(p, x)
+    largest = max(int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
+                  for v in eqn.outvars if hasattr(v.aval, "shape"))
+    assert largest <= 64 * _K * max(_H, _F)
+    with pytest.raises(ValueError, match="are not among"):
+        _dropless(4, 14).init(jax.random.PRNGKey(0), x)

@@ -197,6 +197,60 @@ def _dropout_case(sh):
                _spec((), jnp.int32, sh)), 2
 
 
+def _flash_gqa_8192_case(sh):
+    """The ``nemotron_h`` attention call (``nemotron_twotower_30b_a3b.
+    lm8192``): 32 query heads on 2 key/value heads of 128, causal, 16 x 16
+    tiles; k and v go in at their own head count (no repeated copy), dk
+    and dv come out per query head in float32."""
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention(q, k, v, None, True, 128 ** -0.5)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    return f, (_spec((2, 32, 8192, 128), jnp.bfloat16, sh),
+               _spec((2, 2, 8192, 128), jnp.bfloat16, sh),
+               _spec((2, 2, 8192, 128), jnp.bfloat16, sh)), 3
+
+
+def _grouped_matmul_case(sh):
+    """The held experts of one expert layer of the same cell: 8 experts of
+    2688 x 1856 over the 98,304 assignment slots of 16,384 tokens; up,
+    squared ReLU, down, forward and backward (gmm x 4 + tgmm x 2)."""
+    from apex_tpu.transformer.moe import grouped_matmul, squared_relu
+
+    def f(rows, up, down, sizes):
+        def loss(rows, up, down):
+            h = squared_relu(grouped_matmul(rows, up, sizes))
+            return grouped_matmul(h, down, sizes).astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(rows, up, down)
+
+    return f, (_spec((98304, 2688), jnp.bfloat16, sh),
+               _spec((8, 2688, 1856), jnp.bfloat16, sh),
+               _spec((8, 1856, 2688), jnp.bfloat16, sh),
+               _spec((8,), jnp.int32, sh)), 5
+
+
+def _ssd_scan_case(sh):
+    """The Mamba-2 scan of the same cell (64 heads of 64, 8 groups, state
+    128, chunks of 128, 2 x 8192 tokens): XLA einsums, no Pallas kernel -
+    the case holds its temporaries under the chip's memory."""
+    from apex_tpu.ops.ssd_scan import ssd_scan
+
+    def f(x, dt, A, B, C, D):
+        return jax.value_and_grad(
+            lambda x, dt, B, C: ssd_scan(x, dt, A, B, C, D, chunk=128)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(x, dt, B, C)
+
+    bc = _spec((2, 8192, 8, 128), jnp.bfloat16, sh)
+    return f, (_spec((2, 8192, 64, 64), jnp.bfloat16, sh),
+               _spec((2, 8192, 64), jnp.float32, sh),
+               _spec((64,), jnp.float32, sh), bc, bc,
+               _spec((64,), jnp.float32, sh)), 0
+
+
 _CASES = {
     "layer_norm": _ln_case,
     "flash_attention": _flash_case,
@@ -205,6 +259,9 @@ _CASES = {
     "flash_dropout_keep_mask": _keep_mask_case,
     "scaled_masked_softmax": _softmax_case,
     "fused_dropout": _dropout_case,
+    "flash_attention_gqa_8192": _flash_gqa_8192_case,
+    "grouped_matmul": _grouped_matmul_case,
+    "ssd_scan": _ssd_scan_case,
 }
 
 
