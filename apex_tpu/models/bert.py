@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from apex_tpu import profiler
 from apex_tpu.normalization import FusedLayerNorm
 from apex_tpu.transformer.functional import AttnMaskType, FusedScaleMaskSoftmax
+from apex_tpu.transformer.remat import remat_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,13 +47,15 @@ class BertConfig:
     layernorm_eps: float = 1e-12
     dtype: jnp.dtype = jnp.float32   # activation/compute dtype (bf16 for O2)
     remat: bool = True               # activation checkpointing per layer
-    # remat policy: "full" recomputes everything in the layer backward
-    # (min memory); "dots" saves matmul results and recomputes only the
-    # cheap elementwise ops (jax.checkpoint_policies
-    # .dots_with_no_batch_dims_saveable) — near-no-remat step time at a
-    # fraction of full activation memory, often the best batch-size
-    # enabler on a 16 GB chip
-    remat_policy: str = "full"       # "full" | "dots"
+    # what a checkpointed layer keeps (apex_tpu/transformer/remat.py):
+    # "selective" keeps the matmul and flash-attention outputs and
+    # recomputes only the elementwise ops (LayerNorm, GELU, dropout,
+    # adds); "full" keeps the layer's input alone and recomputes the
+    # whole forward pass - 19% more step time for 2.55 GiB less live
+    # memory at 24 x 1024, S=512, B=16 on a v5e (PERF.md section 6,
+    # PR 31): the way back for a job that fitted only under full
+    # recomputation
+    remat_policy: str = "selective"  # "selective" | "full"
     fused_kernels: bool = True       # Pallas LN/softmax vs stock ops
     # Pallas flash attention (reference: contrib fmha). Used when the
     # sequence is long enough to win (>= flash_min_seq; measured v5e
@@ -349,16 +352,7 @@ class BertModel(nn.Module):
 
         layer_cls = BertLayer
         if cfg.remat:
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            elif cfg.remat_policy == "full":
-                policy = None
-            else:
-                raise ValueError(
-                    f"remat_policy must be 'full' or 'dots', got "
-                    f"{cfg.remat_policy!r}")
-            layer_cls = nn.remat(BertLayer, static_argnums=(3,),
-                                 policy=policy)
+            layer_cls = remat_block(BertLayer, (3,), cfg.remat_policy)
         for i in range(cfg.num_layers):
             x = layer_cls(cfg, name=f"layer_{i}")(x, mask4d, deterministic)
 

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from apex_tpu import profiler
 from apex_tpu.normalization import FusedLayerNorm
+from apex_tpu.transformer.remat import remat_block
 
 _INIT = nn.initializers.normal(stddev=0.02)
 
@@ -278,7 +279,15 @@ class GPTConfig:
     dropout: float = 0.1
     layernorm_eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
-    remat: bool = True
+    remat: bool = True                 # activation checkpointing per block
+    # what a checkpointed dense block keeps (apex_tpu/transformer/
+    # remat.py): "selective" keeps the matmul and flash-attention outputs
+    # and recomputes only the elementwise ops; "full" keeps the block's
+    # input alone and recomputes the whole forward pass - 18% more step
+    # time for 3.78 GiB less live memory at 24 x 1024, S=1024, B=8 on a
+    # v5e (PERF.md section 6, PR 31): the way back for a job that fitted
+    # only under full recomputation. An expert block is always "full"
+    remat_policy: str = "selective"    # "selective" | "full"
     fused_kernels: bool = True
     attention_backend: str = "flash"   # flash | ring | ulysses
     context_axis: str = "context"
@@ -646,12 +655,17 @@ class GPTModel(nn.Module):
                        fold_axes=_ctx_fold_axes(cfg))(
             x, deterministic=deterministic)
 
-        block_cls = GPTBlock
+        dense_cls = moe_cls = GPTBlock
         if cfg.remat:
-            block_cls = nn.remat(GPTBlock, static_argnums=(2,))
+            dense_cls = remat_block(GPTBlock, (2,), cfg.remat_policy)
+            # a block that routes recomputes everything: rows kept across
+            # a recomputed routing can meet another order in the backward
+            # pass (transformer/remat.py)
+            moe_cls = remat_block(GPTBlock, (2,), "full")
         for i in range(cfg.num_layers):
             use_moe = (cfg.num_experts > 0
                        and i % max(cfg.moe_layer_freq, 1) == 0)
+            block_cls = moe_cls if use_moe else dense_cls
             x = block_cls(cfg, use_moe, name=f"h_{i}")(x, deterministic)
         return _norm(cfg, "ln_f")(x), wte
 

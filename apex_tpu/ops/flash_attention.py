@@ -83,9 +83,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import profiler
 from apex_tpu.ops._common import (
     LANE,
     interpret_mode as _interpret,
@@ -945,10 +947,21 @@ def _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate=0.0,
     return out[:, :, :Sq, :D], lse
 
 
+def _name_residuals(out, lse):
+    """Tag the forward kernel's outputs so that a rematerialised caller
+    can keep them by name (``apex_tpu/transformer/remat.py``) and not run
+    the kernel twice; the identity anywhere else. The tagged ``out`` must
+    also be the primal output: an untagged twin would be recomputed."""
+    out = checkpoint_name(out, profiler.FLASH_OUT)
+    if lse is not None:  # the jnp fallback has none
+        lse = checkpoint_name(lse, profiler.FLASH_LSE)
+    return out, lse
+
+
 def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, dropout_rate,
                    dropout_seed):
-    out, lse = _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate,
-                          dropout_seed)
+    out, lse = _name_residuals(*_flash_fwd(
+        q, k, v, key_mask, causal, scale, dropout_rate, dropout_seed))
     return out, (q, k, v, key_mask, out, lse, dropout_seed)
 
 
@@ -1068,8 +1081,8 @@ def _fwl_fwd(q, k, v, key_mask, causal, scale, dropout_rate, dropout_seed):
         out, lse_t = _with_lse_reference(q, k, v, key_mask, causal, scale,
                                          dropout_rate, dropout_seed)
         return (out, lse_t), (q, k, v, key_mask, out, None, dropout_seed)
-    out, lse = _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate,
-                          dropout_seed)
+    out, lse = _name_residuals(*_flash_fwd(
+        q, k, v, key_mask, causal, scale, dropout_rate, dropout_seed))
     return ((out, lse[..., :q.shape[2]]),
             (q, k, v, key_mask, out, lse, dropout_seed))
 
@@ -1435,8 +1448,9 @@ def _bsh_fwd_impl(q, k, v, key_mask, num_heads, causal, scale,
 
 def _bsh_vjp_fwd(q, k, v, key_mask, num_heads, causal, scale,
                  dropout_rate, dropout_seed=None):
-    out, lse = _bsh_fwd_impl(q, k, v, key_mask, num_heads, causal, scale,
-                             dropout_rate, dropout_seed)
+    out, lse = _name_residuals(*_bsh_fwd_impl(
+        q, k, v, key_mask, num_heads, causal, scale, dropout_rate,
+        dropout_seed))
     return out, (q, k, v, key_mask, out, lse, dropout_seed)
 
 

@@ -71,6 +71,12 @@ which name themselves: ``gmm`` (forward and the rows' gradient) and
 ``tgmm`` (the weights' gradient) (:data:`LIBRARY_KERNEL_NAMES`); they sit
 under the ``moe_experts`` scope, which is what a reader should match.
 
+The flash-attention forward rules tag the kernel's outputs with
+``jax.ad_checkpoint.checkpoint_name`` (:data:`FLASH_RESIDUALS`:
+``flash_out``, ``flash_lse``) so that a rematerialised block can keep them
+by name (``apex_tpu/transformer/remat.py``); outside a ``remat`` with a
+policy that reads names the tag is the identity and compiles to nothing.
+
 A model may report step counters beside its loss
 (``build_train_step(has_aux=True)``; they arrive with the loss in
 ``metrics["aux"]``, no extra sync): :data:`STEP_COUNTERS`.
@@ -165,6 +171,14 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
 
 # kernels of a library the train path calls (named by the library)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
+
+# -- residuals a rematerialised block may keep (``checkpoint_name``) -----------
+# the flash-attention forward rules name the kernel's outputs; the
+# "selective" policy of ``apex_tpu.transformer.remat`` keeps them, so the
+# backward pass does not run ``flash_fwd`` a second time
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+FLASH_RESIDUALS = (FLASH_OUT, FLASH_LSE)
 
 # -- host annotations ----------------------------------------------------------
 TRAIN_DISPATCH = "train_dispatch"

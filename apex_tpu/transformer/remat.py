@@ -1,0 +1,50 @@
+"""What a rematerialised transformer block keeps for its backward pass.
+
+One helper for the dense block stacks (``models/bert.py``,
+``models/gpt.py``): :func:`remat_block` wraps a block class in
+``flax.linen.remat`` under one of two policies.
+
+``"selective"`` (the default of ``BertConfig`` / ``GPTConfig``) keeps what
+is expensive to compute twice and recomputes the rest: every matmul
+output (``dots_with_no_batch_dims_saveable``) and the flash-attention
+call's residuals, which the kernel's forward rules name
+(:data:`apex_tpu.profiler.FLASH_RESIDUALS`), stay live from the forward
+pass; LayerNorm, GELU, dropout, bias and residual adds are recomputed
+from them. ``"full"`` keeps the block's input alone and recomputes the
+whole forward pass in the backward: 18-19% more step time for 2.55-3.78
+GiB less live memory at 8,192 tokens a chip over 24 layers of width 1024
+on a v5e (``PERF.md`` section 6, PR 31). Megatron-LM's "selective
+activation recomputation" is the same split.
+
+A block that ROUTES (top-k experts) must be wrapped ``"full"``: rows kept
+across a recomputed routing can meet a recomputed order that differs by a
+bfloat16 rounding (``PERF.md`` section 6, PR 30). The caller decides that
+from the block's type.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+
+from apex_tpu import profiler
+
+POLICIES = ("selective", "full")
+
+
+def _policy(name):
+    if name == "selective":
+        cp = jax.checkpoint_policies
+        return cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable,
+            cp.save_only_these_names(*profiler.FLASH_RESIDUALS))
+    if name == "full":
+        return None
+    raise ValueError(
+        f"remat_policy must be one of {POLICIES}, got {name!r}")
+
+
+def remat_block(block_cls, static_argnums, policy):
+    """``block_cls`` wrapped in ``nn.remat`` under the named policy."""
+    return nn.remat(block_cls, static_argnums=static_argnums,
+                    policy=_policy(policy))

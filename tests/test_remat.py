@@ -1,0 +1,260 @@
+"""What a rematerialised block keeps (``apex_tpu/transformer/remat.py``).
+
+``"selective"`` keeps the matmul outputs and the flash kernel's outputs and
+recomputes the elementwise ops; ``"full"`` recomputes the whole block. The
+two and ``remat=False`` are the same mathematics: equal losses and
+gradients, with dropout on too (the masks are regenerated from the same
+seeds). What differs is what the differentiated step does twice, which the
+jaxpr shows."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu import profiler
+from apex_tpu.models.bert import BertConfig, BertForPreTraining
+from apex_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+from apex_tpu.transformer import remat
+
+# the module: ``apex_tpu.ops`` exports the function under the same name
+fa = importlib.import_module("apex_tpu.ops.flash_attention")
+
+LAYERS, SEQ = 2, 128
+
+
+def _bert(dropout=0.0, wide_heads=False, **kw):
+    """(loss(params), params). ``wide_heads``: heads of 64, which take
+    the (B, S, H) flash entry; the tiny default's heads of 16 take the
+    transposed one."""
+    if wide_heads:
+        kw.update(hidden_size=128, num_heads=2)
+    cfg = BertConfig.tiny(max_position_embeddings=SEQ, flash_min_seq=SEQ,
+                          hidden_dropout=dropout, attention_dropout=dropout,
+                          **kw)
+    model = BertForPreTraining(cfg)
+    rng = np.random.RandomState(0)
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    mask = jnp.ones((2, SEQ), jnp.int32).at[:, -5:].set(0)
+    params = model.init(jax.random.PRNGKey(0), ids, None, mask)
+
+    def loss(p):
+        mlm, nsp = model.apply(p, ids, None, mask, deterministic=False,
+                               rngs={"dropout": jax.random.PRNGKey(1)})
+        return jnp.mean(jnp.square(mlm)) + jnp.mean(nsp)
+
+    return loss, params
+
+
+def _gpt(dropout=0.0, **kw):
+    cfg = GPTConfig.tiny(dropout=dropout, **kw)
+    model = GPTLMHeadModel(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)
+
+    def loss(p):
+        logits = model.apply(p, ids, deterministic=False,
+                             rngs={"dropout": jax.random.PRNGKey(1)},
+                             mutable=["losses"])[0]
+        return jnp.mean(jnp.square(logits))
+
+    return loss, params
+
+
+MAKE = {"bert": _bert, "gpt": _gpt}
+
+
+def _ops(loss, params):
+    """{(primitive or kernel name, recomputed?, flax module or None): count}
+    over the differentiated step's jaxpr. JAX puts ``rematted_computation``
+    into the name stack of what a checkpoint's backward does again."""
+    counts = {}
+
+    def walk(jaxpr, recomputed):
+        for eqn in jaxpr.eqns:
+            stack = str(eqn.source_info.name_stack)
+            again = recomputed or "rematted_computation" in stack
+            name = eqn.primitive.name
+            if name == "pallas_call":      # a kernel counts as one op
+                name = eqn.params["name"]
+            layer = next((part for part in stack.split("/")
+                          if part.startswith(("layer_", "h_"))), None)
+            for key in ((name, again), (name, again, layer)):
+                counts[key] = counts.get(key, 0) + 1
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, again)
+
+    walk(jax.make_jaxpr(jax.grad(loss))(params).jaxpr, False)
+    return counts
+
+
+# -- the same mathematics -------------------------------------------------------
+
+@pytest.mark.parametrize("other", [dict(remat_policy="full"),
+                                   dict(remat=False)],
+                         ids=["full", "no_remat"])
+@pytest.mark.parametrize("dropout", [0.0, 0.1])
+@pytest.mark.parametrize("model", ["bert", "gpt"])
+def test_selective_is_the_same_mathematics(model, dropout, other):
+    loss, params = MAKE[model](dropout)            # the class default
+    want_loss, want = jax.jit(jax.value_and_grad(
+        MAKE[model](dropout, **other)[0]))(params)
+    got_loss, got = jax.jit(jax.value_and_grad(loss))(params)
+    assert abs(float(got_loss) - float(want_loss)) <= 1e-6
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+    assert any(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree.leaves(got))
+
+
+def test_selective_is_the_default_of_both_configs():
+    assert BertConfig().remat and BertConfig().remat_policy == "selective"
+    assert GPTConfig().remat and GPTConfig().remat_policy == "selective"
+    assert remat.POLICIES == ("selective", "full")
+
+
+# -- what is done twice ----------------------------------------------------------
+
+# (model, its options, a block's matmuls and hidden dropouts that "full"
+# does twice: GPT's block ends in ``mlp_out`` + dropout, whose outputs no
+# backward op reads). The tiny BERT's heads of 16 and the tiny GPT take
+# the transposed flash entry, heads of 64 the (B, S, H) entry
+STACKS = [("bert", dict(), 6, 2), ("bert", dict(wide_heads=True), 6, 2),
+          ("gpt", dict(), 5, 1)]
+
+
+@pytest.mark.parametrize("model,options,dots,drops", STACKS,
+                         ids=["bert-bhsd", "bert-bsh", "gpt-bhsd"])
+def test_selective_recomputes_no_matmul_and_no_flash(model, options, dots,
+                                                     drops):
+    full = _ops(*MAKE[model](0.1, remat_policy="full", **options))
+    kept = _ops(*MAKE[model](0.1, **options))
+    none = _ops(*MAKE[model](0.1, remat=False, **options))
+    # "full": every block's matmuls and its flash forward run twice
+    assert full["dot_general", True] == dots * LAYERS
+    assert full["flash_fwd", True] == LAYERS
+    assert full["flash_fwd", False] == LAYERS
+    # "selective": each layer's flash forward once, no matmul again ...
+    assert ("dot_general", True) not in kept
+    assert ("flash_fwd", True) not in kept
+    assert kept["flash_fwd", False] == LAYERS
+    assert kept["dot_general", False] == none["dot_general", False]
+    # ... while the elementwise ops are (the hidden dropouts' kernel)
+    assert kept["dropout_apply", True] == drops * LAYERS
+    assert ("dropout_apply", True) not in none
+    # the backward kernels are the same in all three
+    backward = [k for k in full if k[0].startswith("flash_bwd")
+                and len(k) == 2]
+    assert backward and all(full[k] == kept[k] == none[k] for k in backward)
+
+
+def test_dots_alone_would_recompute_flash(monkeypatch):
+    """The ``"dots"`` policy offered before PR 31 kept the matmuls but not
+    the Pallas call's outputs: the counts above tell the two apart."""
+    import flax.linen as nn
+    from apex_tpu.models import bert
+
+    dots = nn.remat(
+        bert.BertLayer, static_argnums=(3,),
+        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    monkeypatch.setattr(bert, "remat_block", lambda *a: dots)
+    ops = _ops(*_bert(0.1))
+    assert ("dot_general", True) not in ops
+    assert ops["flash_fwd", True] == LAYERS
+
+
+# -- a block that routes keeps full recomputation ---------------------------------
+
+@pytest.mark.parametrize("policy", ["selective", "full"])
+def test_expert_block_recomputes_everything_under_either_policy(policy):
+    # h_0 routes, h_1 is dense
+    ops = _ops(*_gpt(0.0, remat_policy=policy, num_experts=4,
+                     moe_layer_freq=2))
+    assert ops["dot_general", True, "h_0"] == 8    # router + experts too
+    assert ops["flash_fwd", True, "h_0"] == 1
+    assert ops.get(("dot_general", True, "h_1"), 0) == (
+        5 if policy == "full" else 0)
+    assert ops.get(("flash_fwd", True, "h_1"), 0) == (
+        1 if policy == "full" else 0)
+
+
+# -- names ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["dots", "none", "Selective", ""])
+@pytest.mark.parametrize("model", ["bert", "gpt"])
+def test_unknown_policy_raises(model, name):
+    with pytest.raises(ValueError, match="remat_policy"):
+        MAKE[model](remat_policy=name)
+    with pytest.raises(ValueError, match="remat_policy"):
+        remat.remat_block(object, (), name)
+
+
+def test_residual_names_are_one_vocabulary():
+    names = profiler.FLASH_RESIDUALS
+    assert len(set(names)) == len(names)
+    assert not set(names) & set(profiler.SCOPES + profiler.KERNEL_NAMES)
+    for name in names:
+        assert name in profiler.__doc__
+
+
+# -- the tags are the identity outside a remat ------------------------------------
+
+def _qkv(shape, seed=0):
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.randn(*shape), jnp.float32) for _ in range(3)]
+
+
+def _entry_transposed(q, k, v):
+    return fa.flash_attention(q, k, v, None, True, 0.25, 0.1, jnp.int32(7))
+
+
+def _entry_with_lse(q, k, v):
+    out, lse = fa.flash_attention_with_lse(q, k, v, None, False, 0.25, 0.1,
+                                           jnp.int32(7))
+    return out * jnp.exp(-lse).transpose(0, 1, 3, 2)
+
+
+def _entry_bsh(q, k, v):
+    return fa.flash_attention_bsh(q, k, v, None, 2, True, 0.125, 0.1,
+                                  jnp.int32(7))
+
+
+ENTRIES = {"flash_attention": (_entry_transposed, (2, 2, 256, 16)),
+           "flash_attention_with_lse": (_entry_with_lse, (2, 2, 256, 16)),
+           "flash_attention_bsh": (_entry_bsh, (2, 128, 128))}
+
+
+@pytest.mark.parametrize("entry", sorted(ENTRIES))
+def test_residual_tags_change_nothing_outside_remat(entry, monkeypatch):
+    fn, shape = ENTRIES[entry]
+    q, k, v = _qkv(shape)
+
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(fn(q, k, v)))
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    text = str(jax.make_jaxpr(grad)(q, k, v))
+    for name in profiler.FLASH_RESIDUALS:
+        assert f"name[name={name}]" in text
+    tagged = jax.jit(grad)(q, k, v)
+    # the parent's rules: the same code with no tag (JAX caches a
+    # custom_vjp's traced forward rule by function and shapes)
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    jax.clear_caches()
+    try:
+        assert "name[" not in str(jax.make_jaxpr(grad)(q, k, v))
+        plain = jax.jit(grad)(q, k, v)
+    finally:
+        jax.clear_caches()
+    for a, b in zip(jax.tree.leaves(tagged), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -21,6 +21,7 @@ same tests, and a topology that cannot be described skips instead of
 failing collection.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -313,6 +314,23 @@ def test_kernel_compiles_inside_shard_map_on_four_chips(
 # -- the whole step: does BERT-large B=16 S=512 fit one chip? --------------
 
 
+# what the class default of the block stacks ("selective" recomputation:
+# the matmul and flash outputs kept) may hold live at 8,192 tokens a chip
+SELECTIVE_LIVE_BYTES = 12 * 2 ** 30
+
+
+def _compile_bert_large_step(remat_policy, **trainer_options):
+    """(trainer, memory analysis, HLO text) of ``chip_smoke.py``'s
+    BERT-large step at S=512 under a recomputation policy."""
+    import chip_smoke
+
+    cfg = dataclasses.replace(chip_smoke.bert_large_config(),
+                              remat_policy=remat_policy)
+    run = chip_smoke.build_bert_trainer(cfg, seq=S, **trainer_options)
+    compiled = run.step.lower(run.state, run.batch).compile()
+    return run, compiled.memory_analysis(), compiled.as_text()
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("donate", [True, False])
 def test_bert_large_train_step_fits_one_v5e(donate, one_chip,
@@ -320,17 +338,15 @@ def test_bert_large_train_step_fits_one_v5e(donate, one_chip,
     """The headline step of ``chip_smoke.py`` (24 x 1024, S=512, B=16,
     amp O2 + FusedLAMB through ``build_train_step``) compiled from
     shapes for one described chip: its kernels are compiled in and,
-    with donation, its memory fits 16 GB. Slow (a minute or two): run by
-    hand before a chip call, not in tier 1."""
+    with donation, its memory fits 16 GB - under 12 GiB with the class
+    default's kept activations, and with 24 kernels fewer than under
+    ``remat_policy="full"`` (no ``flash_fwd`` runs twice). Slow (minutes):
+    run by hand before a chip call, not in tier 1."""
     import chip_smoke
 
-    run = chip_smoke.build_bert_trainer(
-        chip_smoke.bert_large_config(), batch=B, seq=S, donate=donate,
-        abstract_on=one_chip)
-    compiled = run.step.lower(run.state, run.batch).compile()
-    text = compiled.as_text()
+    options = dict(batch=B, donate=donate, abstract_on=one_chip)
+    _, mem, text = _compile_bert_large_step("selective", **options)
     assert _n_kernels(text) > 0
-    mem = compiled.memory_analysis()
     live = chip_smoke.live_bytes(mem)
     print(f"donate={donate}: args {mem.argument_size_in_bytes / 2**30:.2f} "
           f"GiB, out {mem.output_size_in_bytes / 2**30:.2f}, alias "
@@ -339,30 +355,42 @@ def test_bert_large_train_step_fits_one_v5e(donate, one_chip,
           f"tpu_custom_call x{_n_kernels(text)}")
     if donate:
         assert mem.alias_size_in_bytes > 0
-        assert live < _hbm_bytes()
+        assert live < SELECTIVE_LIVE_BYTES < _hbm_bytes()
+        _, full_mem, full_text = _compile_bert_large_step("full", **options)
+        print(f"remat_policy='full': live "
+              f"{chip_smoke.live_bytes(full_mem) / 2**30:.2f} GiB, "
+              f"tpu_custom_call x{_n_kernels(full_text)}")
+        assert _n_kernels(full_text) - _n_kernels(text) == 24
+        assert chip_smoke.live_bytes(full_mem) < live
 
 
 @pytest.mark.slow
 def test_bert_large_ddp_step_compiles_for_four_v5e(mesh4, compiled_kernels):
-    """The four-chip phase of ``chip_smoke.py`` (``--four-chips``):
-    BERT-large through ``build_train_step(ddp=..., mesh=...)`` at
-    per-chip batch 4, compiled for the four described chips. The
-    kernels sit inside ``shard_map``, one flat all-reduce carries the
-    fp32 gradient bytes, and each chip's share fits 16 GB."""
+    """The four-chip cell's step (``bert_large.phase2_ddp4``: BERT-large
+    through ``build_train_step(ddp=..., mesh=...)`` at 16 rows a chip),
+    compiled for the four described chips. The kernels sit inside
+    ``shard_map``, one flat all-reduce carries the fp32 gradient bytes,
+    and each chip's share stays under 12 GiB with the class default's
+    kept activations, with 24 kernels fewer than under
+    ``remat_policy="full"``."""
     import chip_smoke
     from apex_tpu.parallel import DistributedDataParallel
     from apex_tpu.utils.hlo_audit import collective_stats
 
-    run = chip_smoke.build_bert_trainer(
-        chip_smoke.bert_large_config(), batch=16, seq=S,
-        ddp=DistributedDataParallel("data", delay_allreduce=True),
-        mesh=mesh4, abstract_on=NamedSharding(mesh4, P()))
-    compiled = run.step.lower(run.state, run.batch).compile()
-    text = compiled.as_text()
+    options = dict(batch=4 * B,
+                   ddp=DistributedDataParallel("data", delay_allreduce=True),
+                   mesh=mesh4, abstract_on=NamedSharding(mesh4, P()))
+    run, mem, text = _compile_bert_large_step("selective", **options)
     stats = collective_stats(text)
-    live = chip_smoke.live_bytes(compiled.memory_analysis())
+    live = chip_smoke.live_bytes(mem)
     print(f"ddp x4: all-reduce {stats['all-reduce']}, tpu_custom_call "
           f"x{_n_kernels(text)}, live per chip {live / 2**30:.2f} GiB")
     assert _n_kernels(text) > 0
     assert stats["all-reduce"]["bytes"] >= 4 * run.n_params
-    assert live < _hbm_bytes()
+    assert live < SELECTIVE_LIVE_BYTES < _hbm_bytes()
+    _, full_mem, full_text = _compile_bert_large_step("full", **options)
+    print(f"remat_policy='full': live per chip "
+          f"{chip_smoke.live_bytes(full_mem) / 2**30:.2f} GiB, "
+          f"tpu_custom_call x{_n_kernels(full_text)}")
+    assert _n_kernels(full_text) - _n_kernels(text) == 24
+    assert collective_stats(full_text)["all-reduce"] == stats["all-reduce"]
