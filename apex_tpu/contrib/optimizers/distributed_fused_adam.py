@@ -201,8 +201,8 @@ class _DistributedFlatOptimizer(FusedOptimizer):
 
     def stats(self) -> dict:
         """Flat-buffer accounting of the LAST init/step geometry —
-        ``flat_pad_elems`` is the ZeRO padding the donation-alias and
-        bench memory records must count as real bytes (the padded tail
+        ``flat_pad_elems`` is the ZeRO padding a donation-alias count or
+        a memory account must take as real bytes (the padded tail
         lives in every master/m/v buffer). Raises before the first
         ``init``/``step`` call (no geometry has been built yet)."""
         cached = getattr(self, "_meta_cache", None)

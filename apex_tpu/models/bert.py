@@ -15,7 +15,7 @@ and token-major ``(S, B)`` ordering is used across the first-dim
 gather/reduce-scatter mappings (the reason Megatron is s,b,h internally).
 Matmuls carry ``preferred_element_type=fp32`` so bf16 inputs hit the MXU
 with fp32 accumulation. ``fused_kernels=False`` swaps the Pallas norm/
-softmax for stock flax/jnp ops — the bench baseline.
+softmax for stock flax/jnp ops — the unfused baseline.
 """
 
 from __future__ import annotations

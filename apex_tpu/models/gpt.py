@@ -196,8 +196,8 @@ def quantize_gpt_model(model, params, mode):
 
 
 def gpt_param_bytes(params) -> int:
-    """Total device bytes of a param tree — the number the weight-
-    quantization bench arms and the ``dequant_gemm`` recorder event
+    """Total device bytes of a param tree — the number
+    tests/test_weight_quant.py and the ``dequant_gemm`` recorder event
     compare between the fp and quantized representations."""
     return int(sum(x.size * jnp.dtype(x.dtype).itemsize
                    for x in jax.tree.leaves(params)))

@@ -65,10 +65,9 @@ def flatten_stats(stats: Dict[str, object], sep: str = ".",
     """The ONE sanctioned flattener for nested ``stats()`` dicts:
     nested dict keys join with ``sep`` (``tenants.acme.tokens``),
     scalar leaves pass through, ``exclude`` drops top-level keys
-    (bench's scheduler record excludes the per-tenant ledger, which
-    has its own arm). Replaces the ad-hoc ``isinstance(v, dict)``
-    special-casing bench had to carry once ``stats()`` grew its first
-    nested section."""
+    (a scheduler record leaves the per-tenant ledger out). Replaces
+    ad-hoc ``isinstance(v, dict)`` special-casing at each caller now
+    that ``stats()`` has nested sections."""
     out: Dict[str, object] = {}
 
     def walk(prefix: str, d: Dict[str, object]) -> None:

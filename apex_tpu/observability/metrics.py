@@ -13,8 +13,9 @@ them, never a behavioral input (the zero-perturbation contract in
 docs/observability.md).
 
 Also home of the ONE shared percentile helper (:func:`percentile`):
-``StepTimer.summary()``, bench.py's TTFT/ITL reporting, and the
-histogram quantile estimator all interpolate the same way (numpy's
+``StepTimer.summary()``, the serving scenario tests' TTFT / ITL tails
+(tests/_traffic.py), and the histogram quantile estimator all
+interpolate the same way (numpy's
 default "linear" rule), so a p50 printed by any of them means the same
 thing. (The old ``StepTimer`` median was ``ts[n // 2]`` — the upper
 neighbor, not the median, for even n.)
@@ -225,7 +226,7 @@ class Histogram:
 class MetricsRegistry:
     """Name-keyed collection of metrics with get-or-create semantics
     (re-registering the same (name, kind) returns the existing metric —
-    the engine and a bench harness may both ask for the same handle;
+    the engine and a harness around it may both ask for the same handle;
     a kind clash raises)."""
 
     def __init__(self):

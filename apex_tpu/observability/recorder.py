@@ -1,9 +1,8 @@
 """Flight recorder: a bounded ring buffer of structured engine events
 (docs/observability.md).
 
-BENCH_r01/r05 died and left NOTHING — the motivation written into
-bench.py's section records, restated here for the engine itself:
-when a dispatch chain wedges, the operator needs the last N decisions
+Two early benchmark rounds died and left NOTHING. The lesson, for the
+engine itself: when a dispatch chain wedges, the operator needs the last N decisions
 (tick summaries, ladder transitions, quarantines, retries, cap walks),
 not a point-in-time ``stats()`` dict that says only where the counters
 ended up. The recorder is that black box: O(1) per event while enabled

@@ -14,7 +14,7 @@ sliver into VMEM, dequantizes in-register, and contracts the full K
 axis against the activations — the fp32 weights never exist outside
 VMEM, so HBM reads stay at the quantized byte width.
 
-READ SIDE ONLY, by design: the BENCH_r01 lesson recorded in ROADMAP.md
+READ SIDE ONLY, by design: the first round's lesson
 is that Pallas TPU has no scatter lowering — quantization itself (the
 *write* of the quantized tree, a one-time construction-cost in
 ``quantize_gpt_params``) stays in XLA, and the kernel reads what XLA
@@ -27,9 +27,13 @@ chain — elementwise dequant in fp32, then one fp32
 and the grid tiles ONLY the output-channel axis, never K. Output
 column ``j`` is a K-reduction over ``x`` and ``w[:, j]`` alone, so
 tiling N leaves every column's reduction order untouched and the
-kernel is BIT-IDENTICAL to :func:`dequant_matmul_reference` (a K-split
-with a partial-sum accumulator would not be — that is why there isn't
-one; K lives entirely in VMEM per step).
+kernel is BIT-IDENTICAL to :func:`dequant_matmul_reference` at matrix
+shapes (a K-split with a partial-sum accumulator would not be — that
+is why there isn't one; K lives entirely in VMEM per step). At a
+one-row (decode) shape XLA:CPU lowers the reference's matrix-vector
+product with another reduction order, and the two agree to a few
+float32 ulp of the accumulation; the contract is agreement with the
+reference, not bit-identity to one lowering.
 
 Selection: ``dequant_matmul(..., use_pallas=True)`` or the
 ``APEX_DEQUANT_GEMM_PALLAS=1`` env flag (read at trace time); the

@@ -1596,7 +1596,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_positions,
         ONE fused ``pallas_call``
         (:mod:`apex_tpu.ops.paged_attention_pallas`) instead of the
         composed XLA chain — READ side only (writes stay in XLA:
-        Pallas TPU has no scatter lowering, the BENCH_r01 lesson).
+        Pallas TPU has no scatter lowering, the first round's lesson).
         None consults the ``APEX_PAGED_ATTENTION_PALLAS`` env flag;
         either way the kernel is taken only when its static shape
         gate holds (interpret mode always qualifies), so the XLA

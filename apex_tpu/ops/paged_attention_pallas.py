@@ -13,7 +13,7 @@ streams into VMEM), so gathered K/V tiles live only in VMEM and HBM
 traffic drops to one pass over the pool rows the table actually names
 plus the ``[B, C, H, D]`` output.
 
-READ SIDE ONLY, by design: the BENCH_r01 lesson recorded in ROADMAP.md
+READ SIDE ONLY, by design: the first round's lesson
 is that Pallas TPU has no scatter lowering — the K/V *writes*
 (:func:`apex_tpu.serving.kv_cache.write_kv`) stay in XLA, whose
 ``scatter mode="drop"`` is exactly right for them, and the kernel

@@ -220,8 +220,8 @@ class StepTimer:
         print(timer.summary())       # {mean_ms, p50/p90/p99_ms, ...}
 
     Percentiles come from the shared interpolating helper
-    (:func:`apex_tpu.observability.metrics.percentile` — the one
-    bench.py's TTFT/ITL reporting and the metrics histograms use), so
+    (:func:`apex_tpu.observability.metrics.percentile` — the one the
+    metrics histograms use), so
     a p50 here means the same thing everywhere. (The previous median
     was ``ts[n // 2]`` — the upper neighbor, not the median, for
     even n.)

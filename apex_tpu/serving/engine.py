@@ -3699,8 +3699,8 @@ class InferenceEngine:
     def has_work(self) -> bool:
         """True while anything is queued, resident in a lane, or IN
         FLIGHT (an undrained decode dispatch). This is ``run()``'s loop
-        condition, public so external step-at-a-time drivers (bench.py
-        samples utilization per tick) drain completely without
+        condition, public so external step-at-a-time drivers (the tick
+        loop of tests/_traffic.py) drain completely without
         duplicating it — a hand-rolled ``waiting or slots`` check would
         silently drop the last dispatch's tokens."""
         return (bool(self.waiting) or self._pending is not None
@@ -3719,8 +3719,8 @@ class InferenceEngine:
         ``stats()`` attached instead of spinning forever (plus the
         flight recorder's tail when an observer is attached — and any
         exception escaping the drive loop writes the observer's crash
-        dump to its ``crash_dump_path`` before propagating, so the
-        next dead bench section ships its own post-mortem)."""
+        dump to its ``crash_dump_path`` before propagating, so a run
+        that dies ships its own post-mortem)."""
         try:
             while self.has_work:
                 if not self.step():

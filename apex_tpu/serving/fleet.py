@@ -30,7 +30,7 @@ refreshes a lightweight checkpoint every ``snapshot_interval_ticks``
 (:meth:`InferenceEngine.checkpoint` — no drain, bounded staleness).
 The router's health probe declares a replica dead on (a) any exception
 escaping its ``step()`` — including an injected
-:class:`~apex_tpu.utils.faults.FaultPlan` crash, the chaos bench's
+:class:`~apex_tpu.utils.faults.FaultPlan` crash, the chaos tests'
 weapon — or (b) ``health_patience`` consecutive no-progress ticks
 while it holds work. Failover re-homes everything: results that
 reached terminal inside the checkpoint are adopted directly;
@@ -39,7 +39,7 @@ emitted tokens and arrival PRNG identity (tokens emitted after the
 checkpoint re-derive bit-identically — resume determinism); accepted
 requests the checkpoint never saw re-inject fresh from the router's
 own copy. Nothing accepted is ever lost — the ``num_lost_requests``
-gauge computes the invariant and the chaos bench asserts it at zero.
+gauge computes the invariant and tests/test_fleet.py asserts it at zero.
 A request that kills ``max_request_failovers`` replicas in a row is
 the router-level quarantine: it terminal-fails instead of cascading
 through the fleet.
@@ -892,8 +892,8 @@ class FleetRouter:
         return rep.engine.abort(uid)
 
     def owners(self) -> Dict[str, int]:
-        """Live uid -> owning replica index (a copy) — the chaos
-        bench's victim bookkeeping, and an operator's 'where is my
+        """Live uid -> owning replica index (a copy) — the kill
+        scenarios' victim bookkeeping, and an operator's 'where is my
         request' lookup."""
         return dict(self._owner)
 
@@ -2085,7 +2085,7 @@ class FleetRouter:
         """The fleet counters (docs/fleet.md): routing, health,
         failover, migration, and the zero-lost invariant as a gauge —
         ``num_lost_requests`` is accepted minus live minus terminal
-        and must read 0 always (the chaos bench asserts it). Nested:
+        and must read 0 always (tests/test_fleet.py asserts it). Nested:
         ``replicas`` (per-slot health + load view) and ``tenants``
         (the fleet-wide ledger: per-replica rows summed, the router's
         door tallies and rate estimator merged in)."""

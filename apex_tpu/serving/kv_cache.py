@@ -149,7 +149,7 @@ def kv_block_bytes(num_layers: int, block_size: int, num_heads: int,
                    head_dim: int, dtype=None, quantization=None) -> int:
     """Device bytes one block costs across every layer — K + V payload
     plus (when quantized) the per-row fp32 scales. The number behind
-    the bench's byte-budget pool sizing and the tenant ledger's
+    a byte-budget pool sizing and the tenant ledger's
     reduced-footprint charge for quantized blocks."""
     if quantization is None:
         item = default_kv_dtype(dtype).itemsize

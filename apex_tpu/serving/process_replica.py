@@ -12,7 +12,7 @@ as RPCs over the :mod:`~apex_tpu.serving.wire` frame protocol on the
 child's stdio, so :class:`~apex_tpu.serving.fleet.FleetRouter` drives
 process replicas and in-process engines through ONE code path and a
 1-process-replica fleet certifies bit-identical to the in-process
-fleet (tests/test_process_replica.py, ``bench_serving_process``).
+fleet (tests/test_process_replica.py).
 
 The failure contract mirrors the in-process one deliberately:
 

@@ -204,8 +204,8 @@ def spec_verify_tokens(logits, drafts, draft_lens, lane_keys, token_idx,
       numerical property of two differently-shaped compiled programs
       (the PR 4 scan-vs-standalone drift is the cautionary tale), so
       it is certified empirically per backend: the cross-K/spec
-      bit-identity tests on CPU, ``bench_serving_speculative``'s
-      in-section assertion wherever the bench runs.
+      bit-identity tests of tests/test_speculative.py on the CPU; no
+      run on the chip holds it yet.
     - **sampled lanes**: accept draft ``d`` with probability ``p(d)``
       under the FILTERED target distribution (the same
       temperature/top-k/top-p chain non-speculative sampling draws

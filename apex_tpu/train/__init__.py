@@ -10,8 +10,8 @@ optimizer update — into ONE donated-buffer dispatch, and
 dispatch.
 
 ``build_reference_loop`` builds the hand-wired per-microbatch dispatch
-loop with bit-identical math — the certification baseline used by
-tests and ``bench_train_step``.
+loop with bit-identical math — the certification baseline of
+tests/test_train_step.py.
 """
 
 from apex_tpu.train.loop import (  # noqa: F401

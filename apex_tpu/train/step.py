@@ -35,8 +35,8 @@ tok/s); this module applies the same physics to training:
 Certification: :func:`build_reference_loop` builds the hand-wired
 per-microbatch dispatch loop (one jitted program per microbatch plus an
 apply program) from the SAME configuration with bit-identical math in
-the same order; tests and ``bench_train_step`` certify the fused scan
-against it the way the serving bench certifies cross-K decode.
+the same order; tests/test_train_step.py certifies the fused scan
+against it the way tests/test_serving.py certifies cross-K decode.
 """
 
 from __future__ import annotations
@@ -745,7 +745,7 @@ class ReferenceLoop:
     """The hand-wired per-microbatch dispatch loop the fused step
     replaces — SAME math, same order, one jitted program per microbatch
     plus a separate apply program. Exists as the certification baseline
-    (bit-identity in tests / ``bench_train_step``) and as an honest
+    (bit-identity in tests/test_train_step.py) and as an honest
     what-it-cost-before arm; do not use it to train.
     """
 

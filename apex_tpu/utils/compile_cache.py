@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``,
+One rule for every entry point (``chip_smoke.py``,
 ``examples/train_bert.py``): call :func:`enable_compile_cache` before
 the first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 already reads it and nothing is set here, so the cache can be placed

@@ -226,9 +226,81 @@ def test_role_aware_failover_zero_lost(tiny_gpt):
     fleet.kill_replica(0)
     res = fleet.run(return_status=True)
     st = fleet.stats()
+    assert st["num_failovers"] >= 1
     assert st["num_lost_requests"] == 0
     assert set(res) == {r.uid for r in reqs}
     assert all(v.status in ("finished", "aborted") for v in res.values())
+    for _, rep in fleet._alive():
+        rep.engine.check_allocator_integrity()
+
+
+# ---------------------------------------------------------------------------
+# the interference scenario: specialists against a colocated fleet
+# ---------------------------------------------------------------------------
+
+
+def test_specialists_beat_colocated_ttft_p99_scenario(tiny_gpt):
+    """What disaggregation is for, at EQUAL replica count: on a seeded
+    Poisson mix of long-decode requests and short latency-sensitive
+    ones, with two lanes a replica, a colocated replica's lanes are
+    pinned through whole decodes and a newcomer's prefill waits them
+    out; a prefill specialist recycles its lanes at the handoff. So
+    the {1 prefill + 1 decode} fleet's TTFT p99, in router ticks, is
+    under the 2-replica colocated fleet's on the same trace; the
+    handoff moved requests and bytes; and the decode specialist
+    prefilled only what it imported (sub-block tail resumes: at most
+    one chunk an import, never a fresh prompt)."""
+    from _traffic import TickClock, drive, poisson_burst_trace
+
+    model, params = tiny_gpt
+    ecfg = EngineConfig(max_batch=2, block_size=8, num_blocks=96,
+                        max_prefill_len=8, max_seq_len=64,
+                        enable_prefix_caching=True, spill_max_bytes=1 << 20,
+                        snapshot_interval_ticks=2, max_waiting=64, seed=11)
+    ticks = 14
+    rng = np.random.RandomState(1713)
+
+    def make(tick, k):
+        heavy = k % 3 != 2
+        # single-chunk prompts: the contended resource is the lane a
+        # long decode pins, not prefill bandwidth
+        prompt = list(rng.randint(
+            0, 128, int(rng.randint(6, 9) if heavy else rng.randint(4, 7))))
+        new = int(16 + rng.randint(0, 4) if heavy else rng.randint(2, 5))
+        samp = (SamplingParams() if k % 2 else
+                SamplingParams(temperature=1.0, top_k=40))
+        return lambda: Request(uid=f"q{k}", prompt=list(prompt),
+                               max_new_tokens=new, sampling=samp)
+
+    trace = poisson_burst_trace(
+        rng, ticks, 1.0, make, burst_start=ticks // 3,
+        burst_end=2 * ticks // 3, burst_factor=2)
+
+    def serve(**fleet_kw):
+        clock = TickClock()     # placement by the trace, not the machine
+        fleet = FleetRouter(model, params, ecfg,
+                            FleetConfig(num_replicas=2, **fleet_kw),
+                            clock=clock)
+        seen = drive(fleet, trace, clock=clock)
+        res = fleet.run(return_status=True)
+        assert sorted(res) == sorted(seen.accepted) and len(res) == len(trace)
+        assert fleet.stats()["num_lost_requests"] == 0
+        return fleet, seen.ttft_p99()
+
+    _, p99_colocated = serve()
+    fleet, p99_disagg = serve(replica_roles=("prefill", "decode"))
+    assert p99_disagg < p99_colocated, (p99_disagg, p99_colocated)
+    st = fleet.stats()
+    assert st["num_handoffs"] >= 1
+    assert st["num_handoff_requests"] >= 1
+    assert st["num_handoff_bytes"] > 0
+    decode = st["replicas"]["1"]
+    assert decode["role"] == "decode"
+    chunks = fleet.replicas[1].engine.stats()["num_prefill_chunks"]
+    assert chunks <= decode["num_migrated_in"], (
+        f"the decode specialist ran {chunks} prefill chunks for "
+        f"{decode['num_migrated_in']} imports: fresh prompts leaked "
+        f"onto the decode pool")
 
 
 # ---------------------------------------------------------------------------

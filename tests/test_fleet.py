@@ -839,6 +839,92 @@ def test_import_requests_anchors_observer_timeline(tiny_gpt):
 
 
 # ---------------------------------------------------------------------------
+# the kill scenario: a replica lost mid-burst, and what its victims pay
+# ---------------------------------------------------------------------------
+
+
+def test_kill_mid_burst_victims_ttft_bounded_scenario(tiny_gpt):
+    """Three replicas serve a seeded Poisson trace with a 3x burst
+    and shared-prefix groups. Run once undisturbed for the baseline
+    TTFT p99 in router ticks; then again with transient faults on every
+    replica, replica 1 hard-killed mid-burst (recovery from its last
+    periodic checkpoint alone) and replica 2 drained and migrated
+    later. Nothing accepted is lost, the failover and the migration
+    both fire, the survivors' allocators stay exact, and the requests
+    the dead replica owned at the kill - which pay the failover's
+    re-prefill - see their first token within ``4 x baseline p99 + 16``
+    ticks."""
+    from _traffic import TickClock, drive, poisson_burst_trace
+
+    model, params = tiny_gpt
+    ekw = dict(max_batch=4, block_size=8, num_blocks=64,
+               max_prefill_len=16, max_seq_len=48,
+               enable_prefix_caching=True, snapshot_interval_ticks=2,
+               max_waiting=32, seed=11)
+    ticks, kill_tick, drain_tick = 16, 6, 10
+    heads = np.random.RandomState(1813)
+    prefixes = [list(heads.randint(0, 128, 8)) for _ in range(3)]
+    rng = np.random.RandomState(1814)
+
+    def make(tick, k):
+        # a group's requests open with the same block-aligned head, so
+        # affinity routing has something to win
+        tail = int(rng.choice((8, 14))) - 4
+        prompt = (prefixes[k % 3] + list(rng.randint(0, 128, tail)))[:14]
+        samp = (SamplingParams() if k % 2 else
+                SamplingParams(temperature=1.0, top_k=40))
+        new = int(rng.choice((4, 6)))
+        return lambda: Request(uid=f"q{k}", prompt=list(prompt),
+                               max_new_tokens=new, sampling=samp)
+
+    trace = poisson_burst_trace(
+        rng, ticks, 0.5, make, burst_start=ticks // 3,
+        burst_end=2 * ticks // 3, burst_factor=3)
+
+    clock = TickClock()     # placement by the trace, not the machine
+
+    def fleet_of(faults=None, **engine_kw):
+        return FleetRouter(model, params, EngineConfig(**ekw, **engine_kw),
+                           FleetConfig(num_replicas=3), faults=faults,
+                           clock=clock)
+
+    def serve(fleet, before_step=None):
+        seen = drive(fleet, trace, clock=clock, before_step=before_step)
+        res = fleet.run(return_status=True)
+        assert sorted(res) == sorted(seen.accepted)     # exactly once
+        assert fleet.stats()["num_lost_requests"] == 0
+        return seen, res
+
+    base, _ = serve(fleet_of())
+
+    fleet = fleet_of(
+        faults=[FaultPlan([FaultSpec(site=site, kind="transient",
+                                     every=every)], seed=seed)
+                for site, every, seed in (("prefill", 9, 1815),
+                                          ("decode", 11, 1816),
+                                          ("decode", 13, 1817))],
+        max_dispatch_retries=3)
+    victims = []
+
+    def kill_then_drain(tick, seen):
+        if tick == kill_tick:
+            victims.extend(u for u, o in fleet.owners().items() if o == 1)
+            fleet.kill_replica(1)
+        if tick == drain_tick and fleet.replicas[2].alive:
+            fleet.drain_replica(2)
+
+    seen, res = serve(fleet, kill_then_drain)
+    stats = fleet.stats()
+    assert stats["num_failovers"] >= 1, "the kill never fired"
+    assert stats["num_migrations"] >= 1, "the drain never migrated"
+    assert sum(r.status == "finished" for r in res.values()) > 0
+    for _, rep in fleet._alive():
+        rep.engine.check_allocator_integrity()
+    assert victims, "the killed replica owned nothing"
+    assert seen.ttft_p99(victims) <= 4.0 * base.ttft_p99() + 16.0
+
+
+# ---------------------------------------------------------------------------
 # the fuzz interleaving (satellite)
 # ---------------------------------------------------------------------------
 

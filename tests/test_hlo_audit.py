@@ -1,20 +1,16 @@
-"""The bench's secondary metrics must be regression-WORTHY (round-3
-verdict #3): a deliberately-introduced regression must visibly move the
-recorded value. These tests drive the measurement helpers themselves —
-the HLO collective counter against a program with a doubled sync, and
-the marginal timer's noise guard."""
-
-import sys
-from pathlib import Path
+"""``apex_tpu.utils.hlo_audit.collective_stats``, the counter behind
+``build_train_step(...).audit_collectives`` and the serving mesh's
+collective contract, must be regression-WORTHY: a deliberately
+introduced regression (a doubled gradient sync, a sync rewritten as
+reduce-scatter + all-gather) must visibly move what it reports. Plus
+the Ulysses attention collectives it is used to audit."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-import bench  # noqa: E402  (repo-root module)
+from apex_tpu.utils.hlo_audit import collective_stats
 
 
 def _compiled_hlo(sync_twice):
@@ -38,12 +34,12 @@ def _compiled_hlo(sync_twice):
 
 
 def test_allreduce_counter_catches_doubled_sync():
-    ops1, bytes1 = bench.count_allreduce_bytes(_compiled_hlo(False))
-    ops2, bytes2 = bench.count_allreduce_bytes(_compiled_hlo(True))
-    assert ops1 >= 1 and bytes1 >= 64 * 16 * 4
+    one = collective_stats(_compiled_hlo(False))["all-reduce"]
+    two = collective_stats(_compiled_hlo(True))["all-reduce"]
+    assert one["ops"] >= 1 and one["bytes"] >= 64 * 16 * 4
     # the deliberate regression must move the metric
-    assert bytes2 > bytes1
-    assert ops2 > ops1
+    assert two["bytes"] > one["bytes"]
+    assert two["ops"] > one["ops"]
 
 
 def test_allreduce_counter_parses_tuple_shapes():
@@ -53,62 +49,13 @@ def test_allreduce_counter_parses_tuple_shapes():
         "%other = f32[8]{0} add(%x, %y)\n"
         "%ar2 = bf16[4,128]{1,0} all-reduce-start(%d)\n"
     )
-    ops, total = bench.count_allreduce_bytes(text)
-    assert ops == 2
-    assert total == 32 * 4 + 32 * 4 + 4 + 4 * 128 * 2
-
-
-def test_marginal_time_discards_noise_corrupted_windows():
-    """A latency spike in a small window would produce a negative
-    marginal; the guard must discard it and keep the clean pair."""
-    calls = {"n": 0}
-    t = {"now": 0.0}
-
-    def advance(n):
-        t["now"] += n * 0.010  # 10 ms true step
-
-    spikes = iter([0.200, 0.0, 0.0, 0.0])  # spike hits window 1's fetch
-
-    def fetch():
-        t["now"] += 0.100 + next(spikes, 0.0)
-        return 0.0
-
-    import time as time_mod
-
-    real = time_mod.perf_counter
-    time_mod.perf_counter = lambda: t["now"]
-    try:
-        dt = bench.marginal_time(advance, fetch, iters=8, windows=2)
-    finally:
-        time_mod.perf_counter = real
-    np.testing.assert_allclose(dt, 0.010, rtol=1e-6)
-
-
-def test_marginal_time_all_windows_corrupted_falls_back_positive():
-    t = {"now": 0.0}
-
-    def advance(n):
-        t["now"] += n * 0.010
-
-    spikes = iter([0.500, 0.0, 0.500, 0.0])  # every small window spiked
-
-    def fetch():
-        t["now"] += 0.100 + next(spikes, 0.0)
-        return 0.0
-
-    import time as time_mod
-
-    real = time_mod.perf_counter
-    time_mod.perf_counter = lambda: t["now"]
-    try:
-        dt = bench.marginal_time(advance, fetch, iters=8, windows=2)
-    finally:
-        time_mod.perf_counter = real
-    assert dt > 0
+    stats = collective_stats(text)["all-reduce"]
+    assert stats["ops"] == 2
+    assert stats["bytes"] == 32 * 4 + 32 * 4 + 4 + 4 * 128 * 2
 
 
 # ---------------------------------------------------------------------------
-# round 5: the generalized collective audit (apex_tpu.utils.hlo_audit)
+# every collective family under its own key
 # ---------------------------------------------------------------------------
 
 def _lower_shmap(fn, in_specs, out_specs, *args, n=8, axes=("data",)):
@@ -122,8 +69,6 @@ def test_collective_stats_identifies_each_kind():
     """Every collective family must be counted under its own key (the
     advisor-r4 finding: an all-reduce-only counter reads a grad sync
     rewritten as reduce-scatter + all-gather as an improvement)."""
-    from apex_tpu.utils.hlo_audit import collective_stats
-
     x = jnp.ones((8 * 8, 128))
 
     hlo = _lower_shmap(lambda x: jax.lax.psum(x, "data"),
@@ -152,8 +97,6 @@ def test_collective_stats_identifies_each_kind():
 
 
 def test_collective_stats_total_and_bytes():
-    from apex_tpu.utils.hlo_audit import collective_stats
-
     text = (
         "%ar = (f32[32]{0}, s32[]) all-reduce(%a, %b), replica_groups={}\n"
         "%ag = bf16[64,128]{1,0} all-gather-start(%c)\n"
@@ -176,8 +119,6 @@ def test_collective_stats_complex_f8_and_unknown_dtypes():
     true element sizes, and an unrecognized dtype must WARN instead of
     silently assuming 4 bytes."""
     import warnings
-
-    from apex_tpu.utils.hlo_audit import collective_stats
 
     text = (
         "%ar = c64[8,4]{1,0} all-reduce(%a), replica_groups={}\n"
@@ -204,8 +145,6 @@ def test_collective_audit_catches_migrated_grad_sync():
     replace the all-reduce grad sync with reduce-scatter + all-gather
     (same bytes moved, zero all-reduce bytes). The generalized stats
     must expose the migrated traffic."""
-    from apex_tpu.utils.hlo_audit import collective_stats
-
     p = jnp.ones((64, 16))
     x = jnp.ones((8 * 2, 64))
 
@@ -245,7 +184,6 @@ def test_ulysses_attention_all_to_all_count():
     4 all_to_alls in forward (q, k, v to heads; out back to sequence)
     and 4 in backward (AD of all_to_all is its inverse)."""
     from apex_tpu.ops.ulysses_attention import ulysses_attention
-    from apex_tpu.utils.hlo_audit import collective_stats
 
     B, H, S, D = 2, 4, 16, 8
     rng = np.random.RandomState(0)

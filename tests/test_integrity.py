@@ -15,9 +15,8 @@ full stats dict — and enabling checksums alone changes no served
 token. Plus: the ``"corrupt"`` fault kind and its seeded perturbation
 helpers, the checksum/seal primitives (JSON-wire stable), budgeted
 background scrubbing, the fleet SDC determinism cross-check (a
-compute-corrupted replica is detected and retired), the recorder/
-trace_summary surface, and the ``tools/bench_diff.py`` artifact
-comparer."""
+compute-corrupted replica is detected and retired), and the
+recorder / trace_summary surface."""
 
 import importlib.util
 import json
@@ -819,90 +818,3 @@ def test_trace_summary_integrity_line():
     assert "1 SDC suspects retired (replica 1)" in line[0]
     # absent entirely on a clean run
     assert "integrity" not in ts.summarize({"recorder": {"events": []}})
-
-
-# ---------------------------------------------------------------------------
-# tools/bench_diff.py (CI satellite: the bench record gets a consumer)
-# ---------------------------------------------------------------------------
-
-
-def _artifact(tmp_path, name, sections, metrics, rc=0):
-    lines = [json.dumps(dict(r, section=s))
-             for s, r in sections.items()]
-    lines += [json.dumps(dict(r, metric=m))
-              for m, r in metrics.items()]
-    doc = {"n": 1, "cmd": "bench", "rc": rc,
-           "tail": "noise line\n" + "\n".join(lines) + "\n",
-           "parsed": None}
-    p = tmp_path / name
-    p.write_text(json.dumps(doc))
-    return str(p)
-
-
-def test_bench_diff_clean_and_deltas(tmp_path):
-    bd = _load_tool("bench_diff.py")
-    old = _artifact(tmp_path, "old.json",
-                    {"bench_a": {"status": "ok", "wall_time_s": 1.0}},
-                    {"m1": {"value": 2.0, "unit": "x",
-                            "vs_baseline": 2.0}})
-    new = _artifact(tmp_path, "new.json",
-                    {"bench_a": {"status": "ok", "wall_time_s": 1.5}},
-                    {"m1": {"value": 3.0, "unit": "x",
-                            "vs_baseline": 3.0}})
-    rc, lines = bd.diff(bd.parse_artifact(old), bd.parse_artifact(new))
-    assert rc == 0
-    joined = "\n".join(lines)
-    assert "2 -> 3 (1.500x)" in joined
-    assert bd.main([old, new]) == 0
-
-
-def test_bench_diff_disappeared_section_fails(tmp_path):
-    bd = _load_tool("bench_diff.py")
-    old = _artifact(tmp_path, "old.json",
-                    {"bench_a": {"status": "ok", "wall_time_s": 1.0},
-                     "bench_b": {"status": "ok", "wall_time_s": 1.0}},
-                    {})
-    new = _artifact(tmp_path, "new.json",
-                    {"bench_a": {"status": "ok", "wall_time_s": 1.0}},
-                    {})
-    assert bd.main([old, new]) == 1
-    # status regression ok -> failed also fails
-    new2 = _artifact(tmp_path, "new2.json",
-                     {"bench_a": {"status": "failed",
-                                  "wall_time_s": 1.0},
-                      "bench_b": {"status": "ok", "wall_time_s": 1.0}},
-                     {})
-    assert bd.main([old, new2]) == 1
-    # additions never fail
-    assert bd.main([new, old]) == 0
-
-
-def test_bench_diff_parses_pre_section_artifacts(tmp_path):
-    """An artifact of the older shape — metric lines only, no section
-    records, a log line in the tail, the last record repeated under
-    ``parsed`` — still parses, and the missing sections are reported,
-    not failed on."""
-    bd = _load_tool("bench_diff.py")
-
-    def pre_section(name, lamb):
-        path = _artifact(
-            tmp_path, name, {},
-            {"bert_large_pretrain_s512_samples_per_sec_per_chip":
-                 {"value": 80.0, "unit": "samples/sec",
-                  "vs_baseline": 5.9},
-             "fused_lamb_step_speedup_vs_per_leaf_eager":
-                 {"value": lamb, "unit": "x", "vs_baseline": lamb}})
-        doc = json.loads(Path(path).read_text())
-        doc["tail"] = "# B=16 S=512: a log line, not a record\n" + doc["tail"]
-        doc["parsed"] = {"metric": "ddp_sync_efficiency", "value": 1.0,
-                         "unit": "ratio", "vs_baseline": 1.0}
-        Path(path).write_text(json.dumps(doc))
-        return path
-
-    old = bd.parse_artifact(pre_section("old.json", 1.097))
-    new = bd.parse_artifact(pre_section("new.json", 3.246))
-    assert len(old["metrics"]) == 3 and len(new["metrics"]) == 3
-    assert not old["sections"] and not new["sections"]
-    rc, lines = bd.diff(old, new)
-    assert rc == 0                          # no sections -> no liveness
-    assert any("pre-PR-6" in ln for ln in lines)

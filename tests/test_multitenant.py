@@ -774,3 +774,128 @@ def test_prefix_flush_charges_registering_tenant(tiny_gpt):
     assert flushed > 0
     assert (engine.stats()["tenants"]["hog"]["flushed_blocks"]
             == flushed)
+
+
+# ---------------------------------------------------------------------------
+# the isolation scenario: one flooding tenant against two victims
+# ---------------------------------------------------------------------------
+
+
+def test_flooding_tenant_alone_is_shed_scenario(tiny_gpt):
+    """Two well-behaved tenants with deadlines share a prefix-cached
+    pool with an adversary that floods identical prompts at six times
+    their rate, under weighted DRR admission and quotas on the flood
+    (waiting cap, resident-block ceiling). Ticks of the injected clock
+    throughout. (1) The victims' exact seeded trace runs solo for
+    their baseline TTFT p99. (2) The same trace runs against the
+    flood: the flood is the ONLY tenant shed at the door or throttled,
+    both victims finish tokens, and each victim's TTFT p99 stays
+    within ``3 x solo + 12`` ticks. (3) The mix runs again under
+    injected prefill / decode faults, an abort of every fifth accepted
+    request and low ladder watermarks: aborts, retries, quota sheds
+    and ladder steps all fire, every accepted request ends terminal,
+    and the allocator's per-tenant accounting is exact."""
+    from _traffic import TickClock, drive, poisson_burst_trace
+    from apex_tpu.utils.faults import FaultPlan, FaultSpec
+
+    ekw = dict(max_batch=4, block_size=8, num_blocks=64,
+               max_prefill_len=16, max_seq_len=48, max_waiting=24,
+               enable_prefix_caching=True,
+               tenant_weights={"acme": 4, "bolt": 4, "flood": 1},
+               tenant_quotas={"flood": TenantQuota(
+                   max_waiting=4, max_resident_blocks=5)},
+               drr_quantum=16)
+    ticks = 24
+    victims = ("acme", "bolt")
+
+    def victim_trace():
+        # their OWN rng: the solo and the mixed run see the same bytes
+        rng = np.random.RandomState(1790)
+
+        def make(tick, k):
+            tenant = victims[k % 2]
+            return Request(
+                uid=f"{tenant}-{k}",
+                prompt=list(rng.randint(0, 128, int(rng.choice((6, 10))))),
+                max_new_tokens=int(rng.choice((3, 5))),
+                tenant=tenant, deadline_s=60.0,
+                sampling=(SamplingParams() if k % 2 == 0 else
+                          SamplingParams(temperature=1.0, top_k=40)))
+
+        return poisson_burst_trace(rng, ticks, 0.25, make)
+
+    def flood_trace():
+        rng = np.random.RandomState(1791)
+        shared = list(rng.randint(0, 128, 10))
+        return poisson_burst_trace(
+            rng, ticks, 1.5,
+            lambda tick, k: Request(uid=f"flood-{k}", prompt=list(shared),
+                                    max_new_tokens=5, tenant="flood"))
+
+    def serve(trace, abort_every=None, faults=None, **overrides):
+        model, params = tiny_gpt
+        clock = TickClock()
+        engine = InferenceEngine(
+            model, params, EngineConfig(**{**ekw, **overrides}),
+            clock=clock, faults=faults)
+        aborted = set()
+
+        def abort_some(tick, seen):
+            for uid in seen.accepted[abort_every - 1::abort_every]:
+                if uid not in aborted and engine.abort(uid):
+                    aborted.add(uid)
+
+        seen = drive(engine, trace, clock=clock,
+                     before_step=abort_some if abort_every else None)
+        assert seen.stalls == 0
+        return engine, seen, engine.run(return_status=True)
+
+    def p99(seen, tenant):
+        return seen.ttft_p99([u for u in seen.accepted
+                              if u.startswith(tenant)])
+
+    def tenants_of(requests):
+        return {r.tenant for r in requests}
+
+    # (1) the victims solo, (2) the same victims against the flood
+    _, solo, _ = serve(victim_trace())
+    mixed = sorted(victim_trace() + flood_trace(), key=lambda x: x[0])
+    engine, mix, res = serve(mixed)
+    assert tenants_of(mix.shed) <= {"flood"}
+    refused = {u for u, r in res.items()
+               if r.status in ("throttled", "rejected")}
+    assert all(u.startswith("flood") for u in refused), refused
+    assert mix.shed or engine.stats()["num_throttled"] > 0, \
+        "the flood was never shed: the quotas were not exercised"
+    for t in victims:
+        assert p99(mix, t) <= 3.0 * p99(solo, t) + 12.0
+        assert sum(len(r.tokens) for u, r in res.items()
+                   if u.startswith(t) and r.status == "finished") > 0
+        assert engine.stats()["tenants"][t]["statuses"].get(
+            "throttled", 0) == 0
+
+    # (3) chaos over the same mix
+    faults = FaultPlan([
+        FaultSpec(site="prefill", kind="transient", every=11),
+        FaultSpec(site="decode", kind="transient", every=13),
+    ], seed=1792)
+    # the flood's queue share is capped at 4, so 4 is the pressure
+    # mark the ladder can reach
+    engine, chaos, res = serve(
+        mixed, abort_every=5, faults=faults,
+        max_waiting=8, queue_high_watermark=4,
+        free_block_low_watermark=0.25, degrade_patience=1,
+        max_dispatch_retries=3)
+    engine.check_allocator_integrity()
+    stats = engine.stats()
+    assert stats["num_cancelled"] > 0, "no abort fired"
+    assert stats["num_dispatch_retries"] > 0, "no fault fired"
+    assert stats["num_throttled"] > 0 or chaos.shed, "no quota shed"
+    assert stats["num_degrade_steps_down"] > 0, "the ladder never stepped"
+    # every accepted request ended terminal; what else has a verdict
+    # is the quota's refusals at the door
+    assert set(chaos.accepted) <= set(res)
+    assert {res[u].status for u in chaos.accepted} <= {
+        "finished", "cancelled", "timeout", "rejected", "failed"}
+    assert {res[u].status for u in set(res) - set(chaos.accepted)} \
+        <= {"throttled"}

@@ -525,25 +525,42 @@ def test_single_process_replica_fleet_bit_identical(tiny_gpt,
 
 
 def test_fleet_survives_real_sigkill(tiny_gpt):
+    """A child is SIGKILLed for real three ticks into a burst: nothing
+    accepted is lost, the slot respawns into a fresh OS process, and
+    the requests the dead child owned see their first token within
+    ``4 x p99 + 16`` router ticks of the same burst served undisturbed
+    (in process: the tick schedule is the mode's, the boot cost of the
+    respawn is wall time)."""
+    from _traffic import drive
+
     cfg, model, params = tiny_gpt
     ecfg = EngineConfig(**ENGINE_KW, snapshot_interval_ticks=2)
+
+    def burst():
+        return [(0, r) for r in _reqs(n=6, sampled=True, uid="k")]
+
+    base = drive(FleetRouter(model, params, ecfg,
+                             FleetConfig(num_replicas=2)), burst())
     fleet = FleetRouter(
         model, params, ecfg,
         FleetConfig(num_replicas=2, replica_mode="process",
                     respawn=True, rpc_timeout_s=60.0),
         model_spec=gpt_model_spec(cfg))
     try:
-        reqs = _reqs(n=6, sampled=True, uid="k")
-        for req in reqs:
-            fleet.add_request(req)
-        for _ in range(3):
-            fleet.step()
         victim = fleet.replicas[0].engine
         pid0 = victim.child_pid
-        os.kill(pid0, signal.SIGKILL)        # a REAL kill -9
+        owned = []
+
+        def kill_at_tick_3(tick, seen):
+            if tick == 3:
+                owned.extend(u for u, o in fleet.owners().items()
+                             if o == 0)
+                os.kill(pid0, signal.SIGKILL)        # a REAL kill -9
+
+        seen = drive(fleet, burst(), before_step=kill_at_tick_3)
         res = fleet.run(return_status=True)
         # zero lost accepted requests, exactly-once terminals
-        assert sorted(res) == sorted(r.uid for r in reqs)
+        assert sorted(res) == sorted(seen.accepted) and len(res) == 6
         assert all(r.status == "finished" for r in res.values())
         st = fleet.stats()
         assert st["num_lost_requests"] == 0
@@ -557,6 +574,8 @@ def test_fleet_survives_real_sigkill(tiny_gpt):
         # the corpse really is gone (waitpid would have reaped it;
         # poll() on the handle did)
         assert not victim.alive
+        assert owned, "the killed child owned nothing"
+        assert seen.ttft_p99(owned) <= 4.0 * base.ttft_p99() + 16.0
     finally:
         fleet.close()
     # close() disposed every child: none of the handles poll alive

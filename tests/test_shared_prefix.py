@@ -48,7 +48,7 @@ def tiny_gpt():
 
 BLK = 4096   # comfortably above one tiny-model block payload
 
-# the proven shared-tier physics (bench_serving_shared_prefix): a
+# the proven shared-tier physics (the equal-bytes scenario below): a
 # pool small enough that finished prompts EVICT into the local spill
 # tier (num_blocks=8 = one full 32-token sequence), a local tier big
 # enough to hold a whole seeded 7-block run (8 blocks — a run larger
@@ -323,6 +323,92 @@ def test_tier_off_constant_clock_stats_bit_identical(tiny_gpt):
                                            sort_keys=True,
                                            default=str))))
     assert runs[0] == runs[1]
+
+
+# ---------------------------------------------------------------------------
+# the capacity scenario: one shared tier against per-replica tiers at
+# equal total spill bytes
+# ---------------------------------------------------------------------------
+
+
+def test_shared_tier_beats_per_replica_tiers_at_equal_bytes_scenario(
+        tiny_gpt):
+    """What the fleet-global tier is for. Seven rotating 28-token
+    prefixes (odd, and placement is affinity-blind, so BOTH replicas
+    see EVERY prefix) finish as 8-block sequences: a deduped working
+    set of 56 blocks. The shared arm gives each replica an 8-block
+    local tier and the fleet one 60-block shared tier that holds the
+    set once; the per-replica arm splits the same 76 blocks into two
+    38-block local tiers, each too small for the 56 it needs privately,
+    so its LRU cycles. On one greedy trace the shared arm's fleet-wide
+    hit rate (prefix hits + spill re-admissions over looked-up blocks)
+    and its steady-state TTFT p99 in ticks (second half of the trace:
+    the cold misses are the same in both arms) beat the per-replica
+    arm's; publishes, dedupe and shared hits all moved; the tokens are
+    the same in both arms; and a replica killed mid-trace with the
+    tier on loses nothing."""
+    from _traffic import TickClock
+    from apex_tpu.observability import percentile
+
+    n_reqs = 28
+    reqs = _warm_trace(n=n_reqs, npref=7, seed=1713, uid="q")
+
+    def serve(spill_blocks, reqs=reqs, kill_before_pair=None, **fleet_kw):
+        # the router weighs a replica's backlog by its measured service
+        # time: under the tick clock every dispatch measures 0 and the
+        # placement is the trace's alone, not the machine's
+        clock = TickClock()
+        fleet = _fleet(tiny_gpt, n=2, spill_max_bytes=spill_blocks * BLK,
+                       clock=clock,
+                       fleet_kw=dict(affinity_weight=0.0, **fleet_kw))
+        submit, first, tick = {}, {}, 0
+        for k in range(0, len(reqs), 2):    # pairs, drained (_drive_pairs)
+            if k // 2 == kill_before_pair:
+                fleet.kill_replica(0)
+            for r in reqs[k:k + 2]:
+                fleet.add_request(Request(
+                    r.uid, list(r.prompt), sampling=r.sampling,
+                    max_new_tokens=r.max_new_tokens))
+                submit[r.uid] = tick
+            while fleet.has_work:
+                clock.now = float(tick)
+                fleet.step()
+                for uid, tok, _last in fleet.pop_stream_events():
+                    if tok >= 0:
+                        first.setdefault(uid, tick)
+                tick += 1
+        res = fleet.run(return_status=True)
+        assert sorted(res) == sorted(r.uid for r in reqs)
+        assert fleet.stats()["num_lost_requests"] == 0
+        engines = [rep.engine.stats() for _, rep in fleet._alive()]
+        hit_rate = (sum(s["prefix_hit_blocks"] + s["spill_hits"]
+                        for s in engines)
+                    / sum(s["prefix_lookup_blocks"] for s in engines))
+        steady = [first[r.uid] - submit[r.uid]
+                  for r in reqs[len(reqs) // 2:]]
+        return fleet, _resdict(res), hit_rate, percentile(steady, 99)
+
+    _, per_tokens, per_rate, per_p99 = serve(38)
+    fleet, sh_tokens, sh_rate, sh_p99 = serve(
+        8, shared_prefix_bytes=60 * BLK)
+    assert sh_rate > per_rate, (sh_rate, per_rate)
+    assert sh_p99 < per_p99, (sh_p99, per_p99)
+    st = fleet.stats()
+    assert st["num_shared_publishes"] >= 1
+    assert st["num_shared_dedupe"] >= 1, (
+        "both replicas' evictions of one prefix should collide in the "
+        "shared tier")
+    assert st["shared_tier_hits"] >= 1
+    assert sh_tokens == per_tokens
+    assert all(status == "finished" for _, status in sh_tokens.values())
+
+    # the kill needs the tier warm, not the whole trace: two visits of
+    # every prefix, replica 0 killed before the fifth pair
+    chaos, _, _, _ = serve(8, reqs=reqs[:14], kill_before_pair=4,
+                           respawn=True, shared_prefix_bytes=60 * BLK)
+    assert chaos.stats()["num_failovers"] >= 1, "the kill never fired"
+    for _, rep in chaos._alive():
+        rep.engine.check_allocator_integrity()
 
 
 # ---------------------------------------------------------------------------

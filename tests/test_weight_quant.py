@@ -3,9 +3,9 @@ layer (docs/serving.md "Quantized weight storage").
 
 The quantization transform (per-output-channel scales, deterministic
 bytes, the byte shrink, idempotency); the fused Pallas dequant-GEMM
-certified BIT-IDENTICAL to the XLA dequantize-then-dot reference in
-interpret mode (tiled and single-tile shapes, decode row counts
-included); quantized logits at tight tolerance to fp; engine greedy
+certified against the XLA dequantize-then-dot reference in
+interpret mode (bit-identical at the tiled and single-tile matrix
+shapes, a few float32 ulp at the decode row); quantized logits at tight tolerance to fp; engine greedy
 decode token-identical across ``weight_quantization`` on/off with
 speculation on/off; the restore-fingerprint refusal across mismatched
 modes; the process-replica params-checksum handshake covering the
@@ -174,8 +174,16 @@ def test_scale_leaves_shard_like_their_module(tiny):
 
 
 # ---------------------------------------------------------------------------
-# the fused Pallas dequant-GEMM: bit-identity to the XLA reference
+# the fused Pallas dequant-GEMM: agreement with the XLA reference
 # ---------------------------------------------------------------------------
+
+# the one-row tolerance, in units of float32 eps times the column's
+# accumulation magnitude sum_k |x_k * w_k| (what one rounding of a
+# partial sum can cost): reference and kernel sit 0.9-1.4 units apart
+# on the installed XLA, a weight rounded to int8 or fp8 sits ~6,000 /
+# ~25,000 units away
+_ONE_ROW_ULPS = 4.0
+
 
 @pytest.mark.parametrize("mode", QUANT_MODES)
 @pytest.mark.parametrize("m,k,n", [
@@ -183,18 +191,33 @@ def test_scale_leaves_shard_like_their_module(tiny):
     (8, 128, 128),     # aligned everything, single tile
     (4, 48, 96),       # unaligned single-tile fallback shape
 ])
-def test_pallas_dequant_gemm_bit_identical(mode, m, k, n):
-    """THE kernel cert: N-only tiling leaves every output column's
-    K-reduction order untouched, so the fused kernel must reproduce
-    the XLA dequantize-then-dot reference BIT for bit (interpret mode
-    on CPU), decode (single-row) shapes included."""
+def test_pallas_dequant_gemm_matches_reference(mode, m, k, n):
+    """THE kernel cert: the fused kernel agrees with the XLA
+    dequantize-then-dot reference (``dequant_matmul_reference``;
+    interpret mode on CPU). N-only tiling leaves every output column's
+    K-reduction untouched, so the matrix shapes are held BIT for bit.
+    The decode (single-row) shape is held to ``_ONE_ROW_ULPS``: XLA:CPU
+    lowers a matrix-vector product with another reduction order than a
+    matrix-matrix one, so the last bits differ there and neither side
+    is the more exact - the contract is agreement with the reference,
+    not bit-identity to one CPU lowering. The tolerance is one a
+    quantisation error cannot hide under: the unquantised product must
+    miss it by two orders of magnitude."""
     rr = np.random.RandomState(7)
     x = jnp.asarray(rr.randn(m, k), jnp.float32)
     w = jnp.asarray(rr.randn(k, n), jnp.float32)
     w_q, scale = quantize_dense_kernel(w, mode)
-    ref = dg.dequant_matmul_reference(x, w_q, scale)
-    fused = dg.dequant_matmul(x, w_q, scale, use_pallas=True)
-    assert np.array_equal(np.asarray(ref), np.asarray(fused))
+    ref = np.asarray(dg.dequant_matmul_reference(x, w_q, scale))
+    fused = np.asarray(dg.dequant_matmul(x, w_q, scale, use_pallas=True))
+    if m > 1:
+        assert np.array_equal(ref, fused)
+        return
+    w_deq = np.asarray(w_q, np.float32) * np.asarray(scale)[None, :]
+    tol = (_ONE_ROW_ULPS * np.finfo(np.float32).eps
+           * (np.abs(np.asarray(x)) @ np.abs(w_deq)))
+    assert np.all(np.abs(fused - ref) <= tol)
+    unquantised = np.asarray(x) @ np.asarray(w)
+    assert np.median(np.abs(unquantised - ref) / tol) > 100.0
 
 
 def test_dequant_matmul_default_is_reference(monkeypatch):

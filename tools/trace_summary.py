@@ -5,14 +5,15 @@ the engine writes on an unhandled exception) into a human-readable
 report: per-request latency breakdown (queue wait, prefill time,
 decode dispatches, preemptions, end-to-end), the shed/quarantine
 tally, the degradation-ladder timeline, recorded incidents, and the
-headline metric quantiles. The consumer of a dead bench round's
+headline metric quantiles. The consumer of a dead run's
 post-mortem, runnable anywhere (stdlib only — no jax import)::
 
     python tools/trace_summary.py run_dump.json
 
-Wired into ``bench.py --smoke`` (the ``bench_obs_pipeline`` section)
-so the dump -> summarize pipeline is certified end to end on every
-smoke run, not first exercised at the incident.
+``tests/test_observability.py::
+test_trace_summary_reports_lifecycle_and_tallies`` drives the dump ->
+summarize pipeline end to end in tier 1, so it is not first exercised
+at the incident.
 """
 
 from __future__ import annotations
@@ -59,7 +60,7 @@ def _request_rows(timelines: Dict[str, List[Dict]]) -> List[Dict]:
 
 def summarize(dump: Dict) -> str:
     """The report, as one printable string (also the programmatic
-    surface bench's smoke section asserts on)."""
+    surface the tier-1 tests assert on)."""
     lines: List[str] = ["== apex_tpu observability dump summary =="]
     if dump.get("error"):
         lines.append(f"CRASH DUMP: {dump['error']}")
