@@ -44,6 +44,7 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu import profiler
 from apex_tpu.amp.frontend import _default_norm_filter
@@ -51,6 +52,7 @@ from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.ops.flash_attention import flash_attention, mha_reference
 from apex_tpu.ops.ssd_scan import ssd_scan
 from apex_tpu.transformer.moe import DroplessMoE, squared_relu
+from apex_tpu.transformer.remat import remat_routing_block
 
 _INIT = nn.initializers.normal(stddev=0.02)
 _FP32_LEAVES = ("router", "A_log", "D", "dt_bias")
@@ -227,6 +229,11 @@ class ExpertMixer(nn.Module):
         cfg = self.cfg
         held = (cfg.n_routed_experts if cfg.experts_held is None
                 else cfg.experts_held)
+        # the normed tokens: kept by a rematerialised block, so that the row
+        # gather and the shared expert's up projection (done again in the
+        # backward pass: keeping ITS output cost the gradients' accuracy,
+        # PERF.md section 6, PR 33) read what the forward pass read
+        x = checkpoint_name(x, profiler.MOE_INPUT)
         routed, counters = DroplessMoE(
             hidden_size=cfg.hidden_size,
             ffn_hidden_size=cfg.moe_intermediate_size,
@@ -280,9 +287,16 @@ class NemotronHModel(nn.Module):
         table = self.param("embedding", _INIT,
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         x = table[input_ids].astype(cfg.dtype)
-        block_cls = nn.remat(NemotronHBlock) if cfg.remat else NemotronHBlock
+        # an expert block keeps its routing and the rows it ordered
+        # (transformer/remat.py); Mamba and attention blocks recompute
+        # everything
+        plain_cls = expert_cls = NemotronHBlock
+        if cfg.remat:
+            plain_cls = nn.remat(NemotronHBlock)
+            expert_cls = remat_routing_block(NemotronHBlock)
         total = _zero_counters()
         for i, kind in enumerate(cfg.pattern):
+            block_cls = expert_cls if kind == "E" else plain_cls
             x, counters = block_cls(cfg, kind, name=f"layers_{i}")(x)
             if counters is not None:
                 for name in (profiler.MOE_ASSIGNMENTS_HELD,

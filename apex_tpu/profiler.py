@@ -76,6 +76,13 @@ The flash-attention forward rules tag the kernel's outputs with
 ``flash_out``, ``flash_lse``) so that a rematerialised block can keep them
 by name (``apex_tpu/transformer/remat.py``); outside a ``remat`` with a
 policy that reads names the tag is the identity and compiles to nothing.
+The dropless expert layer tags what ITS backward pass needs beside the
+block's input (:data:`MOE_RESIDUALS`): the routing - ``moe_chosen``,
+``moe_perm`` with ``moe_weights`` in its order, ``moe_inv_perm``,
+``moe_group_sizes`` - and the rows the routing ordered, ``moe_hidden`` (the
+up projection's output); the expert mixer's ``moe_input`` (the normed
+tokens) goes with them. Rows are kept only together with the routing that
+ordered them.
 
 A model may report step counters beside its loss
 (``build_train_step(has_aux=True)``; they arrive with the loss in
@@ -179,6 +186,22 @@ LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
 FLASH_RESIDUALS = (FLASH_OUT, FLASH_LSE)
+# the dropless expert layer (``transformer/moe.py: DroplessMoE``) names its
+# routing (the choice of k, the sort by expert with the slots' weights in
+# its order, the sort's inverse, the held groups' sizes) and the up
+# projection's output; ``models/nemotron_h.py: ExpertMixer`` names its
+# input (the block's normed tokens). A recomputed routing can differ from
+# the forward pass's by a rounding of its input, so rows are kept only
+# WITH the routing that ordered them
+MOE_INPUT = "moe_input"
+MOE_CHOSEN = "moe_chosen"
+MOE_WEIGHTS = "moe_weights"
+MOE_PERM = "moe_perm"
+MOE_INV_PERM = "moe_inv_perm"
+MOE_GROUP_SIZES = "moe_group_sizes"
+MOE_HIDDEN = "moe_hidden"
+MOE_RESIDUALS = (MOE_INPUT, MOE_CHOSEN, MOE_WEIGHTS, MOE_PERM, MOE_INV_PERM,
+                 MOE_GROUP_SIZES, MOE_HIDDEN)
 
 # -- host annotations ----------------------------------------------------------
 TRAIN_DISPATCH = "train_dispatch"

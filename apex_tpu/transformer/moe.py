@@ -45,6 +45,7 @@ from typing import Callable, NamedTuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu import profiler
 from apex_tpu.transformer import parallel_state
@@ -342,6 +343,19 @@ _permute_rows.defvjp(
     lambda res, g: (g[res[1]], None, None))
 
 
+@jax.custom_vjp
+def _permute_scalars(x, perm, inv_perm):
+    """``x[perm]`` for a permutation of scalars, as a sort by ``inv_perm``
+    (and transposed by a sort by ``perm``): on a v5e a sort of the 98,304
+    slots takes a tenth of the time a gather of as many scalars does."""
+    return jax.lax.sort((inv_perm, x), num_keys=1)[1]
+
+
+_permute_scalars.defvjp(
+    lambda x, perm, inv_perm: (_permute_scalars(x, perm, inv_perm), perm),
+    lambda perm, g: (jax.lax.sort((perm, g), num_keys=1)[1], None, None))
+
+
 # rows, contraction and output tile of the TPU grouped-matmul kernel
 _GMM_TILING = (512, 1024, 1024)
 
@@ -393,7 +407,14 @@ class DroplessMoE(nn.Module):
     Every assignment that lands on a held expert is computed: the sorted
     buffer has one row for each of the ``T * top_k`` slots (the most that
     can land here), and the grouped matmuls run over the rows the held
-    groups fill. Returns ``(y, counters)``; the counters are
+    groups fill. A row is weighed before its down projection (``W_down_i
+    (w_i act(W_up_i h))``, the weight in float32 at the hidden width), so
+    the combine is a gather and a sum and the projection's output is no
+    residual. What the backward pass needs beside the input carries a
+    ``checkpoint_name`` (:data:`apex_tpu.profiler.MOE_RESIDUALS`: the
+    routing and the up projection's output), for a rematerialised block
+    to keep (:func:`apex_tpu.transformer.remat.remat_routing_block`).
+    Returns ``(y, counters)``; the counters are
     ``assignments_held`` (token-expert pairs computed here),
     ``load_max_over_mean`` (the fullest held expert over the mean) and
     ``tokens_dropped`` (held assignments left out of the buffer: 0).
@@ -430,6 +451,7 @@ class DroplessMoE(nn.Module):
         T = tokens.shape[0]
         slots = T * k
 
+        # the ``checkpoint_name``s below are ``profiler.MOE_RESIDUALS``
         with jax.named_scope(profiler.MOE_ROUTER):
             scores = jax.nn.sigmoid(jnp.dot(
                 tokens.astype(jnp.float32), router.astype(jnp.float32),
@@ -437,6 +459,7 @@ class DroplessMoE(nn.Module):
             chosen_by = scores if selection_bias is None else (
                 scores + selection_bias.astype(jnp.float32))
             _, chosen = jax.lax.top_k(chosen_by, k)               # (T, k)
+            chosen = checkpoint_name(chosen, profiler.MOE_CHOSEN)
             weight = jnp.take_along_axis(scores, chosen, axis=-1)
             if self.norm_topk_prob:
                 weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
@@ -447,27 +470,39 @@ class DroplessMoE(nn.Module):
             here = (local >= 0) & (local < held)
             # held assignments first, grouped by expert; the rest behind
             key = jnp.where(here, local, held).reshape(slots)
-            perm = jnp.argsort(key, stable=True).astype(jnp.int32)
-            inv_perm = jnp.zeros((slots,), jnp.int32).at[perm].set(
-                jnp.arange(slots, dtype=jnp.int32), unique_indices=True)
-            group_sizes = jnp.sum(
+            perm = checkpoint_name(
+                jnp.argsort(key, stable=True).astype(jnp.int32),
+                profiler.MOE_PERM)
+            inv_perm = checkpoint_name(
+                jnp.argsort(perm).astype(jnp.int32), profiler.MOE_INV_PERM)
+            # a slot's weight in the sorted order; zero off the held experts
+            w_row = checkpoint_name(_permute_scalars(
+                jnp.where(here, weight, 0.0).reshape(slots), perm, inv_perm),
+                profiler.MOE_WEIGHTS)
+            group_sizes = checkpoint_name(jnp.sum(
                 key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0,
-                dtype=jnp.int32)
+                dtype=jnp.int32), profiler.MOE_GROUP_SIZES)
             n_here = jnp.sum(group_sizes)
             rows = _rows_of_slots(tokens, perm, inv_perm, k)
 
         with jax.named_scope(profiler.MOE_EXPERTS):
-            h = grouped_matmul(rows, w_up.astype(self.dtype), group_sizes)
-            h = self.activation(h)
-            out = grouped_matmul(h, w_down.astype(self.dtype), group_sizes)
+            h = checkpoint_name(
+                grouped_matmul(rows, w_up.astype(self.dtype), group_sizes),
+                profiler.MOE_HIDDEN)
+            # a row is weighed BEFORE its down projection, in float32 at
+            # the hidden width, and rounded once where the grouped
+            # matmul's operand was rounded already: sum_i w_i W_i a_i =
+            # sum_i W_i (w_i a_i), so the projection's output is no
+            # residual of the combine
+            a = (self.activation(h.astype(jnp.float32)) * w_row[:, None]
+                 ).astype(self.dtype)
+            out = grouped_matmul(a, w_down.astype(self.dtype), group_sizes)
 
         with jax.named_scope(profiler.MOE_COMBINE):
             # rows no held group fills are zero (`grouped_matmul`), in
             # the backward pass too, and their weight is zero besides
             by_slot = _permute_rows(out, inv_perm, perm).reshape(T, k, H)
-            w_here = jnp.where(here, weight, 0.0)
-            y = jnp.sum(by_slot.astype(jnp.float32) * w_here[..., None],
-                        axis=1)
+            y = jnp.sum(by_slot.astype(jnp.float32), axis=1)
 
         loads = group_sizes.astype(jnp.float32)
         counters = {

@@ -16,10 +16,17 @@ GiB less live memory at 8,192 tokens a chip over 24 layers of width 1024
 on a v5e (``PERF.md`` section 6, PR 31). Megatron-LM's "selective
 activation recomputation" is the same split.
 
-A block that ROUTES (top-k experts) must be wrapped ``"full"``: rows kept
-across a recomputed routing can meet a recomputed order that differs by a
-bfloat16 rounding (``PERF.md`` section 6, PR 30). The caller decides that
-from the block's type.
+A block that ROUTES (top-k experts) may keep rows only together with the
+routing that ordered them: rows kept across a recomputed routing can meet
+a recomputed order that differs by a bfloat16 rounding (``PERF.md``
+section 6, PR 30). The caller decides from the block's type: ``"full"``
+where the expert layer names nothing (``GPTBlock(use_moe=True)``), and
+:func:`remat_routing_block` where it names its routing and its rows
+(:class:`apex_tpu.transformer.moe.DroplessMoE`,
+:data:`apex_tpu.profiler.MOE_RESIDUALS`): the backward pass then does the
+router's scores, the row gather, the shared expert's up projection and the
+elementwise ops again, and never a sort or a grouped matmul (``PERF.md``
+section 6, PR 33).
 """
 
 from __future__ import annotations
@@ -48,3 +55,13 @@ def remat_block(block_cls, static_argnums, policy):
     """``block_cls`` wrapped in ``nn.remat`` under the named policy."""
     return nn.remat(block_cls, static_argnums=static_argnums,
                     policy=_policy(policy))
+
+
+def remat_routing_block(block_cls):
+    """``block_cls``, a block whose expert layer names its routing and the
+    rows it ordered, wrapped in ``nn.remat`` so that exactly those names
+    are kept and everything else is recomputed. No config names this
+    policy: the caller picks it from the block's type."""
+    return nn.remat(block_cls,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *profiler.MOE_RESIDUALS))

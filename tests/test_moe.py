@@ -524,3 +524,114 @@ def test_dropless_work_follows_assignments_not_experts():
     assert largest <= 64 * _K * max(_H, _F)
     with pytest.raises(ValueError, match="are not among"):
         _dropless(4, 14).init(jax.random.PRNGKey(0), x)
+
+
+# -- rematerialised: rows are kept only with the routing that ordered them ---
+
+import flax.linen as nn  # noqa: E402
+
+from apex_tpu import profiler  # noqa: E402
+from apex_tpu.transformer.remat import remat_routing_block  # noqa: E402
+
+_HELD, _FIRST = 8, 4
+
+
+class _ExpertBlock(nn.Module):
+    """The expert block in miniature: what precedes the layer (in a model
+    the norm, here a shift the test owns) is recomputed with it."""
+    shift: object
+
+    @nn.compact
+    def __call__(self, x):
+        return _dropless(_HELD, _FIRST, name="experts")(x + self.shift(x))[0]
+
+
+class _ShiftOnRecomputation:
+    """Zero when first evaluated (the forward pass), ``delta`` every time
+    after (the backward pass's recomputation): what XLA does to a
+    recomputed norm by fusing it otherwise, planted from the host. For
+    op-by-op evaluation only: the forward pass's call comes first there,
+    under ``jit`` XLA may order the two either way."""
+
+    def __init__(self, delta):
+        self.delta, self.calls = np.asarray(delta, np.float32), 0
+
+    def _host(self, _):
+        self.calls += 1
+        return self.delta if self.calls > 1 else np.zeros_like(self.delta)
+
+    def __call__(self, x):
+        return jax.pure_callback(
+            self._host, jax.ShapeDtypeStruct(self.delta.shape, jnp.float32),
+            jax.lax.stop_gradient(x.reshape(-1)[0]))
+
+
+def _near_tie(router, x, tiny=1e-4):
+    """(x with one token's k-th and (k+1)-th router scores ``tiny`` apart in
+    logit, the k-th on a held expert; a shift far under one bfloat16 ulp of
+    that token which swaps the two)."""
+    t = np.asarray(x, np.float64).reshape(-1, _H)
+    r = np.asarray(router, np.float64)
+
+    def choice(tokens):
+        return np.argsort(-(tokens @ r), axis=-1, kind="stable")[:, :_K]
+
+    for t0 in range(t.shape[0]):
+        order = np.argsort(-(t[t0] @ r), kind="stable")
+        a, b = order[_K - 1], order[_K]
+        if not _FIRST <= a < _FIRST + _HELD:
+            continue
+        d = r[:, b] - r[:, a]
+        gap = t[t0] @ (r[:, a] - r[:, b])
+        tied, delta = t.copy(), np.zeros_like(t)
+        tied[t0] += d * (gap - tiny) / (d @ d)
+        delta[t0] = d * 2 * tiny / (d @ d)
+        before, after = choice(tied), choice(tied + delta)
+        same = np.delete(np.arange(t.shape[0]), t0)
+        if (np.array_equal(before[same], after[same])
+                and set(before[t0]) - set(after[t0]) == {a}
+                and set(after[t0]) - set(before[t0]) == {b}):
+            assert np.max(np.abs(delta[t0])) < 2.0 ** -8 * np.max(
+                np.abs(tied[t0]))
+            return (jnp.asarray(tied.reshape(x.shape), jnp.float32),
+                    delta.reshape(x.shape))
+    raise AssertionError("no token's k-th choice is a held expert")
+
+
+@pytest.mark.parametrize("kept", ["routing_and_rows", "rows_only"])
+def test_rows_are_kept_only_with_the_routing_that_ordered_them(
+        dropless_params, kept):
+    """A recomputed routing can differ from the forward pass's: one token's
+    k-th and (k+1)-th scores are a near-tie and the recomputation sees an
+    input shifted by less than a bfloat16 rounding, which swaps them. With
+    the routing kept beside the hidden rows (``remat_routing_block``) the
+    gradients are the un-rematerialised block's; with the rows alone kept
+    (PR 30's scratch build) they are not."""
+    p, x = dropless_params
+    mine = {"experts": {"router": p["router"],
+                        "w_up": p["w_up"][_FIRST:_FIRST + _HELD],
+                        "w_down": p["w_down"][_FIRST:_FIRST + _HELD]}}
+    x, delta = _near_tie(p["router"], x)
+
+    def grads(block_cls, shift):
+        block = block_cls(shift)
+        with jax.default_matmul_precision("highest"):
+            return jax.tree.leaves(jax.grad(lambda p, x: jnp.sum(jnp.sin(
+                block.apply({"params": p}, x))), argnums=(0, 1))(mine, x))
+
+    want = grads(_ExpertBlock, lambda x: 0.0)
+    if kept == "routing_and_rows":
+        block_cls = remat_routing_block(_ExpertBlock)
+    else:
+        block_cls = nn.remat(
+            _ExpertBlock, policy=jax.checkpoint_policies
+            .save_only_these_names(profiler.MOE_HIDDEN))
+    shift = _ShiftOnRecomputation(delta)
+    got = grads(block_cls, shift)
+    assert shift.calls == 2            # the forward pass and its recomputation
+    worst = max(float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                for a, b in zip(got, want))
+    if kept == "routing_and_rows":
+        assert worst < 1e-4
+    else:
+        assert worst > 1e-2

@@ -73,15 +73,18 @@ def _ops(loss, params):
     into the name stack of what a checkpoint's backward does again."""
     counts = {}
 
-    def walk(jaxpr, recomputed):
+    def walk(jaxpr, recomputed, layer=None):
+        outer = layer
         for eqn in jaxpr.eqns:
             stack = str(eqn.source_info.name_stack)
             again = recomputed or "rematted_computation" in stack
             name = eqn.primitive.name
             if name == "pallas_call":      # a kernel counts as one op
                 name = eqn.params["name"]
+            # an inner jaxpr's name stacks start at its call site
             layer = next((part for part in stack.split("/")
-                          if part.startswith(("layer_", "h_"))), None)
+                          if part.startswith(("layer_", "layers_", "h_"))),
+                         outer)
             for key in ((name, again), (name, again, layer)):
                 counts[key] = counts.get(key, 0) + 1
             if eqn.primitive.name == "pallas_call":
@@ -91,7 +94,7 @@ def _ops(loss, params):
                             else [value]):
                     sub = getattr(sub, "jaxpr", sub)
                     if hasattr(sub, "eqns"):
-                        walk(sub, again)
+                        walk(sub, again, layer)
 
     walk(jax.make_jaxpr(jax.grad(loss))(params).jaxpr, False)
     return counts
@@ -188,6 +191,46 @@ def test_expert_block_recomputes_everything_under_either_policy(policy):
         1 if policy == "full" else 0)
 
 
+# -- a block whose expert layer names its routing keeps it, with its rows ------
+
+def test_nemotron_expert_block_keeps_its_routing_and_rows():
+    """``models/nemotron_h.py`` wraps an ``E`` block so that the names of
+    ``profiler.MOE_RESIDUALS`` are kept: the backward pass runs no grouped
+    matmul, no ``top_k``, no sort and no scatter again, and of the block's
+    matmuls the router's scores and the shared expert's up projection;
+    ``M`` and ``*`` blocks recompute everything, as before."""
+    from apex_tpu.models.nemotron_h import (NemotronHConfig,
+                                            NemotronHLMHeadModel)
+
+    def ops(**kw):
+        cfg = NemotronHConfig.tiny(pattern="ME*", fused_kernels=False, **kw)
+        model = NemotronHLMHeadModel(cfg)
+        ids = jnp.asarray(np.random.RandomState(0).randint(
+            0, cfg.vocab_size, (2, 32)), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), ids)["params"]
+        return _ops(lambda p: model.apply({"params": p}, ids,
+                                          method="loss")[0], params)
+
+    kept, none = ops(), ops(remat=False)
+    # off the TPU a grouped matmul is ``ragged_dot``: up and down forward,
+    # two each backward, in both
+    for counts in (kept, none):
+        assert counts["ragged_dot_general", False, "layers_1"] == 6
+    for name in ("ragged_dot_general", "gmm", "tgmm", "top_k", "sort",
+                 "scatter"):
+        assert (name, True, "layers_1") not in kept, name
+    assert kept["top_k", False, "layers_1"] == 1
+    # the sort by expert, its inverse, the weights into that order and back
+    assert kept["sort", False, "layers_1"] == 4
+    # the router's scores and the shared expert's up projection
+    assert kept["dot_general", True, "layers_1"] == 2
+    # the Mamba and the attention block do their matmuls again
+    assert kept["dot_general", True, "layers_0"] == 6
+    assert kept["dot_general", True, "layers_2"] == 5
+    # (the loss recomputes a row's head matmul either way, in no layer)
+    assert not [k for k in none if k[1] and k[2:] not in ((), (None,))]
+
+
 # -- names ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", ["dots", "none", "Selective", ""])
@@ -200,9 +243,11 @@ def test_unknown_policy_raises(model, name):
 
 
 def test_residual_names_are_one_vocabulary():
-    names = profiler.FLASH_RESIDUALS
+    names = profiler.FLASH_RESIDUALS + profiler.MOE_RESIDUALS
     assert len(set(names)) == len(names)
-    assert not set(names) & set(profiler.SCOPES + profiler.KERNEL_NAMES)
+    assert not set(names) & set(profiler.SCOPES + profiler.LAYER_SCOPES
+                                + profiler.KERNEL_NAMES
+                                + profiler.STEP_COUNTERS)
     for name in names:
         assert name in profiler.__doc__
 

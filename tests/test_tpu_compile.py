@@ -394,3 +394,73 @@ def test_bert_large_ddp_step_compiles_for_four_v5e(mesh4, compiled_kernels):
           f"tpu_custom_call x{_n_kernels(full_text)}")
     assert _n_kernels(full_text) - _n_kernels(text) == 24
     assert collective_stats(full_text)["all-reduce"] == stats["all-reduce"]
+
+
+# -- the fourth cell's step: what an expert block does twice ----------------
+
+# what the ``nemotron_h`` step may hold live at 2 x 8,192 tokens a chip with
+# the expert blocks' routing and hidden rows kept (12.16 GiB with every
+# block recomputed in full, PERF.md section 6, PR 33)
+NEMOTRON_LIVE_BYTES = int(12.5 * 2 ** 30)
+
+
+def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
+        one_chip, compiled_kernels):
+    """The whole train step of ``nemotron_twotower_30b_a3b.lm8192`` (seven
+    blocks ``MEMEM*E`` at the published widths, amp O2 + FusedAdam
+    through ``build_train_step``, as the cell builds it) compiled from
+    shapes for one described chip. An expert block keeps its routing and
+    its hidden rows under recomputation: six grouped-matmul calls a layer
+    (``gmm`` up and down forward; two ``gmm`` and two ``tgmm`` backward),
+    none of them and no sort (``top_k``, the sort by expert and the ones
+    that follow it) on a recomputed path, inside the memory the kept rows
+    were promised."""
+    import re
+
+    import chip_smoke
+    from benchmark.harness import runner
+    from benchmark.harness.manifest import Manifest
+
+    manifest = Manifest()
+    cell = "nemotron_twotower_30b_a3b.lm8192"
+    config = manifest.config(manifest.cell(cell)["config"])
+    traffic = manifest.traffic(cell)
+    builder, reference = runner.family(config)
+    built = builder.build(config, traffic, reference, seed=0,
+                          key=runner.weights_key(0), abstract_on=one_chip)
+    ids = np.zeros((traffic["rows_per_chip"], traffic["seq"]), np.int32)
+    batch = jax.tree.map(
+        lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip),
+        built.program_batch({"ids": ids, "seed": [1]}))
+    compiled = built.step.lower(built.state, batch).compile()
+    text = compiled.as_text()
+
+    def paths(pattern):
+        """``op_name`` of every instruction whose line matches."""
+        return [m.group(1) for line in text.splitlines()
+                if re.search(pattern, line)
+                for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+    kernels = paths(r'custom_call_target="tpu_custom_call"')
+    layers = [i for i, kind in enumerate(builder.model_config(config).pattern)
+              if kind == "E"]
+    assert len(layers) == 3
+    for i in layers:
+        mine = [p for p in kernels if f"/layers_{i}/" in p]
+        assert len(mine) == 6, mine
+        assert sum("jit(tgmm)" in p for p in mine) == 2
+        assert sum("jit(gmm)" in p for p in mine) == 4
+        assert all("/moe_experts/" in p for p in mine)
+    # the attention block's three flash calls and its recomputed forward
+    assert len(kernels) == 6 * len(layers) + 4
+    again = [p for p in kernels if "rematted_computation" in p]
+    assert len(again) == 1 and "flash_fwd" in again[0], again
+    sorts = paths(r" sort\(")
+    assert not [p for p in sorts if "rematted_computation" in p], sorts
+    sorts = [p for p in sorts if "/experts/" in p]
+    # top_k; the sort by expert, its inverse, the weights there and back
+    assert len(sorts) == 5 * len(layers), sorts
+    live = chip_smoke.live_bytes(compiled.memory_analysis())
+    print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
+          f"{live / 2 ** 30:.2f} GiB")
+    assert live <= NEMOTRON_LIVE_BYTES < _hbm_bytes()
