@@ -51,7 +51,8 @@ from apex_tpu.amp.frontend import _default_norm_filter
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.ops.flash_attention import flash_attention, mha_reference
 from apex_tpu.ops.ssd_scan import ssd_scan
-from apex_tpu.transformer.moe import DroplessMoE, squared_relu
+from apex_tpu.transformer.moe import (DroplessMoE, add_step_counters,
+                                      squared_relu, zero_step_counters)
 from apex_tpu.transformer.remat import remat_routing_block
 
 _INIT = nn.initializers.normal(stddev=0.02)
@@ -269,10 +270,6 @@ class NemotronHBlock(nn.Module):
         return x + y.astype(x.dtype), counters
 
 
-def _zero_counters():
-    return {name: jnp.float32(0.0) for name in profiler.STEP_COUNTERS}
-
-
 class NemotronHModel(nn.Module):
     """Embedding, the blocks of ``cfg.pattern``, final RMSNorm. Returns
     ``(hidden, counters)``; the counters sum the expert layers'
@@ -294,16 +291,12 @@ class NemotronHModel(nn.Module):
         if cfg.remat:
             plain_cls = nn.remat(NemotronHBlock)
             expert_cls = remat_routing_block(NemotronHBlock)
-        total = _zero_counters()
+        total = zero_step_counters()
         for i, kind in enumerate(cfg.pattern):
             block_cls = expert_cls if kind == "E" else plain_cls
             x, counters = block_cls(cfg, kind, name=f"layers_{i}")(x)
             if counters is not None:
-                for name in (profiler.MOE_ASSIGNMENTS_HELD,
-                             profiler.MOE_TOKENS_DROPPED):
-                    total[name] = total[name] + counters[name]
-                name = profiler.MOE_LOAD_MAX_OVER_MEAN
-                total[name] = jnp.maximum(total[name], counters[name])
+                total = add_step_counters(total, counters)
         return _block_norm(cfg, "norm_f")(x), total
 
 

@@ -53,20 +53,32 @@ moe_dispatch       sort by expert, gather the held rows      transformer/moe.py
 moe_experts        the held experts' grouped matmuls         transformer/moe.py
 moe_shared         the shared expert (every token)           models/nemotron_h.py
 moe_combine        weighted rows back to token order         transformer/moe.py
-gqa_attention      q/k/v projections, grouped-query flash,   models/nemotron_h.py
-                   output projection
+gqa_attention      q/k/v projections, grouped-query flash,   models/nemotron_h.py,
+                   output projection                         models/lfm2.py
+attn_qk_norm       per-head RMSNorm of q and of k            models/lfm2.py
+attn_rope          rotary positions on q and k               models/lfm2.py
+conv_in_proj       short convolution: input projection       models/lfm2.py
+                   ``[B | C | x]``
+conv_gate          the two gates and the causal taps:        ops/short_conv.py
+                   ``C * conv(B * x)``
+conv_out_proj      short convolution: output projection      models/lfm2.py
+mlp_dense          the dense gated MLP                       models/lfm2.py
 ================== ======================================== ==========================
 
-The model scopes of the last ten rows sit INSIDE ``train_fwd_bwd`` (a
-phase reader files their ops by that ancestor).
+The model scopes from ``ssm_in_proj`` down sit INSIDE ``train_fwd_bwd`` (a
+phase reader files their ops by that ancestor); ``attn_qk_norm`` and
+``attn_rope`` sit inside ``gqa_attention`` besides. ``models/lfm2.py``
+reuses ``lm_head`` / ``lm_loss`` and the ``moe_*`` scopes of the expert
+layer it shares with ``models/nemotron_h.py``.
 
 Pallas kernels carry a stable ``name=`` that says kernel and direction,
 never the caller (:data:`KERNEL_NAMES`); the name becomes the HLO
 instruction's name and so the device event's: ``flash_fwd``,
 ``flash_bwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``layer_norm_fwd``,
 ``layer_norm_bwd``, ``softmax_fwd``, ``softmax_bwd``, ``dropout_apply``,
-``dropout_mask``. The dropless expert layer runs the grouped-matmul
-kernels that ship with JAX (``jax.experimental.pallas.ops.tpu.megablox``),
+``dropout_mask``, ``short_conv_fwd``, ``short_conv_bwd``. The dropless
+expert layer runs the grouped-matmul kernels that ship with JAX
+(``jax.experimental.pallas.ops.tpu.megablox``),
 which name themselves: ``gmm`` (forward and the rows' gradient) and
 ``tgmm`` (the weights' gradient) (:data:`LIBRARY_KERNEL_NAMES`); they sit
 under the ``moe_experts`` scope, which is what a reader should match.
@@ -149,6 +161,12 @@ MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"
 MOE_COMBINE = "moe_combine"
 GQA_ATTENTION = "gqa_attention"
+ATTN_QK_NORM = "attn_qk_norm"
+ATTN_ROPE = "attn_rope"
+CONV_IN_PROJ = "conv_in_proj"
+CONV_GATE = "conv_gate"
+CONV_OUT_PROJ = "conv_out_proj"
+MLP_DENSE = "mlp_dense"
 
 STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
                AMP_SCALE_LOSS, AMP_UNSCALE, AMP_FOUND_INF, AMP_UPDATE_SCALE,
@@ -161,7 +179,8 @@ MODEL_SCOPES = (LM_HEAD, LM_LOSS, MLM_HEAD, NSP_HEAD, PRETRAINING_LOSS)
 # their own and stay out of ``SCOPES``
 LAYER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_OUT, MOE_ROUTER,
                 MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE,
-                GQA_ATTENTION)
+                GQA_ATTENTION, ATTN_QK_NORM, ATTN_ROPE, CONV_IN_PROJ,
+                CONV_GATE, CONV_OUT_PROJ, MLP_DENSE)
 SCOPES = STEP_SCOPES + OPTIMIZER_SCOPES + DDP_SCOPES + MODEL_SCOPES
 
 # -- step metrics a model reports beside its loss (``has_aux``) ----------------
@@ -174,7 +193,8 @@ STEP_COUNTERS = (MOE_ASSIGNMENTS_HELD, MOE_LOAD_MAX_OVER_MEAN,
 # -- Pallas kernel names (``pl.pallas_call(name=...)``) ------------------------
 KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "layer_norm_fwd", "layer_norm_bwd", "softmax_fwd",
-                "softmax_bwd", "dropout_apply", "dropout_mask")
+                "softmax_bwd", "dropout_apply", "dropout_mask",
+                "short_conv_fwd", "short_conv_bwd")
 
 # kernels of a library the train path calls (named by the library)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")

@@ -391,29 +391,58 @@ def grouped_matmul(rows, weights, group_sizes):
         default=jax.lax.ragged_dot)
 
 
+def zero_step_counters():
+    """The step counters of :data:`apex_tpu.profiler.STEP_COUNTERS` before
+    any expert layer has reported."""
+    return {name: jnp.float32(0.0) for name in profiler.STEP_COUNTERS}
+
+
+def add_step_counters(total, counters):
+    """``total`` with one expert layer's ``counters`` folded in: the
+    assignments held and the tokens dropped add up, the fullest expert's
+    load over the mean is the worst layer's."""
+    total = dict(total)
+    for name in (profiler.MOE_ASSIGNMENTS_HELD, profiler.MOE_TOKENS_DROPPED):
+        total[name] = total[name] + counters[name]
+    name = profiler.MOE_LOAD_MAX_OVER_MEAN
+    total[name] = jnp.maximum(total[name], counters[name])
+    return total
+
+
 class DroplessMoE(nn.Module):
     """One rank's share of a dropless mixture-of-experts layer.
 
     The router scores every token over all ``num_experts`` experts in
     float32 (``sigmoid``), picks the ``top_k`` largest of ``score +
     selection_bias`` and weighs them by their scores, normalised over the
-    chosen ``top_k`` (``norm_topk_prob``) and times
-    ``routed_scaling_factor``. This rank holds experts ``expert_offset ..
-    expert_offset + experts_held - 1`` and returns the part of the sum
-    that THEY give: ``sum_{i chosen, held} w_i W_down_i act(W_up_i h)``
-    (no gate, no bias). What absent experts would add is another rank's
-    part; the exchange that sums the parts goes around this layer.
+    chosen ``top_k`` (``norm_topk_prob``: ``s_i / (sum + norm_topk_eps)``)
+    and times ``routed_scaling_factor``. This rank holds experts
+    ``expert_offset .. expert_offset + experts_held - 1`` and returns the
+    part of the sum that THEY give, ``sum_{i chosen, held} w_i expert_i(h)``,
+    with no bias anywhere. An expert has one of two forms, which the model
+    sets from its family (``gated``):
+
+    * plain: ``W_down_i act(W_up_i h)``, parameters ``w_up (held, H, F)``
+      and ``w_down (held, F, H)``;
+    * gated: ``W_down_i (act(W_gate_i h) * W_up_i h)``, parameters
+      ``w_gate_up (held, H, 2F)`` - the gate's columns, then the up
+      projection's, so that both are ONE grouped matmul - and ``w_down``.
+
+    What absent experts would add is another rank's part; the exchange
+    that sums the parts goes around this layer.
 
     Every assignment that lands on a held expert is computed: the sorted
     buffer has one row for each of the ``T * top_k`` slots (the most that
     can land here), and the grouped matmuls run over the rows the held
     groups fill. A row is weighed before its down projection (``W_down_i
-    (w_i act(W_up_i h))``, the weight in float32 at the hidden width), so
-    the combine is a gather and a sum and the projection's output is no
-    residual. What the backward pass needs beside the input carries a
-    ``checkpoint_name`` (:data:`apex_tpu.profiler.MOE_RESIDUALS`: the
-    routing and the up projection's output), for a rematerialised block
-    to keep (:func:`apex_tpu.transformer.remat.remat_routing_block`).
+    (w_i a_i)``, the weight applied in float32 at the hidden width, with
+    the gate where there is one), so the combine is a gather and a sum and
+    the projection's output is no residual. What the backward pass needs
+    beside the input carries a ``checkpoint_name``
+    (:data:`apex_tpu.profiler.MOE_RESIDUALS`: the routing and the first
+    grouped matmul's output, ``(slots, F)`` or ``(slots, 2F)``), for a
+    rematerialised block to keep
+    (:func:`apex_tpu.transformer.remat.remat_routing_block`).
     Returns ``(y, counters)``; the counters are
     ``assignments_held`` (token-expert pairs computed here),
     ``load_max_over_mean`` (the fullest held expert over the mean) and
@@ -431,6 +460,8 @@ class DroplessMoE(nn.Module):
     activation: Callable = squared_relu
     dtype: jnp.dtype = jnp.bfloat16
     params_dtype: jnp.dtype = jnp.float32
+    gated: bool = False
+    norm_topk_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x, selection_bias=None):
@@ -443,7 +474,11 @@ class DroplessMoE(nn.Module):
             raise ValueError(f"top_k ({k}) exceeds num_experts ({E})")
         init = nn.initializers.normal(stddev=0.02)
         router = self.param("router", init, (H, E), self.params_dtype)
-        w_up = self.param("w_up", init, (held, H, F), self.params_dtype)
+        if self.gated:
+            w_in = self.param("w_gate_up", init, (held, H, 2 * F),
+                              self.params_dtype)
+        else:
+            w_in = self.param("w_up", init, (held, H, F), self.params_dtype)
         w_down = self.param("w_down", init, (held, F, H), self.params_dtype)
 
         lead = x.shape[:-1]
@@ -462,7 +497,8 @@ class DroplessMoE(nn.Module):
             chosen = checkpoint_name(chosen, profiler.MOE_CHOSEN)
             weight = jnp.take_along_axis(scores, chosen, axis=-1)
             if self.norm_topk_prob:
-                weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+                weight = weight / (jnp.sum(weight, -1, keepdims=True)
+                                   + self.norm_topk_eps)
             weight = weight * self.routed_scaling_factor
 
         with jax.named_scope(profiler.MOE_DISPATCH):
@@ -487,15 +523,19 @@ class DroplessMoE(nn.Module):
 
         with jax.named_scope(profiler.MOE_EXPERTS):
             h = checkpoint_name(
-                grouped_matmul(rows, w_up.astype(self.dtype), group_sizes),
+                grouped_matmul(rows, w_in.astype(self.dtype), group_sizes),
                 profiler.MOE_HIDDEN)
             # a row is weighed BEFORE its down projection, in float32 at
             # the hidden width, and rounded once where the grouped
             # matmul's operand was rounded already: sum_i w_i W_i a_i =
             # sum_i W_i (w_i a_i), so the projection's output is no
             # residual of the combine
-            a = (self.activation(h.astype(jnp.float32)) * w_row[:, None]
-                 ).astype(self.dtype)
+            if self.gated:
+                a = (self.activation(h[:, :F].astype(jnp.float32))
+                     * h[:, F:].astype(jnp.float32))
+            else:
+                a = self.activation(h.astype(jnp.float32))
+            a = (a * w_row[:, None]).astype(self.dtype)
             out = grouped_matmul(a, w_down.astype(self.dtype), group_sizes)
 
         with jax.named_scope(profiler.MOE_COMBINE):

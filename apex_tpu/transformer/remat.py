@@ -57,11 +57,17 @@ def remat_block(block_cls, static_argnums, policy):
                     policy=_policy(policy))
 
 
-def remat_routing_block(block_cls):
+def remat_routing_block(block_cls, policy="full"):
     """``block_cls``, a block whose expert layer names its routing and the
-    rows it ordered, wrapped in ``nn.remat`` so that exactly those names
-    are kept and everything else is recomputed. No config names this
-    policy: the caller picks it from the block's type."""
-    return nn.remat(block_cls,
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *profiler.MOE_RESIDUALS))
+    rows it ordered, wrapped in ``nn.remat`` so that those names are kept.
+    Under ``"full"`` everything else is recomputed; under ``"selective"``
+    the block's dense matmul outputs and flash attention's residuals are
+    kept as well (a layer that holds a mixer beside its experts:
+    ``models/lfm2.py``). No config names this policy: the caller picks it
+    from the block's type."""
+    names = jax.checkpoint_policies.save_only_these_names(
+        *profiler.MOE_RESIDUALS)
+    dense = _policy(policy)
+    if dense is not None:
+        names = jax.checkpoint_policies.save_from_both_policies(dense, names)
+    return nn.remat(block_cls, policy=names)

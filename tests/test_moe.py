@@ -477,6 +477,78 @@ def test_dropless_gradients_match_the_uncut_layer(dropless_params):
             jnp.max(jnp.abs(b)))
 
 
+# -- the gated form: W_down (act(W_gate h) * W_up h), gate and up side by side --
+
+_GATED = dict(gated=True, activation=jax.nn.silu, norm_topk_eps=1e-6)
+
+
+def _uncut_gated_reference(p, x, k=_K, scale=2.5):
+    """The whole gated layer as a loop over every expert with a 0/1 mask."""
+    t = x.reshape(-1, _H)
+    s = jax.nn.sigmoid(t @ p["router"])
+    _, idx = jax.lax.top_k(s, k)
+    w = jnp.take_along_axis(s, idx, -1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-6) * scale
+    y = 0.0
+    for e in range(p["w_gate_up"].shape[0]):
+        mine = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
+        gate, up = (t @ p["w_gate_up"][e, :, :_F],
+                    t @ p["w_gate_up"][e, :, _F:])
+        y = y + mine[:, None] * ((jax.nn.silu(gate) * up) @ p["w_down"][e])
+    return y.reshape(x.shape)
+
+
+@pytest.fixture(scope="module")
+def gated_params():
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 20, _H))
+    p = _dropless(_E, **_GATED).init(jax.random.PRNGKey(0), x)["params"]
+    assert set(p) == {"router", "w_gate_up", "w_down"}
+    assert p["w_gate_up"].shape == (_E, _H, 2 * _F)
+    return jax.tree.map(lambda a: a * 10.0, p), x
+
+
+def test_gated_experts_match_a_loop_over_experts(gated_params):
+    """Values and gradients of the gated form (one grouped matmul of width
+    2F, the row weighed with the gate) against the plain loop."""
+    p, x = gated_params
+    layer = _dropless(_E, **_GATED)
+    with jax.default_matmul_precision("highest"):
+        y, counters = layer.apply({"params": p}, x)
+        want = _uncut_gated_reference(p, x)
+        got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+            layer.apply({"params": p}, x)[0])), argnums=(0, 1))(p, x)
+        ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+            _uncut_gated_reference(p, x))), argnums=(0, 1))(p, x)
+    assert float(counters["moe_tokens_dropped"]) == 0.0
+    assert float(jnp.max(jnp.abs(y - want))) < 1e-4 * float(
+        jnp.max(jnp.abs(want)))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * float(
+            jnp.max(jnp.abs(b)))
+
+
+def test_the_eight_gated_shares_add_up_to_the_uncut_layer(gated_params):
+    """8 ranks of 2 experts each (offsets 0, 2, .., 14 of 16: the cut of
+    ``lfm2_24b_a2b`` - offsets 0, 8, .., 56 of 64 - at this size) give
+    what the uncut layer gives; there is no shared expert to count once."""
+    p, x = gated_params
+    with jax.default_matmul_precision("highest"):
+        whole = _uncut_gated_reference(p, x)
+        total, pairs = 0.0, 0.0
+        for first in range(0, _E, 2):
+            mine = {"router": p["router"],
+                    "w_gate_up": p["w_gate_up"][first:first + 2],
+                    "w_down": p["w_down"][first:first + 2]}
+            y, counters = _dropless(2, first, **_GATED).apply(
+                {"params": mine}, x)
+            total = total + y
+            pairs += float(counters["moe_assignments_held"])
+            assert float(counters["moe_tokens_dropped"]) == 0.0
+    assert pairs == x.shape[0] * x.shape[1] * _K       # each pair once
+    assert float(jnp.max(jnp.abs(total - whole))) < 1e-5 * float(
+        jnp.max(jnp.abs(whole)))
+
+
 @pytest.mark.parametrize("favourite,held,first,expect", [
     (5, 4, 4, "all"),      # every token chooses the same held expert
     (5, 4, 8, "none"),     # no token chooses a held one
@@ -540,10 +612,13 @@ class _ExpertBlock(nn.Module):
     """The expert block in miniature: what precedes the layer (in a model
     the norm, here a shift the test owns) is recomputed with it."""
     shift: object
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x):
-        return _dropless(_HELD, _FIRST, name="experts")(x + self.shift(x))[0]
+        form = _GATED if self.gated else {}
+        return _dropless(_HELD, _FIRST, name="experts", **form)(
+            x + self.shift(x))[0]
 
 
 class _ShiftOnRecomputation:
@@ -598,23 +673,24 @@ def _near_tie(router, x, tiny=1e-4):
     raise AssertionError("no token's k-th choice is a held expert")
 
 
+@pytest.mark.parametrize("form", ["plain", "gated"])
 @pytest.mark.parametrize("kept", ["routing_and_rows", "rows_only"])
 def test_rows_are_kept_only_with_the_routing_that_ordered_them(
-        dropless_params, kept):
+        dropless_params, gated_params, kept, form):
     """A recomputed routing can differ from the forward pass's: one token's
     k-th and (k+1)-th scores are a near-tie and the recomputation sees an
     input shifted by less than a bfloat16 rounding, which swaps them. With
     the routing kept beside the hidden rows (``remat_routing_block``) the
     gradients are the un-rematerialised block's; with the rows alone kept
     (PR 30's scratch build) they are not."""
-    p, x = dropless_params
-    mine = {"experts": {"router": p["router"],
-                        "w_up": p["w_up"][_FIRST:_FIRST + _HELD],
-                        "w_down": p["w_down"][_FIRST:_FIRST + _HELD]}}
+    p, x = gated_params if form == "gated" else dropless_params
+    mine = {"experts": {name: (w if name == "router"
+                               else w[_FIRST:_FIRST + _HELD])
+                        for name, w in p.items()}}
     x, delta = _near_tie(p["router"], x)
 
     def grads(block_cls, shift):
-        block = block_cls(shift)
+        block = block_cls(shift, form == "gated")
         with jax.default_matmul_precision("highest"):
             return jax.tree.leaves(jax.grad(lambda p, x: jnp.sum(jnp.sin(
                 block.apply({"params": p}, x))), argnums=(0, 1))(mine, x))

@@ -136,8 +136,11 @@ def test_layer_scopes_are_in_the_step(tiny):
                               jnp.int32)}
     text = built.step.lower(built.state, batch).as_text(debug_info=True)
     paths = set(re.findall(r'loc\("([^"]*)"', text))
-    for scope in profiler.LAYER_SCOPES + (profiler.LM_HEAD,
-                                          profiler.LM_LOSS):
+    # the layer scopes of this family (the others are ``models/lfm2.py``'s)
+    mine = tuple(s for s in profiler.LAYER_SCOPES
+                 if s.startswith(("ssm_", "moe_", "gqa_")))
+    assert len(mine) == 10
+    for scope in mine + (profiler.LM_HEAD, profiler.LM_LOSS):
         under = [p for p in paths
                  if re.search(r"(^|[/(])" + scope + r"([/)]|$)", p)]
         assert under, scope

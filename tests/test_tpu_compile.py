@@ -252,7 +252,62 @@ def _ssd_scan_case(sh):
                _spec((64,), jnp.float32, sh)), 0
 
 
+def _flash_gqa64_8192_case(sh):
+    """The ``lfm2`` attention call (``lfm2_24b_a2b.lm8192``): 32 query
+    heads on 8 key/value heads of 64, causal, 2 x 8192 tokens."""
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention(q, k, v, None, True, 64 ** -0.5)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    return f, (_spec((2, 32, 8192, 64), jnp.bfloat16, sh),
+               _spec((2, 8, 8192, 64), jnp.bfloat16, sh),
+               _spec((2, 8, 8192, 64), jnp.bfloat16, sh)), 3
+
+
+def _gated_grouped_matmul_case(sh):
+    """The held gated experts of one expert layer of the same cell: 8
+    experts of 2048 x (2 x 1536) and 1536 x 2048 over the 65,536 slots of
+    16,384 tokens; gate and up in ONE grouped matmul, SiLU times the gate,
+    down; forward and backward (gmm x 4 + tgmm x 2)."""
+    from apex_tpu.transformer.moe import grouped_matmul
+
+    def f(rows, gate_up, down, sizes):
+        def loss(rows, gate_up, down):
+            h = grouped_matmul(rows, gate_up, sizes).astype(jnp.float32)
+            a = (jax.nn.silu(h[:, :1536]) * h[:, 1536:]).astype(rows.dtype)
+            return grouped_matmul(a, down, sizes).astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(rows, gate_up,
+                                                           down)
+
+    return f, (_spec((65536, 2048), jnp.bfloat16, sh),
+               _spec((8, 2048, 3072), jnp.bfloat16, sh),
+               _spec((8, 1536, 2048), jnp.bfloat16, sh),
+               _spec((8,), jnp.int32, sh)), 5
+
+
+def _short_conv_case(sh):
+    """The gated short convolution of the same cell (width 2048, 3 taps, 2
+    x 8192 tokens): ``short_conv_fwd`` and ``short_conv_bwd``, picked by
+    the platform the program is lowered for (nothing to steer)."""
+    from apex_tpu.ops.short_conv import gated_short_conv
+
+    def f(b, c, x, taps):
+        return jax.value_and_grad(
+            lambda b, c, x, taps: gated_short_conv(b, c, x, taps)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(b, c, x, taps)
+
+    part = _spec((2, 8192, 2048), jnp.bfloat16, sh)
+    return f, (part, part, part, _spec((3, 2048), jnp.float32, sh)), 2
+
+
 _CASES = {
+    "flash_attention_gqa64_8192": _flash_gqa64_8192_case,
+    "gated_grouped_matmul": _gated_grouped_matmul_case,
+    "gated_short_conv": _short_conv_case,
     "layer_norm": _ln_case,
     "flash_attention": _flash_case,
     "flash_attention_causal_1024": _flash_causal_1024_case,
@@ -464,3 +519,87 @@ def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
     print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
           f"{live / 2 ** 30:.2f} GiB")
     assert live <= NEMOTRON_LIVE_BYTES < _hbm_bytes()
+
+
+# -- the fifth cell's step: gated experts, short convolutions, rotary GQA ----
+
+# what the ``lfm2`` step may hold live at 2 x 8,192 tokens a chip with every
+# layer's matmul outputs, flash residuals, routing and expert rows kept
+# (11.64 GiB by this compile; PERF.md section 4, PR 34)
+LFM2_LIVE_BYTES = int(12.5 * 2 ** 30)
+
+
+def test_lfm2_step_runs_no_grouped_matmul_no_sort_and_no_flash_twice(
+        one_chip, compiled_kernels):
+    """The whole train step of ``lfm2_24b_a2b.lm8192`` (five layers at the
+    published widths, amp O2 + FusedAdam through ``build_train_step``, as
+    the cell builds it) compiled from shapes for one described chip. A
+    gated expert layer keeps its routing and its ``(slots, 2F)`` rows: six
+    grouped-matmul calls a layer (``gmm`` gate-up and down forward; two
+    ``gmm`` and two ``tgmm`` backward), none of them, no sort and no flash
+    call on a recomputed path; a conv mixer runs ``short_conv_fwd`` in the
+    forward and the recomputed pass and ``short_conv_bwd`` once; well under
+    the chip's memory."""
+    import re
+
+    import chip_smoke
+    from benchmark.harness import runner
+    from benchmark.harness.manifest import Manifest
+
+    manifest = Manifest()
+    cell = "lfm2_24b_a2b.lm8192"
+    config = manifest.config(manifest.cell(cell)["config"])
+    traffic = manifest.traffic(cell)
+    builder, reference = runner.family(config)
+    built = builder.build(config, traffic, reference, seed=0,
+                          key=runner.weights_key(0), abstract_on=one_chip)
+    assert built.n_params == 469_285_248
+    ids = np.zeros((traffic["rows_per_chip"], traffic["seq"]), np.int32)
+    batch = jax.tree.map(
+        lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip),
+        built.program_batch({"ids": ids, "seed": [1]}))
+    compiled = built.step.lower(built.state, batch).compile()
+    text = compiled.as_text()
+
+    def paths(pattern):
+        """``op_name`` of every instruction whose line matches."""
+        return [m.group(1) for line in text.splitlines()
+                if re.search(pattern, line)
+                for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+    kernels = paths(r'custom_call_target="tpu_custom_call"')
+    kinds = reference.kinds(config)
+    experts = [i for i, pair in enumerate(kinds) if "moe" in pair]
+    convs = [i for i, pair in enumerate(kinds) if "conv" in pair]
+    assert experts == [1, 2, 3, 4] and convs == [0, 2, 3, 4]
+    for i in experts:
+        mine = [p for p in kernels if f"/layers_{i}/expert_ffn/" in p]
+        assert len(mine) == 6, mine
+        assert sum("jit(tgmm)" in p for p in mine) == 2
+        assert sum("jit(gmm)" in p for p in mine) == 4
+        assert all("/moe_experts/" in p for p in mine)
+        assert not any("rematted_computation" in p for p in mine)
+    for i in convs:
+        mine = [p for p in kernels if f"/layers_{i}/conv/" in p]
+        assert all("/conv_gate/" in p for p in mine)
+        assert sum("short_conv_fwd" in p for p in mine) == 2
+        assert sum("short_conv_bwd" in p for p in mine) == 1
+        assert len(mine) == 3
+    flash = [p for p in kernels if "/gqa_attention/" in p]
+    assert sorted(p.rsplit("/", 2)[-2] for p in flash) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert not any("rematted_computation" in p for p in flash)
+    # the rest: the RMSNorm backward of two norms a layer and the last
+    norms = [p for p in kernels if "layer_norm_bwd" in p]
+    assert len(norms) == 2 * len(kinds) + 1
+    assert len(kernels) == (6 * len(experts) + 3 * len(convs) + 3
+                            + len(norms))
+    sorts = paths(r" sort\(")
+    assert not [p for p in sorts if "rematted_computation" in p], sorts
+    sorts = [p for p in sorts if "/experts/" in p]
+    # top_k; the sort by expert, its inverse, the weights there and back
+    assert len(sorts) == 5 * len(experts), sorts
+    live = chip_smoke.live_bytes(compiled.memory_analysis())
+    print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
+          f"{live / 2 ** 30:.2f} GiB")
+    assert live <= LFM2_LIVE_BYTES < 15 * 2 ** 30 < _hbm_bytes()

@@ -221,7 +221,7 @@ def test_benchmark_table_knows_the_vocabulary(table):
 
 
 @pytest.mark.parametrize("module", ["flash_attention", "layer_norm",
-                                    "softmax", "dropout"])
+                                    "softmax", "dropout", "short_conv"])
 def test_every_train_path_kernel_has_a_stable_name(module):
     text = (ROOT / "apex_tpu" / "ops" / f"{module}.py").read_text()
     calls = len(re.findall(r"pl\.pallas_call\(", text))
