@@ -28,6 +28,17 @@ embedding and head around a final RMSNorm.
 Under amp O2 pass :func:`keep_fp32_filter` to ``amp.initialize``: the
 router, ``A_log``, ``D``, ``dt_bias`` and every RMSNorm gain stay float32.
 
+With ``remat`` (the default) every block is rematerialised, and what it
+keeps follows its letter (:mod:`apex_tpu.transformer.remat`). ``M`` and
+``*`` blocks keep their dense matmul outputs (``in_proj``; q, k, v) and
+flash attention's output and log-sum-exp (``"selective"``): the backward
+pass does the RMSNorm, the conv and SiLU, ``softplus``, the gated norm, the
+head transposes and the chunked scan again (its einsums carry batch
+dimensions and name nothing), and no projection and no flash call. An ``E``
+block keeps its normed input, its routing and its hidden rows and nothing
+else: the shared expert's up projection is done again, because keeping its
+output cost the gradients' accuracy (``PERF.md`` section 6, PR 33).
+
 The loss (:meth:`NemotronHLMHeadModel.loss`) runs the head and the
 cross-entropy one sequence at a time under ``jax.checkpoint``: one row's
 float32 logits are live, never the batch's. Beside the loss the model
@@ -53,7 +64,7 @@ from apex_tpu.ops.flash_attention import flash_attention, mha_reference
 from apex_tpu.ops.ssd_scan import ssd_scan
 from apex_tpu.transformer.moe import (DroplessMoE, add_step_counters,
                                       squared_relu, zero_step_counters)
-from apex_tpu.transformer.remat import remat_routing_block
+from apex_tpu.transformer.remat import remat_block, remat_routing_block
 
 _INIT = nn.initializers.normal(stddev=0.02)
 _FP32_LEAVES = ("router", "A_log", "D", "dt_bias")
@@ -284,12 +295,12 @@ class NemotronHModel(nn.Module):
         table = self.param("embedding", _INIT,
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         x = table[input_ids].astype(cfg.dtype)
-        # an expert block keeps its routing and the rows it ordered
-        # (transformer/remat.py); Mamba and attention blocks recompute
-        # everything
+        # what a block keeps follows its letter (the module's docstring):
+        # an expert block its routing and the rows it ordered, a Mamba or
+        # an attention block its matmul outputs and flash's residuals
         plain_cls = expert_cls = NemotronHBlock
         if cfg.remat:
-            plain_cls = nn.remat(NemotronHBlock)
+            plain_cls = remat_block(NemotronHBlock, (), "selective")
             expert_cls = remat_routing_block(NemotronHBlock)
         total = zero_step_counters()
         for i, kind in enumerate(cfg.pattern):

@@ -1,7 +1,8 @@
 """What a rematerialised transformer block keeps for its backward pass.
 
-One helper for the dense block stacks (``models/bert.py``,
-``models/gpt.py``): :func:`remat_block` wraps a block class in
+One helper for the block stacks (``models/bert.py``, ``models/gpt.py``,
+``models/lfm2.py``, and the Mamba and attention blocks of
+``models/nemotron_h.py``): :func:`remat_block` wraps a block class in
 ``flax.linen.remat`` under one of two policies.
 
 ``"selective"`` (the default of ``BertConfig`` / ``GPTConfig``) keeps what

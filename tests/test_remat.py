@@ -81,12 +81,18 @@ def _ops(loss, params):
             name = eqn.primitive.name
             if name == "pallas_call":      # a kernel counts as one op
                 name = eqn.params["name"]
+            names = [name]
+            # a matmul with no batch dimensions: what "selective" keeps
+            if name == "dot_general" and not any(
+                    eqn.params["dimension_numbers"][1]):
+                names.append("dense_dot")
             # an inner jaxpr's name stacks start at its call site
             layer = next((part for part in stack.split("/")
                           if part.startswith(("layer_", "layers_", "h_"))),
                          outer)
-            for key in ((name, again), (name, again, layer)):
-                counts[key] = counts.get(key, 0) + 1
+            for name in names:
+                for key in ((name, again), (name, again, layer)):
+                    counts[key] = counts.get(key, 0) + 1
             if eqn.primitive.name == "pallas_call":
                 continue
             for value in eqn.params.values():
@@ -102,6 +108,19 @@ def _ops(loss, params):
 
 # -- the same mathematics -------------------------------------------------------
 
+def _same_loss_and_gradients(loss, other, params):
+    """Holds ``loss`` to ``other`` at ``params``: value and every gradient
+    to 1e-6. Returns each gradient's largest entry."""
+    want_loss, want = jax.jit(jax.value_and_grad(other))(params)
+    got_loss, got = jax.jit(jax.value_and_grad(loss))(params)
+    assert abs(float(got_loss) - float(want_loss)) <= 1e-6
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+    return [float(jnp.max(jnp.abs(g))) for g in jax.tree.leaves(got)]
+
+
 @pytest.mark.parametrize("other", [dict(remat_policy="full"),
                                    dict(remat=False)],
                          ids=["full", "no_remat"])
@@ -109,15 +128,9 @@ def _ops(loss, params):
 @pytest.mark.parametrize("model", ["bert", "gpt"])
 def test_selective_is_the_same_mathematics(model, dropout, other):
     loss, params = MAKE[model](dropout)            # the class default
-    want_loss, want = jax.jit(jax.value_and_grad(
-        MAKE[model](dropout, **other)[0]))(params)
-    got_loss, got = jax.jit(jax.value_and_grad(loss))(params)
-    assert abs(float(got_loss) - float(want_loss)) <= 1e-6
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6,
-                                   err_msg=jax.tree_util.keystr(path))
-    assert any(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree.leaves(got))
+    peaks = _same_loss_and_gradients(
+        loss, MAKE[model](dropout, **other)[0], params)
+    assert any(peak > 0 for peak in peaks)
 
 
 def test_selective_is_the_default_of_both_configs():
@@ -193,25 +206,29 @@ def test_expert_block_recomputes_everything_under_either_policy(policy):
 
 # -- a block whose expert layer names its routing keeps it, with its rows ------
 
+def _nemotron(pattern, seq=32, **kw):
+    """(loss(params), params) of a tiny ``nemotron_h`` stack."""
+    from apex_tpu.models.nemotron_h import (NemotronHConfig,
+                                            NemotronHLMHeadModel)
+
+    cfg = NemotronHConfig.tiny(pattern=pattern, **kw)
+    model = NemotronHLMHeadModel(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, seq)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    return (lambda p: model.apply({"params": p}, ids, method="loss")[0],
+            params)
+
+
 def test_nemotron_expert_block_keeps_its_routing_and_rows():
     """``models/nemotron_h.py`` wraps an ``E`` block so that the names of
     ``profiler.MOE_RESIDUALS`` are kept: the backward pass runs no grouped
     matmul, no ``top_k``, no sort and no scatter again, and of the block's
     matmuls the router's scores and the shared expert's up projection;
-    ``M`` and ``*`` blocks recompute everything, as before."""
-    from apex_tpu.models.nemotron_h import (NemotronHConfig,
-                                            NemotronHLMHeadModel)
-
-    def ops(**kw):
-        cfg = NemotronHConfig.tiny(pattern="ME*", fused_kernels=False, **kw)
-        model = NemotronHLMHeadModel(cfg)
-        ids = jnp.asarray(np.random.RandomState(0).randint(
-            0, cfg.vocab_size, (2, 32)), jnp.int32)
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        return _ops(lambda p: model.apply({"params": p}, ids,
-                                          method="loss")[0], params)
-
-    kept, none = ops(), ops(remat=False)
+    ``M`` and ``*`` blocks keep their dense matmul outputs (PR 35) and do
+    again only the scan's batched einsums and the composed attention's."""
+    kept = _ops(*_nemotron("ME*", fused_kernels=False))
+    none = _ops(*_nemotron("ME*", fused_kernels=False, remat=False))
     # off the TPU a grouped matmul is ``ragged_dot``: up and down forward,
     # two each backward, in both
     for counts in (kept, none):
@@ -224,10 +241,61 @@ def test_nemotron_expert_block_keeps_its_routing_and_rows():
     assert kept["sort", False, "layers_1"] == 4
     # the router's scores and the shared expert's up projection
     assert kept["dot_general", True, "layers_1"] == 2
-    # the Mamba and the attention block do their matmuls again
-    assert kept["dot_general", True, "layers_0"] == 6
-    assert kept["dot_general", True, "layers_2"] == 5
+    # the Mamba and the attention block do no projection again: what is
+    # left is the scan's five einsums and the composed attention's two,
+    # all with batch dimensions
+    assert kept["dot_general", True, "layers_0"] == 5
+    assert kept["dot_general", True, "layers_2"] == 2
+    for layer in ("layers_0", "layers_2"):
+        assert ("dense_dot", True, layer) not in kept
     # (the loss recomputes a row's head matmul either way, in no layer)
+    assert not [k for k in none if k[1] and k[2:] not in ((), (None,))]
+
+
+# -- the Mamba and attention blocks of ``nemotron_h`` keep their matmuls --------
+
+# seq 128: one flash tile; the tiny config's chunk of 16 gives 8 chunks
+def _mixer_stacks(test):
+    test = pytest.mark.parametrize("fused", [False, True],
+                                   ids=["composed", "fused"])(test)
+    return pytest.mark.parametrize("pattern", ["M", "*", "M*M"])(test)
+
+
+@_mixer_stacks
+def test_nemotron_mixer_blocks_are_the_same_mathematics(pattern, fused):
+    loss, params = _nemotron(pattern, seq=SEQ, fused_kernels=fused)
+    peaks = _same_loss_and_gradients(
+        loss, _nemotron(pattern, seq=SEQ, fused_kernels=fused,
+                        remat=False)[0], params)
+    assert all(peak > 0 for peak in peaks)
+
+
+@_mixer_stacks
+def test_nemotron_mixer_blocks_recompute_no_matmul_and_no_flash(pattern,
+                                                                fused):
+    kept = _ops(*_nemotron(pattern, seq=SEQ, fused_kernels=fused))
+    none = _ops(*_nemotron(pattern, seq=SEQ, fused_kernels=fused,
+                           remat=False))
+    for i, kind in enumerate(pattern):
+        layer = f"layers_{i}"
+        # no projection (in_proj, out_proj; q, k, v, out) runs again ...
+        assert ("dense_dot", True, layer) not in kept
+        assert (kept["dense_dot", False, layer]
+                == none["dense_dot", False, layer])
+        if kind == "*" and fused:
+            # ... and no flash forward: once, and the backward kernels as
+            # without recomputation
+            assert ("flash_fwd", True, layer) not in kept
+            flash = [k for k in none if k[0].startswith("flash_")
+                     and k[2:] == (layer,)]
+            assert len(flash) >= 2 and all(kept[k] == none[k] == 1
+                                           for k in flash), flash
+        if kind == "M":
+            # ... while the chunked scan does: its einsums carry batch
+            # dimensions and ``ssd_scan`` names nothing (ROADMAP.md S6)
+            assert kept["dot_general", True, layer] == 5
+    # the elementwise ops are done again: a block's RMSNorm at the least
+    assert any(k[1] and k[2:] == ("layers_0",) for k in kept)
     assert not [k for k in none if k[1] and k[2:] not in ((), (None,))]
 
 

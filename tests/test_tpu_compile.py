@@ -78,8 +78,7 @@ def mesh4(topo):
     return Mesh(np.array(topo.devices).reshape(4), ("data",))
 
 
-@pytest.fixture
-def compiled_kernels(monkeypatch):
+def _steer_kernels(patch):
     """Trace the Pallas kernels as the chip would: ``interpret=False``."""
     import importlib
 
@@ -87,7 +86,12 @@ def compiled_kernels(monkeypatch):
     # same names as its submodules
     for name in ("dropout", "flash_attention", "layer_norm", "softmax"):
         mod = importlib.import_module(f"apex_tpu.ops.{name}")
-        monkeypatch.setattr(mod, "_interpret", lambda: False)
+        patch.setattr(mod, "_interpret", lambda: False)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    _steer_kernels(monkeypatch)
 
 
 def _spec(shape, dtype, sharding):
@@ -451,25 +455,13 @@ def test_bert_large_ddp_step_compiles_for_four_v5e(mesh4, compiled_kernels):
     assert collective_stats(full_text)["all-reduce"] == stats["all-reduce"]
 
 
-# -- the fourth cell's step: what an expert block does twice ----------------
+# -- a cell's whole step, as its builder builds it ---------------------------
 
-# what the ``nemotron_h`` step may hold live at 2 x 8,192 tokens a chip with
-# the expert blocks' routing and hidden rows kept (12.16 GiB with every
-# block recomputed in full, PERF.md section 6, PR 33)
-NEMOTRON_LIVE_BYTES = int(12.5 * 2 ** 30)
-
-
-def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
-        one_chip, compiled_kernels):
-    """The whole train step of ``nemotron_twotower_30b_a3b.lm8192`` (seven
-    blocks ``MEMEM*E`` at the published widths, amp O2 + FusedAdam
-    through ``build_train_step``, as the cell builds it) compiled from
-    shapes for one described chip. An expert block keeps its routing and
-    its hidden rows under recomputation: six grouped-matmul calls a layer
-    (``gmm`` up and down forward; two ``gmm`` and two ``tgmm`` backward),
-    none of them and no sort (``top_k``, the sort by expert and the ones
-    that follow it) on a recomputed path, inside the memory the kept rows
-    were promised."""
+def _cell_step(cell, one_chip):
+    """The train step of a benchmark cell compiled from shapes for one
+    described chip (call with the kernels steered): ``(builder, reference,
+    config, built, paths, live bytes)``, where ``paths(regex)`` gives the
+    ``op_name`` of every instruction whose line matches."""
     import re
 
     import chip_smoke
@@ -477,7 +469,6 @@ def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
     from benchmark.harness.manifest import Manifest
 
     manifest = Manifest()
-    cell = "nemotron_twotower_30b_a3b.lm8192"
     config = manifest.config(manifest.cell(cell)["config"])
     traffic = manifest.traffic(cell)
     builder, reference = runner.family(config)
@@ -488,17 +479,52 @@ def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
         lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip),
         built.program_batch({"ids": ids, "seed": [1]}))
     compiled = built.step.lower(built.state, batch).compile()
-    text = compiled.as_text()
+    lines = compiled.as_text().splitlines()
 
     def paths(pattern):
-        """``op_name`` of every instruction whose line matches."""
-        return [m.group(1) for line in text.splitlines()
-                if re.search(pattern, line)
+        return [m.group(1) for line in lines if re.search(pattern, line)
                 for m in [re.search(r'op_name="([^"]*)"', line)] if m]
 
+    return (builder, reference, config, built, paths,
+            chip_smoke.live_bytes(compiled.memory_analysis()))
+
+
+# -- the fourth cell's step: what each kind of block does twice -------------
+
+# what the ``nemotron_h`` step may hold live at 2 x 8,192 tokens a chip with
+# the expert blocks' routing and hidden rows, the Mamba blocks' ``in_proj``
+# output and the attention block's q, k, v and flash residuals kept
+# (13.07 GiB by this compile; 11.94 with the Mamba and attention blocks
+# recomputed in full, PERF.md section 6, PR 35)
+NEMOTRON_LIVE_BYTES = int(14.0 * 2 ** 30)
+NEMOTRON_CELL = "nemotron_twotower_30b_a3b.lm8192"
+
+
+@pytest.fixture(scope="module")
+def nemotron_step(one_chip):
+    """The whole train step of ``nemotron_twotower_30b_a3b.lm8192`` (seven
+    blocks ``MEMEM*E`` at the published widths, amp O2 + FusedAdam through
+    ``build_train_step``, as the cell builds it), compiled ONCE for the
+    tests below: ``(paths, pattern, live bytes)``."""
+    with pytest.MonkeyPatch.context() as patch:
+        _steer_kernels(patch)
+        builder, _, config, _, paths, live = _cell_step(NEMOTRON_CELL,
+                                                        one_chip)
+    return paths, builder.model_config(config).pattern, live
+
+
+def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
+        nemotron_step):
+    """An expert block keeps its routing and its hidden rows under
+    recomputation: six grouped-matmul calls a layer (``gmm`` up and down
+    forward; two ``gmm`` and two ``tgmm`` backward), none of them and no
+    sort (``top_k``, the sort by expert and the ones that follow it) on a
+    recomputed path; the attention block keeps flash's residuals, so no
+    kernel at all runs on a recomputed path; inside the memory the kept
+    tensors were promised."""
+    paths, pattern, live = nemotron_step
     kernels = paths(r'custom_call_target="tpu_custom_call"')
-    layers = [i for i, kind in enumerate(builder.model_config(config).pattern)
-              if kind == "E"]
+    layers = [i for i, kind in enumerate(pattern) if kind == "E"]
     assert len(layers) == 3
     for i in layers:
         mine = [p for p in kernels if f"/layers_{i}/" in p]
@@ -506,19 +532,43 @@ def test_nemotron_step_runs_no_grouped_matmul_and_no_sort_twice(
         assert sum("jit(tgmm)" in p for p in mine) == 2
         assert sum("jit(gmm)" in p for p in mine) == 4
         assert all("/moe_experts/" in p for p in mine)
-    # the attention block's three flash calls and its recomputed forward
-    assert len(kernels) == 6 * len(layers) + 4
-    again = [p for p in kernels if "rematted_computation" in p]
-    assert len(again) == 1 and "flash_fwd" in again[0], again
+    # the attention block's three flash calls: forward once, dq, dkv
+    assert len(kernels) == 6 * len(layers) + 3
+    flash = sorted(p.rsplit("/", 2)[-2] for p in kernels
+                   if "/gqa_attention/" in p)
+    assert flash == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], flash
+    assert not [p for p in kernels if "rematted_computation" in p]
     sorts = paths(r" sort\(")
     assert not [p for p in sorts if "rematted_computation" in p], sorts
     sorts = [p for p in sorts if "/experts/" in p]
     # top_k; the sort by expert, its inverse, the weights there and back
     assert len(sorts) == 5 * len(layers), sorts
-    live = chip_smoke.live_bytes(compiled.memory_analysis())
-    print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
+    print(f"{NEMOTRON_CELL}: tpu_custom_call x{len(kernels)}, live "
           f"{live / 2 ** 30:.2f} GiB")
     assert live <= NEMOTRON_LIVE_BYTES < _hbm_bytes()
+
+
+def test_nemotron_step_recomputes_no_projection_but_the_scan(nemotron_step):
+    """A Mamba block keeps ``in_proj``'s output and the attention block its
+    q, k and v (``remat_block(..., "selective")``): none of those matmuls
+    lies on a recomputed path of the compiled step, where under a bare
+    ``nn.remat`` each ran twice. The chunked scan DOES run again in every
+    Mamba block: its einsums carry batch dimensions, so the policy keeps
+    none of them and ``ssd_scan`` names nothing. That is what is left for
+    the scan's own kernel (``ROADMAP.md`` S6): a PR that keeps or fuses
+    its residuals sees this assertion go."""
+    paths, pattern, _ = nemotron_step
+    again = [p for p in paths(r" (convolution|dot)\(")
+             if "rematted_computation" in p]
+    mixers = [i for i, kind in enumerate(pattern) if kind in "M*"]
+    assert len(mixers) == 4
+    for i in mixers:
+        mine = [p for p in again if f"/layers_{i}/" in p]
+        for dense in ("/in_proj/", "/q/", "/k/", "/v/", "/out_proj/",
+                      "/out/"):
+            assert not [p for p in mine if dense in p], (i, dense, mine)
+        scans = [p for p in mine if "/ssm_scan/" in p]
+        assert bool(scans) == (pattern[i] == "M"), (i, mine)
 
 
 # -- the fifth cell's step: gated experts, short convolutions, rotary GQA ----
@@ -540,33 +590,9 @@ def test_lfm2_step_runs_no_grouped_matmul_no_sort_and_no_flash_twice(
     call on a recomputed path; a conv mixer runs ``short_conv_fwd`` in the
     forward and the recomputed pass and ``short_conv_bwd`` once; well under
     the chip's memory."""
-    import re
-
-    import chip_smoke
-    from benchmark.harness import runner
-    from benchmark.harness.manifest import Manifest
-
-    manifest = Manifest()
     cell = "lfm2_24b_a2b.lm8192"
-    config = manifest.config(manifest.cell(cell)["config"])
-    traffic = manifest.traffic(cell)
-    builder, reference = runner.family(config)
-    built = builder.build(config, traffic, reference, seed=0,
-                          key=runner.weights_key(0), abstract_on=one_chip)
+    _, reference, config, built, paths, live = _cell_step(cell, one_chip)
     assert built.n_params == 469_285_248
-    ids = np.zeros((traffic["rows_per_chip"], traffic["seq"]), np.int32)
-    batch = jax.tree.map(
-        lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip),
-        built.program_batch({"ids": ids, "seed": [1]}))
-    compiled = built.step.lower(built.state, batch).compile()
-    text = compiled.as_text()
-
-    def paths(pattern):
-        """``op_name`` of every instruction whose line matches."""
-        return [m.group(1) for line in text.splitlines()
-                if re.search(pattern, line)
-                for m in [re.search(r'op_name="([^"]*)"', line)] if m]
-
     kernels = paths(r'custom_call_target="tpu_custom_call"')
     kinds = reference.kinds(config)
     experts = [i for i, pair in enumerate(kinds) if "moe" in pair]
@@ -599,7 +625,6 @@ def test_lfm2_step_runs_no_grouped_matmul_no_sort_and_no_flash_twice(
     sorts = [p for p in sorts if "/experts/" in p]
     # top_k; the sort by expert, its inverse, the weights there and back
     assert len(sorts) == 5 * len(experts), sorts
-    live = chip_smoke.live_bytes(compiled.memory_analysis())
     print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
           f"{live / 2 ** 30:.2f} GiB")
     assert live <= LFM2_LIVE_BYTES < 15 * 2 ** 30 < _hbm_bytes()
