@@ -20,25 +20,34 @@ TPU design notes:
   degrades to uniform over the keys of its LIVE tiles (below), not over
   all Sk keys as the composed reference does; ``mha_reference`` is the
   specification for every row with at least one visible key.
-- Causal attention past one tile skips the tiles the mask kills. The
-  mask is ``row >= col`` on absolute indices, so from ``program_id`` and
-  the static block sizes each score tile (iq, ik) of the multi-tile
-  kernels (fwd, dq, dkv) is dead (``ik*bk > iq*bq + bq - 1``), full
-  (``ik*bk + bk - 1 <= iq*bq``) or diagonal (``causal_tile_classes``
-  counts them: S=1024 at 512-blocks (dead 1, diagonal 2, full 1); 2048:
-  (6, 4, 6); 640 -> 768 at 384-blocks: (1, 2, 1)). On a dead tile the
-  body does not run (``pl.when``), and the index maps of the operands
+- Attention past one tile skips the tiles its mask kills. A mask that is
+  a static function of (query index, key index) - ``causal`` (``row >=
+  col``) or a ``score_mask`` description (:class:`BlockDiffusionMask`) -
+  makes each score tile (iq, ik) of the multi-tile kernels (fwd, dq, dkv)
+  dead (every pair masked), full (none) or partly masked;
+  :func:`tile_classes` counts them for either kind of mask (causal S=1024
+  at 512-blocks: dead 1, partial 2, full 1; 2048: (6, 4, 6); 640 -> 768
+  at 384-blocks: (1, 2, 1); block diffusion over two copies of L=8192 in
+  blocks of 4: 736 dead, 48 partial, 240 full of 1,024). On a dead tile
+  the body does not run (``pl.when``), and the index maps of the operands
   that vary along the inner grid axis re-name the nearest live block (k,
   v, key mask and the interpret-mode dropout bits in fwd/dq; q, do, lse,
   delta and the bits in dkv), so the pipeline sees an unchanged block
   index and issues no DMA. Such a tile contributed exactly 0
   (``p = exp(FILL - m) = 0`` in fp32), so outputs, lse and gradients are
-  bit-identical. Full tiles run the diagonal tiles' body: a maskless
+  bit-identical. Full tiles run the partly masked tiles' body: a maskless
   second body measured slower on the v5e (see the comment at
   ``_causal_dead``). The grid's shape and order, ``_tile_id`` and so the
   dropout stream are those of the unskipped kernel;
   ``_init``/``_finish`` stay tied to the first and last inner step, dead
-  or not.
+  or not. ``causal`` finds a tile's class and the block to re-name from
+  ``program_id`` and the static block sizes in closed form (its live
+  tiles are one run a row); a ``score_mask``'s live tiles are several
+  runs a row, so its classes and the blocks to re-name are two small
+  int32 tables, made from the description when the call is traced and
+  read from SMEM by ``program_id`` (scalar prefetch), and its element
+  mask comes from iotas and the description's own arithmetic. No mask or
+  score tensor larger than a tile exists anywhere.
 - "No key mask" is static: with ``key_mask=None`` and no key padding the
   multi-tile kernels are built without the two key-mask selects.
 - Forward also emits the per-row logsumexp; backward recomputes score
@@ -79,10 +88,12 @@ backend so tests can compose a bit-matched reference.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -166,15 +177,164 @@ def _causal_full(iq, ik, bq, bk):
     return ik * bk + bk - 1 <= iq * bq
 
 
-def causal_tile_classes(Sq, Sk, bq, bk):
-    """``(dead, diagonal, full)`` tile counts of one head's causal score
-    matrix at block sizes (bq, bk): the multi-tile kernels skip the dead
-    ones (no compute, no DMA) and run the rest. Static in the shapes."""
-    tiles = [(iq, ik) for iq in range(-(-Sq // bq))
-             for ik in range(-(-Sk // bk))]
-    dead = sum(bool(_causal_dead(iq, ik, bq, bk)) for iq, ik in tiles)
-    full = sum(bool(_causal_full(iq, ik, bq, bk)) for iq, ik in tiles)
-    return dead, len(tiles) - dead - full, full
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """Static description of the block-diffusion training mask (Arriola et
+    al., *Block Diffusion*, ICLR 2025): a row of ``seq_len`` tokens is fed
+    as a NOISED copy (positions ``0 .. seq_len - 1``) followed by the CLEAN
+    copy (``seq_len .. 2 seq_len - 1``), in blocks of ``block`` consecutive
+    tokens, ``b(i) = (i mod seq_len) // block``. The query at ``r`` sees the
+    key at ``c`` iff
+
+    * both are noised and ``b(r) == b(c)`` (a block sees itself, both ways),
+    * ``r`` is noised, ``c`` clean and ``b(c) < b(r)`` (strictly earlier
+      blocks of the clean copy), or
+    * both are clean and ``b(c) <= b(r)`` (block-causal);
+
+    nothing sees forward and no clean query sees a noised key. Keys are
+    always the two copies (``Sk = 2 seq_len``). ``clean_queries=False``
+    describes a call whose queries are the noised copy alone (``Sq =
+    seq_len``): the last layer of a stack, whose clean rows feed nothing.
+
+    Hashable, so it rides through ``jax.custom_vjp`` as a static argument.
+    ``tag`` names the kernels of such a call (``flash_<tag>_fwd`` ...), so
+    that a trace tells them from the causal ones."""
+
+    seq_len: int
+    block: int
+    clean_queries: bool = True
+    tag = "blockdiff"
+
+    def __post_init__(self):
+        if self.block < 1 or self.seq_len < 1 or self.seq_len % self.block:
+            raise ValueError(
+                f"BlockDiffusionMask: seq_len ({self.seq_len}) must be a "
+                f"positive multiple of block ({self.block})")
+
+    @property
+    def q_len(self) -> int:
+        return (2 if self.clean_queries else 1) * self.seq_len
+
+    @property
+    def k_len(self) -> int:
+        return 2 * self.seq_len
+
+    def visible(self, row, col):
+        """Boolean ``row sees col`` on absolute indices (int arrays that
+        broadcast against each other; numpy or traced). Two compares at
+        the broadcast shape, joined by the key's copy (and / or: Mosaic
+        has no select between booleans): a noised query's block id is
+        compared for equality with a noised key's (and is -1, equal to
+        none, for a clean query); a clean key's block id must lie below
+        the query's, plus one for a clean query."""
+        L, g = self.seq_len, self.block
+        row_noised, col_noised = row < L, col < L
+        rb = _div(jnp.where(row_noised, row, row - L), g)
+        cb = _div(jnp.where(col_noised, col, col - L), g)
+        same = jnp.where(row_noised, rb, -1)
+        below = jnp.where(row_noised, rb, rb + 1)
+        return (col_noised & (cb == same)) | (~col_noised & (cb < below))
+
+    def _parts(self, lo, hi):
+        """The index range ``lo .. hi`` as (noised positions, clean
+        positions), each ``(first, last)`` within its copy or None."""
+        L = self.seq_len
+        noised = (lo, min(hi, L - 1)) if lo < L else None
+        clean = (max(lo, L) - L, hi - L) if hi >= L else None
+        return noised, clean
+
+    def tile_class(self, r0, r1, c0, c1) -> str:
+        """``"dead"``, ``"full"`` or ``"partial"``: whether no, every or
+        some pair of the rows ``r0 .. r1`` and columns ``c0 .. c1``
+        (inclusive, inside the call's lengths) is visible. Python ints."""
+        g = self.block
+        (rn, rc), (cn, cc) = self._parts(r0, r1), self._parts(c0, c1)
+        any_, all_ = False, not (rc and cn)
+        if rn and cn:      # a block sees itself
+            any_ |= rn[0] // g <= cn[1] // g and cn[0] // g <= rn[1] // g
+            all_ &= rn[0] // g == rn[1] // g == cn[0] // g == cn[1] // g
+        if rn and cc:      # strictly earlier clean blocks
+            any_ |= cc[0] // g < rn[1] // g
+            all_ &= cc[1] // g < rn[0] // g
+        if rc and cc:      # block-causal
+            any_ |= cc[0] // g <= rc[1] // g
+            all_ &= cc[1] // g <= rc[0] // g
+        return "dead" if not any_ else ("full" if all_ else "partial")
+
+    def check(self, Sq, Sk):
+        if (Sq, Sk) != (self.q_len, self.k_len):
+            raise ValueError(
+                f"{self}: the call has {Sq} queries and {Sk} keys, the "
+                f"description {self.q_len} and {self.k_len}")
+
+
+def _div(x, g):
+    """``x // g`` for non-negative ints: a shift where ``g`` is a power of
+    two (cheaper on the TPU's vector unit than a divide, which also
+    compiles)."""
+    if g & (g - 1) == 0:
+        return x >> (g.bit_length() - 1)
+    return x // g
+
+
+def _tile_class_table(Sq, Sk, bq, bk, causal=False, score_mask=None):
+    """``(nq, nk)`` numpy array of ``"dead"`` / ``"partial"`` / ``"full"``:
+    the class of every score tile of one head under ``causal`` or a
+    ``score_mask`` description (one of the two), at block sizes (bq, bk).
+    Rows and columns the wrapper pads beyond (Sq, Sk) are not counted: a
+    tile of padding alone is dead."""
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    table = np.empty((nq, nk), dtype="<U7")
+    for iq in range(nq):
+        for ik in range(nk):
+            if score_mask is not None:
+                table[iq, ik] = score_mask.tile_class(
+                    iq * bq, min(iq * bq + bq, Sq) - 1,
+                    ik * bk, min(ik * bk + bk, Sk) - 1)
+            elif _causal_dead(iq, ik, bq, bk):
+                table[iq, ik] = "dead"
+            else:
+                table[iq, ik] = ("full" if _causal_full(iq, ik, bq, bk)
+                                 else "partial")
+    return table
+
+
+def tile_classes(Sq, Sk, bq, bk, causal=False, score_mask=None):
+    """``(dead, partial, full)`` tile counts of one head's score matrix at
+    block sizes (bq, bk) under ``causal=True`` or a ``score_mask``
+    description: the multi-tile kernels skip the dead ones (no compute, no
+    DMA) and run the rest. Static in the shapes."""
+    if causal == (score_mask is not None):
+        raise ValueError("tile_classes counts under causal=True or under "
+                         "a score_mask, one of the two")
+    table = _tile_class_table(Sq, Sk, bq, bk, causal, score_mask)
+    dead, full = int(np.sum(table == "dead")), int(np.sum(table == "full"))
+    return dead, table.size - dead - full, full
+
+
+@functools.lru_cache(maxsize=None)
+def _mask_tables(score_mask, bq, bk):
+    """The three flat int32 tables a ``score_mask`` call's kernels read
+    from SMEM by ``program_id``: ``live[iq * nk + ik]`` (1 where the tile
+    is not dead), ``fetch_k[iq * nk + ik]`` (the key block the q-major
+    kernels name at that step) and ``fetch_q[ik * nq + iq]`` (the query
+    block the k-major kernel names). A dead step names the block of the
+    last live step before it on its row (its column, k-major), or of the
+    first live step where none came before, so the pipeline sees the block
+    index change only when a live tile needs another block: no DMA is
+    issued for a dead tile."""
+    live = _tile_class_table(score_mask.q_len, score_mask.k_len, bq, bk,
+                             score_mask=score_mask) != "dead"
+
+    def fetch(live):                   # along the last axis
+        n = live.shape[-1]
+        index = np.where(live, np.arange(n), -1)
+        before = np.maximum.accumulate(index, axis=-1)
+        first = np.where(live.any(-1), live.argmax(-1), 0)[:, None]
+        return np.where(before >= 0, before, first)
+
+    flat = lambda a: np.ascontiguousarray(a, np.int32).reshape(-1)  # noqa: E731
+    return flat(live), flat(fetch(live)), flat(fetch(live.T))
 
 
 def _live_k(causal, iq, ik, bq, bk):
@@ -197,15 +357,47 @@ def _live_q(causal, iq, ik, bq, bk, nq):
                      jnp.minimum((ik * bk) // bq, nq - 1), iq)
 
 
-def _on_live_tile(causal, iq, ik, bq, bk, body):
-    """Run ``body()`` for tile (iq, ik) unless the causal mask kills it."""
-    if not causal:
+def _on_live_tile(causal, iq, ik, bq, bk, body, live=None):
+    """Run ``body()`` for tile (iq, ik) unless its mask kills it: by the
+    causal predicate, or by ``live``, a ``score_mask`` call's table entry
+    for the tile."""
+    if live is not None:
+        pl.when(live != 0)(body)
+    elif not causal:
         body()
     else:
         pl.when(jnp.logical_not(_causal_dead(iq, ik, bq, bk)))(body)
 
 
-def _score_tile(q, k, mask_ref, iq, ik, *, scale, causal, bq, bk, has_mask):
+def _visible_tile(score_mask, iq, ik, bq, bk):
+    """``(bq, bk)`` boolean element mask of tile (iq, ik) under a
+    ``score_mask`` description, from a column of row indices and a row of
+    column indices (the description's arithmetic runs at those shapes)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0) + iq * bq
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) + ik * bk
+    return score_mask.visible(row, col)
+
+
+def _tabled(kernel):
+    """``kernel`` for a ``score_mask`` call, whose two scalar-prefetched
+    tables come before the operands: the kernel body reads ``live`` (the
+    index maps read the other, the blocks to name)."""
+    def with_tables(live_ref, fetch_ref, *refs):
+        return kernel(*refs, live_ref=live_ref)
+    return with_tables
+
+
+def _grid(spec, tables):
+    """``pl.pallas_call``'s grid arguments: as they are, or as a grid with
+    the ``score_mask`` call's tables prefetched into SMEM."""
+    if not tables:
+        return spec
+    return {"grid_spec": pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables), **spec)}
+
+
+def _score_tile(q, k, mask_ref, iq, ik, *, scale, causal, bq, bk, has_mask,
+                score_mask=None):
     """fp32 (bq, bk) masked scores of tile (iq, ik), and the key-mask row
     (None when the call has neither a user mask nor key padding).
 
@@ -222,6 +414,8 @@ def _score_tile(q, k, mask_ref, iq, ik, *, scale, causal, bq, bk, has_mask):
         row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
         col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
         s = jnp.where(row >= col, s, FILL)
+    if score_mask is not None:
+        s = jnp.where(_visible_tile(score_mask, iq, ik, bq, bk), s, FILL)
     return s, mrow
 
 
@@ -235,7 +429,8 @@ def _zero_padded_keys(p, mrow):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
-                has_mask=True, dropout_rate=0.0, native_prng=True):
+                has_mask=True, dropout_rate=0.0, native_prng=True,
+                score_mask=None, live_ref=None):
     if dropout_rate > 0.0:
         drop_ref, o_ref, lse_ref, acc_s, m_s, l_s = rest
     else:
@@ -255,7 +450,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
         k = k_ref[0, 0]                            # (bk, D)
         prec = _prec(q.dtype)
         s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
-                              causal=causal, bq=bq, bk=bk, has_mask=has_mask)
+                              causal=causal, bq=bq, bk=bk, has_mask=has_mask,
+                              score_mask=score_mask)
 
         m_prev = m_s[:, :1]                        # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -280,7 +476,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
         m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
-    _on_live_tile(causal, iq, ik, bq, bk, _tile)
+    _on_live_tile(causal, iq, ik, bq, bk, _tile,
+                  None if live_ref is None else live_ref[iq * nk + ik])
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -291,7 +488,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal,
-                       bq, bk, dropout_rate=0.0, native_prng=True):
+                       bq, bk, dropout_rate=0.0, native_prng=True,
+                       score_mask=None):
     """Single-tile forward (nq == nk == 1): the whole attention row fits
     one tile, so the softmax is direct — no VMEM running-statistics
     scratch, no alpha rescale of the accumulator, no @pl.when phases."""
@@ -311,6 +509,8 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal,
         row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(row >= col, s, FILL)
+    if score_mask is not None:
+        s = jnp.where(_visible_tile(score_mask, 0, 0, bq, bk), s, FILL)
 
     m = jnp.max(s, axis=1, keepdims=True)
     p = jnp.exp(s - m)
@@ -335,7 +535,8 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                    *rest, scale, causal, bq, bk, has_mask=True,
-                   dropout_rate=0.0, native_prng=True):
+                   dropout_rate=0.0, native_prng=True, score_mask=None,
+                   live_ref=None):
     if dropout_rate > 0.0:
         drop_ref, dq_ref, dq_s = rest
     else:
@@ -353,7 +554,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0]
         prec = _prec(q.dtype)
         s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
-                              causal=causal, bq=bq, bk=bk, has_mask=has_mask)
+                              causal=causal, bq=bq, bk=bk, has_mask=has_mask,
+                              score_mask=score_mask)
 
         lse = lse_ref[0, 0, 0][:, None]            # (bq, 1)
         p = _zero_padded_keys(jnp.exp(s - lse), mrow)     # (bq, bk)
@@ -372,7 +574,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta) * scale              # (bq, bk)
         dq_s[:] = dq_s[:] + _dot(ds.astype(k.dtype), k, ((1,), (0,)), prec)
 
-    _on_live_tile(causal, iq, ik, bq, bk, _tile)
+    _on_live_tile(causal, iq, ik, bq, bk, _tile,
+                  None if live_ref is None else live_ref[iq * nk + ik])
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -381,7 +584,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                       delta_ref, *rest, scale, causal, bq, bk,
-                      dropout_rate=0.0, native_prng=True):
+                      dropout_rate=0.0, native_prng=True, score_mask=None):
     """Single-tile backward (nq == nk == 1 — the reference fmha's
     seqlen<=512 specialization): one (b, h) grid step recomputes s and p
     ONCE and emits dq, dk, AND dv — 5 matmuls instead of the 7 the
@@ -403,6 +606,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(row >= col, s, FILL)
+    if score_mask is not None:
+        s = jnp.where(_visible_tile(score_mask, 0, 0, bq, bk), s, FILL)
 
     lse = lse_ref[0, 0, 0][:, None]
     p = jnp.exp(s - lse)
@@ -430,7 +635,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                     *rest, scale, causal, bq, bk, has_mask=True,
-                    dropout_rate=0.0, native_prng=True):
+                    dropout_rate=0.0, native_prng=True, score_mask=None,
+                    live_ref=None):
     if dropout_rate > 0.0:
         drop_ref, dk_ref, dv_ref, dk_s, dv_s = rest
     else:
@@ -449,7 +655,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0]                            # (bk, D)
         prec = _prec(q.dtype)
         s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
-                              causal=causal, bq=bq, bk=bk, has_mask=has_mask)
+                              causal=causal, bq=bq, bk=bk, has_mask=has_mask,
+                              score_mask=score_mask)
 
         lse = lse_ref[0, 0, 0][:, None]
         p = _zero_padded_keys(jnp.exp(s - lse), mrow)     # (bq, bk)
@@ -476,7 +683,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         # dk += ds^T @ q
         dk_s[:] = dk_s[:] + _dot(ds.astype(q.dtype), q, ((0,), (0,)), prec)
 
-    _on_live_tile(causal, iq, ik, bq, bk, _tile)
+    _on_live_tile(causal, iq, ik, bq, bk, _tile,
+                  None if live_ref is None
+                  else live_ref[iq * pl.num_programs(2) + ik])
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -528,24 +737,38 @@ def _sum_groups(dk, k):
     return dk.reshape(B, Hkv, -1, Sk, D).sum(axis=2)
 
 
-def _q_major_maps(causal, bq, bk, kv_head=lambda h: h):
+def _q_major_maps(causal, bq, bk, kv_head=lambda h: h, tabled_nk=None):
     """Index maps on the q-major grid ``(b, h, iq, ik)`` of fwd and dq for
     the operands blocked along keys: (k / v, key mask, interpret-mode
     dropout bits). Under ``causal`` a dead step keeps the row's last live
-    block (``_live_k``)."""
-    def live(iq, ik):
+    block (``_live_k``); in a ``score_mask`` call (``tabled_nk``: its key
+    blocks a row) every step names the block its prefetched table gives
+    (``_mask_tables``), which the maps get as their last argument."""
+    def live(iq, ik, *tables):
+        if tabled_nk is not None:
+            return tables[1][iq * tabled_nk + ik]
         return _live_k(causal, iq, ik, bq, bk)
 
-    return (lambda b, h, iq, ik: (b, kv_head(h), live(iq, ik), 0),
-            lambda b, h, iq, ik: (b, 0, live(iq, ik)),
-            lambda b, h, iq, ik: (b, h, iq, live(iq, ik)))
+    return (lambda b, h, iq, ik, *t: (b, kv_head(h), live(iq, ik, *t), 0),
+            lambda b, h, iq, ik, *t: (b, 0, live(iq, ik, *t)),
+            lambda b, h, iq, ik, *t: (b, h, iq, live(iq, ik, *t)))
+
+
+def _kernel_name(kind, score_mask):
+    """``flash_<kind>``, or ``flash_<tag>_<kind>`` for a ``score_mask``
+    call: a trace tells the two apart by name."""
+    return f"flash_{kind}" if score_mask is None else (
+        f"flash_{score_mask.tag}_{kind}")
 
 
 def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
-                    dropout_rate=0.0, drop_in=None):
+                    dropout_rate=0.0, drop_in=None, score_mask=None):
     """``has_mask=False`` (static: no user key mask, no key padding, so
     ``mask`` is all zeros) builds the multi-tile kernel without its two
-    key-mask selects; the single-tile kernel ignores it."""
+    key-mask selects; the single-tile kernel ignores it. ``score_mask``
+    (static; the lengths it was checked against are the unpadded ones)
+    adds its element mask to the kernels and, past one tile, its two
+    prefetched tables to the call."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     native = drop_in is not None and drop_in.ndim == 1
@@ -558,7 +781,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
             functools.partial(_fwd_single_kernel, scale=scale,
                               causal=causal, bq=bq, bk=bk,
                               dropout_rate=dropout_rate,
-                              native_prng=native),
+                              native_prng=native, score_mask=score_mask),
             grid=(B, H),
             in_specs=[
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
@@ -574,43 +797,54 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
                 out_struct((B, H, Sq, D), q.dtype, q, k, v),
                 out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
             ),
-            name="flash_fwd",
+            name=_kernel_name("fwd", score_mask),
             interpret=_interpret(),
         )(q, k, v, mask, *extra)
-    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk, kv_head)
+    nk = Sk // bk
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
+                               bk=bk, has_mask=has_mask,
+                               dropout_rate=dropout_rate, native_prng=native,
+                               score_mask=score_mask)
+    tables = ()
+    if score_mask is not None:
+        live, fetch_k, _ = _mask_tables(score_mask, bq, bk)
+        tables, kernel = (live, fetch_k), _tabled(kernel)
+    kv_map, mask_map, bits_map = _q_major_maps(
+        causal, bq, bk, kv_head, nk if tables else None)
     extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, has_mask=has_mask,
-                          dropout_rate=dropout_rate, native_prng=native),
-        grid=(B, H, Sq // bq, Sk // bk),
-        in_specs=[
-            _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
-            _spec4(bk, D, kv_map),
-            _spec4(bk, D, kv_map),
-            pl.BlockSpec((1, 1, bk), mask_map),
-        ] + extra_specs,
-        out_specs=(
-            _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
-        ),
+        kernel,
+        **_grid(dict(
+            grid=(B, H, Sq // bq, nk),
+            in_specs=[
+                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
+                _spec4(bk, D, kv_map),
+                _spec4(bk, D, kv_map),
+                pl.BlockSpec((1, 1, bk), mask_map),
+            ] + extra_specs,
+            out_specs=(
+                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b, h, iq, ik, *t: (b, h, 0, iq)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, LANE), jnp.float32),
+                pltpu.VMEM((bq, LANE), jnp.float32),
+            ]), tables),
         out_shape=(
             out_struct((B, H, Sq, D), q.dtype, q, k, v),
             out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, LANE), jnp.float32),
-            pltpu.VMEM((bq, LANE), jnp.float32),
-        ],
-        name="flash_fwd",
+        name=_kernel_name("fwd", score_mask),
         interpret=_interpret(),
-    )(q, k, v, mask, *extra)
+    )(*tables, q, k, v, mask, *extra)
     return out, lse
 
 
 def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
-                    has_mask=True, dropout_rate=0.0, drop_in=None):
+                    has_mask=True, dropout_rate=0.0, drop_in=None,
+                    score_mask=None):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     native = drop_in is not None and drop_in.ndim == 1
@@ -629,7 +863,7 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
         return pl.pallas_call(
             functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, dropout_rate=dropout_rate,
-                              native_prng=native),
+                              native_prng=native, score_mask=score_mask),
             grid=(B, H),
             in_specs=[
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
@@ -650,75 +884,91 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
                 out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
                 out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
             ),
-            name="flash_bwd",
+            name=_kernel_name("bwd", score_mask),
             interpret=_interpret(),
         )(q, k, v, mask, do, lse, delta, *extra)
 
-    nq = Sq // bq
+    nq, nk = Sq // bq, Sk // bk
     kern = dict(scale=scale, causal=causal, bq=bq, bk=bk, has_mask=has_mask,
-                dropout_rate=dropout_rate, native_prng=native)
+                dropout_rate=dropout_rate, native_prng=native,
+                score_mask=score_mask)
+    dq_kernel = functools.partial(_bwd_dq_kernel, **kern)
+    dkv_kernel = functools.partial(_bwd_dkv_kernel, **kern)
+    dq_tables = dkv_tables = ()
+    if score_mask is not None:
+        live, fetch_k, fetch_q = _mask_tables(score_mask, bq, bk)
+        dq_tables, dkv_tables = (live, fetch_k), (live, fetch_q)
+        dq_kernel, dkv_kernel = _tabled(dq_kernel), _tabled(dkv_kernel)
 
-    kv_map, mask_map, bits_map = _q_major_maps(causal, bq, bk, kv_head)
+    kv_map, mask_map, bits_map = _q_major_maps(
+        causal, bq, bk, kv_head, nk if dq_tables else None)
     extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kern),
-        grid=(B, H, nq, Sk // bk),
-        in_specs=[
-            _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
-            _spec4(bk, D, kv_map),
-            _spec4(bk, D, kv_map),
-            pl.BlockSpec((1, 1, bk), mask_map),
-            _spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
-        ] + extra_specs,
-        out_specs=_spec4(bq, D, lambda b, h, iq, ik: (b, h, iq, 0)),
+        dq_kernel,
+        **_grid(dict(
+            grid=(B, H, nq, nk),
+            in_specs=[
+                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
+                _spec4(bk, D, kv_map),
+                _spec4(bk, D, kv_map),
+                pl.BlockSpec((1, 1, bk), mask_map),
+                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b, h, iq, ik, *t: (b, h, 0, iq)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b, h, iq, ik, *t: (b, h, 0, iq)),
+            ] + extra_specs,
+            out_specs=_spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]), dq_tables),
         out_shape=out_struct((B, H, Sq, D), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        name="flash_bwd_dq",
+        name=_kernel_name("bwd_dq", score_mask),
         interpret=_interpret(),
-    )(q, k, v, mask, do, lse, delta, *extra)
+    )(*dq_tables, q, k, v, mask, do, lse, delta, *extra)
 
     # k-major grid (ik outer, iq inner): a dead step keeps the column's
-    # first live q/do/lse/delta/bits block
-    def live(ik, iq):
+    # first live q/do/lse/delta/bits block (causal), or the block the
+    # ``score_mask`` call's table names
+    def live(ik, iq, *tables):
+        if tables:
+            return tables[1][ik * nq + iq]
         return _live_q(causal, iq, ik, bq, bk, nq)
 
-    def q_map(b, h, ik, iq):
-        return (b, h, live(ik, iq), 0)
+    def q_map(b, h, ik, iq, *t):
+        return (b, h, live(ik, iq, *t), 0)
 
-    def row_map(b, h, ik, iq):
-        return (b, h, 0, live(ik, iq))
+    def row_map(b, h, ik, iq, *t):
+        return (b, h, 0, live(ik, iq, *t))
 
     extra, extra_specs = _drop_arg(
-        drop_in, bq, bk, lambda b, h, ik, iq: (b, h, live(ik, iq), ik))
+        drop_in, bq, bk, lambda b, h, ik, iq, *t: (b, h, live(ik, iq, *t), ik))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kern),
-        grid=(B, H, Sk // bk, nq),
-        in_specs=[
-            _spec4(bq, D, q_map),
-            _spec4(bk, D, lambda b, h, ik, iq: (b, kv_head(h), ik, 0)),
-            _spec4(bk, D, lambda b, h, ik, iq: (b, kv_head(h), ik, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, ik, iq: (b, 0, ik)),
-            _spec4(bq, D, q_map),
-            pl.BlockSpec((1, 1, 1, bq), row_map),
-            pl.BlockSpec((1, 1, 1, bq), row_map),
-        ] + extra_specs,
-        out_specs=(
-            _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
-            _spec4(bk, D, lambda b, h, ik, iq: (b, h, ik, 0)),
-        ),
+        dkv_kernel,
+        **_grid(dict(
+            grid=(B, H, nk, nq),
+            in_specs=[
+                _spec4(bq, D, q_map),
+                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, kv_head(h), ik, 0)),
+                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, kv_head(h), ik, 0)),
+                pl.BlockSpec((1, 1, bk), lambda b, h, ik, iq, *t: (b, 0, ik)),
+                _spec4(bq, D, q_map),
+                pl.BlockSpec((1, 1, 1, bq), row_map),
+                pl.BlockSpec((1, 1, 1, bq), row_map),
+            ] + extra_specs,
+            out_specs=(
+                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, h, ik, 0)),
+                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, h, ik, 0)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ]), dkv_tables),
         out_shape=(
             out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
             out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        name="flash_bwd_dkv",
+        name=_kernel_name("bwd_dkv", score_mask),
         interpret=_interpret(),
-    )(q, k, v, mask, do, lse, delta, *extra)
+    )(*dkv_tables, q, k, v, mask, do, lse, delta, *extra)
     return dq, dk, dv
 
 
@@ -845,7 +1095,7 @@ def _repeat_groups(k, H):
     return k if k.shape[1] == H else jnp.repeat(k, H // k.shape[1], axis=1)
 
 
-def _scores(q, k, key_mask, causal, scale):
+def _scores(q, k, key_mask, causal, scale, score_mask=None):
     """(B, H, Sq, Sk) fp32 masked scores — shared by every composed path."""
     k = _repeat_groups(k, q.shape[1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
@@ -857,18 +1107,25 @@ def _scores(q, k, key_mask, causal, scale):
         row = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 1)
         s = jnp.where((row >= col)[None, None], s, FILL)
+    if score_mask is not None:
+        Sq, Sk = s.shape[-2:]
+        score_mask.check(Sq, Sk)
+        s = jnp.where(score_mask.visible(np.arange(Sq)[:, None],
+                                         np.arange(Sk)[None, :]), s, FILL)
     return s
 
 
 def mha_reference(q, k, v, key_mask=None, causal=False, scale=1.0,
-                  dropout_rate=0.0, dropout_seed=None):
-    """Composed-ops reference: materializes (B, H, Sq, Sk) scores.
+                  dropout_rate=0.0, dropout_seed=None, score_mask=None):
+    """Composed-ops reference: materializes (B, H, Sq, Sk) scores (under a
+    ``score_mask`` description, its dense mask too).
 
     With dropout the mask comes from ``jax.random`` (same distribution as
     the kernel's hardware PRNG, different bits — use
     ``flash_dropout_keep_mask`` + ``mha_with_mask_reference`` for
     bit-matched parity tests)."""
-    p = jax.nn.softmax(_scores(q, k, key_mask, causal, scale), axis=-1)
+    p = jax.nn.softmax(_scores(q, k, key_mask, causal, scale, score_mask),
+                       axis=-1)
     if dropout_rate > 0.0:
         if dropout_seed is None:
             raise ValueError(
@@ -892,10 +1149,10 @@ def mha_with_mask_reference(q, k, v, keep, key_mask=None, causal=False,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 8))
 def flash_attention(q, k, v, key_mask=None, causal: bool = False,
                     scale: float = 1.0, dropout_rate: float = 0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, score_mask=None):
     """Multi-head attention without materializing the score matrix.
 
     Args:
@@ -908,6 +1165,13 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
       key_mask: optional ``(B, Sk)`` boolean, True = key position masked
         (the reference's padding-mask convention).
       causal: apply the upper-triangular causal mask in-kernel.
+      score_mask: a static description of a mask that is a function of
+        (query index, key index), beyond ``causal``
+        (:class:`BlockDiffusionMask`; hashable, not traced). Its dead
+        tiles are skipped as causal's are and its live tiles are masked
+        from iotas; no mask tensor exists. The call's lengths must be the
+        description's. Not together with ``causal``, ``key_mask`` or
+        dropout (nothing needs the combinations; they raise).
       scale: softmax temperature (typically ``1/sqrt(D)``).
       dropout_rate: attention-probability dropout, fused in-kernel (the
         reference fmha's Philox dropout; static Python float).
@@ -920,19 +1184,45 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
     replays the identical dropout mask from the seed.
     """
     out, _ = _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate,
-                        dropout_seed)
+                        dropout_seed, score_mask)
     return out
 
 
+def _check_score_mask(score_mask, Sq, Sk, key_mask, causal, dropout_rate):
+    """A ``score_mask`` call stands alone: the description holds the whole
+    mask, and its tables are made for the call's own lengths."""
+    if score_mask is None:
+        return
+    score_mask.check(Sq, Sk)
+    if causal:
+        raise ValueError(
+            "flash_attention: score_mask describes the whole mask (what it "
+            "lets a query see is already behind it); causal=True on top "
+            "would need the product of two tile tables that no model asks "
+            "for")
+    if key_mask is not None:
+        raise ValueError(
+            "flash_attention: score_mask with a key_mask is not built: a "
+            "fully user-masked live tile would need the causal path's rule "
+            "for rows with no visible key, and the block-diffusion rows "
+            "are unpadded")
+    if dropout_rate > 0.0:
+        raise ValueError(
+            "flash_attention: score_mask with attention dropout is not "
+            "built (the block-diffusion objective has none)")
+
+
 def _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate=0.0,
-               dropout_seed=None):
+               dropout_seed=None, score_mask=None):
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError(
             "flash_attention with dropout_rate > 0 requires dropout_seed "
             "(an int32 scalar; fold in the training step / layer index)")
+    _check_score_mask(score_mask, q.shape[2], k.shape[2], key_mask, causal,
+                      dropout_rate)
     if use_jnp_fallback(q, k, v, key_mask):
         out = mha_reference(q, k, v, key_mask, causal, scale,
-                            dropout_rate, dropout_seed)
+                            dropout_rate, dropout_seed, score_mask)
         return out, None
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
@@ -943,7 +1233,8 @@ def _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate=0.0,
     out, lse = _flash_fwd_call(qp, kp, vp, mask, scale=scale, causal=causal,
                                bq=bq, bk=bk,
                                has_mask=_has_mask(key_mask, Sk, bk),
-                               dropout_rate=dropout_rate, drop_in=drop_in)
+                               dropout_rate=dropout_rate, drop_in=drop_in,
+                               score_mask=score_mask)
     return out[:, :, :Sq, :D], lse
 
 
@@ -959,14 +1250,16 @@ def _name_residuals(out, lse):
 
 
 def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, dropout_rate,
-                   dropout_seed):
+                   dropout_seed, score_mask):
     out, lse = _name_residuals(*_flash_fwd(
-        q, k, v, key_mask, causal, scale, dropout_rate, dropout_seed))
+        q, k, v, key_mask, causal, scale, dropout_rate, dropout_seed,
+        score_mask))
     return out, (q, k, v, key_mask, out, lse, dropout_seed)
 
 
 def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
-                g_lse=None, dropout_rate=0.0, dropout_seed=None):
+                g_lse=None, dropout_rate=0.0, dropout_seed=None,
+                score_mask=None):
     """Shared recompute backward for both vjps. ``lse_padded`` is the
     kernel's padded-width lse; ``g_lse`` (optional, (B, H, 1, Sq)) is the
     lse cotangent, folded into delta (d lse/d s = p, so
@@ -997,7 +1290,8 @@ def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
     dq, dk, dv = _flash_bwd_call(qp, kp, vp, mask, gp, lse_padded, delta,
                                  scale=scale, causal=causal, bq=bq, bk=bk,
                                  has_mask=_has_mask(key_mask, Sk, bk),
-                                 dropout_rate=dropout_rate, drop_in=drop_in)
+                                 dropout_rate=dropout_rate, drop_in=drop_in,
+                                 score_mask=score_mask)
     dk, dv = _sum_groups(dk, kp), _sum_groups(dv, vp)
     return (match_vma(dq[:, :, :Sq, :D].astype(q.dtype), q),
             match_vma(dk[:, :, :Sk, :D].astype(k.dtype), k),
@@ -1005,12 +1299,12 @@ def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
             None)
 
 
-def _flash_vjp_bwd(causal, scale, dropout_rate, res, g):
+def _flash_vjp_bwd(causal, scale, dropout_rate, score_mask, res, g):
     q, k, v, key_mask, out, lse, dropout_seed = res
     if lse is None:  # jnp fallback path: differentiate the reference
         def f(q, k, v):
             return mha_reference(q, k, v, key_mask, causal, scale,
-                                 dropout_rate, dropout_seed)
+                                 dropout_rate, dropout_seed, score_mask)
 
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g)
@@ -1018,7 +1312,8 @@ def _flash_vjp_bwd(causal, scale, dropout_rate, res, g):
                 None, None)
     dq, dk, dv, dmask = _kernel_bwd(causal, scale, q, k, v, key_mask, out,
                                     lse, g, dropout_rate=dropout_rate,
-                                    dropout_seed=dropout_seed)
+                                    dropout_seed=dropout_seed,
+                                    score_mask=score_mask)
     return dq, dk, dv, dmask, None
 
 

@@ -13,70 +13,82 @@ the names are this module's constants, so a reader of a trace has ONE
 table to learn (docs/observability.md, "Training: scopes and
 annotations"):
 
-================== ======================================== ==========================
-scope              what runs under it                       emitted in
-================== ======================================== ==========================
-train_fwd_bwd      forward, recomputed forward, backward of train/step.py (microbatch)
-                   one microbatch. JAX itself marks the
-                   backward ops ``transpose(jvp(...))`` and
-                   the recomputed ones
-                   ``checkpoint/rematted_computation``
-train_accumulate   the zeroed fp32 accumulator, the          train/step.py
-                   accumulate, the microbatch loop's own
-                   plumbing
-train_reduce       average over microbatches (+ DDP sync)   train/step.py (apply)
-train_metrics      loss mean, gradient norm, aux gather,     train/step.py (apply)
-                   the step counter
-amp_scale_loss     loss x scale                              amp/scaler.py
-amp_unscale        gradients / scale + finite check          amp/scaler.py
-amp_found_inf      the global overflow flag                  train/step.py (apply)
-amp_update_scale   the scaler's state update                 train/step.py (apply)
-optimizer_update   the ``lax.cond`` and both its branches    train/step.py (apply)
-lamb_grad_norm     LAMB stage 0: global gradient norm        optimizers/fused_lamb.py
-lamb_stage1        LAMB clip + moments + update directions   optimizers/fused_lamb.py
-lamb_stage2        LAMB trust ratios + parameter step        optimizers/fused_lamb.py
-adam_update        the one fused Adam/AdamW update           optimizers/fused_adam.py
-ddp_flatten        gradient leaves -> flat buffer(s)         parallel/distributed.py
-ddp_allreduce      predivide, psum, average                  parallel/distributed.py
-ddp_unflatten      flat buffer(s) -> gradient leaves         parallel/distributed.py
-lm_head            GPT's tied vocabulary einsum              models/gpt.py
-lm_loss            shifted cross-entropy (fp32 logsumexp)    models/gpt.py
-mlm_head           BERT gather + transform + LN + decoder    models/bert.py
-nsp_head           BERT next-sentence classifier             models/bert.py
-pretraining_loss   MLM + NSP loss                            models/bert.py
-ssm_in_proj        Mamba-2 input projection (z, x, B, C, dt) models/nemotron_h.py
-ssm_conv           depthwise causal conv + SiLU              models/nemotron_h.py
-ssm_scan           the chunked selective scan                ops/ssd_scan.py
-ssm_out            gated grouped RMSNorm + output projection models/nemotron_h.py
-moe_router         fp32 scores, top-k, normalised weights    transformer/moe.py
-moe_dispatch       sort by expert, gather the held rows      transformer/moe.py
-moe_experts        the held experts' grouped matmuls         transformer/moe.py
-moe_shared         the shared expert (every token)           models/nemotron_h.py
-moe_combine        weighted rows back to token order         transformer/moe.py
-gqa_attention      q/k/v projections, grouped-query flash,   models/nemotron_h.py,
-                   output projection                         models/lfm2.py
-attn_qk_norm       per-head RMSNorm of q and of k            models/lfm2.py
-attn_rope          rotary positions on q and k               models/lfm2.py
-conv_in_proj       short convolution: input projection       models/lfm2.py
-                   ``[B | C | x]``
-conv_gate          the two gates and the causal taps:        ops/short_conv.py
-                   ``C * conv(B * x)``
-conv_out_proj      short convolution: output projection      models/lfm2.py
-mlp_dense          the dense gated MLP                       models/lfm2.py
-================== ======================================== ==========================
+=================== ======================================== ==========================
+scope               what runs under it                       emitted in
+=================== ======================================== ==========================
+train_fwd_bwd       forward, recomputed forward, backward of train/step.py (microbatch)
+                    one microbatch. JAX itself marks the
+                    backward ops ``transpose(jvp(...))`` and
+                    the recomputed ones
+                    ``checkpoint/rematted_computation``
+train_accumulate    the zeroed fp32 accumulator, the          train/step.py
+                    accumulate, the microbatch loop's own
+                    plumbing
+train_reduce        average over microbatches (+ DDP sync)   train/step.py (apply)
+train_metrics       loss mean, gradient norm, aux gather,     train/step.py (apply)
+                    the step counter
+amp_scale_loss      loss x scale                              amp/scaler.py
+amp_unscale         gradients / scale + finite check          amp/scaler.py
+amp_found_inf       the global overflow flag                  train/step.py (apply)
+amp_update_scale    the scaler's state update                 train/step.py (apply)
+optimizer_update    the ``lax.cond`` and both its branches    train/step.py (apply)
+lamb_grad_norm      LAMB stage 0: global gradient norm        optimizers/fused_lamb.py
+lamb_stage1         LAMB clip + moments + update directions   optimizers/fused_lamb.py
+lamb_stage2         LAMB trust ratios + parameter step        optimizers/fused_lamb.py
+adam_update         the one fused Adam/AdamW update           optimizers/fused_adam.py
+ddp_flatten         gradient leaves -> flat buffer(s)         parallel/distributed.py
+ddp_allreduce       predivide, psum, average                  parallel/distributed.py
+ddp_unflatten       flat buffer(s) -> gradient leaves         parallel/distributed.py
+lm_head             GPT's tied vocabulary einsum              models/gpt.py
+lm_loss             shifted cross-entropy (fp32 logsumexp)    models/gpt.py
+mlm_head            BERT gather + transform + LN + decoder    models/bert.py
+nsp_head            BERT next-sentence classifier             models/bert.py
+pretraining_loss    MLM + NSP loss                            models/bert.py
+ssm_in_proj         Mamba-2 input projection (z, x, B, C, dt) models/nemotron_h.py
+ssm_conv            depthwise causal conv + SiLU              models/nemotron_h.py
+ssm_scan            the chunked selective scan                ops/ssd_scan.py
+ssm_out             gated grouped RMSNorm + output projection models/nemotron_h.py
+moe_router          fp32 scores, top-k, normalised weights    transformer/moe.py
+moe_dispatch        sort by expert, gather the held rows      transformer/moe.py
+moe_experts         the held experts' grouped matmuls         transformer/moe.py
+moe_shared          the shared expert (every token)           models/nemotron_h.py
+moe_combine         weighted rows back to token order         transformer/moe.py
+gqa_attention       q/k/v projections, grouped-query flash,   models/nemotron_h.py,
+                    output projection                         models/lfm2.py
+attn_qk_norm        per-head RMSNorm of q and of k            models/lfm2.py
+attn_rope           rotary positions on q and k               models/lfm2.py
+conv_in_proj        short convolution: input projection       models/lfm2.py
+                    ``[B | C | x]``
+conv_gate           the two gates and the causal taps:        ops/short_conv.py
+                    ``C * conv(B * x)``
+conv_out_proj       short convolution: output projection      models/lfm2.py
+mlp_dense           the dense gated MLP                       models/lfm2.py
+diffusion_noise     block diffusion: the seeded draw, the     models/sdar.py
+                    noised row, the two copies
+blockdiff_attention q/k/v projections, q/k norm, rotary,      models/sdar.py
+                    block-masked grouped-query flash, output
+                    projection
+diffusion_loss      the masked positions' cross-entropy,      models/sdar.py
+                    weighed by 1 / p (inside ``lm_loss``)
+=================== ======================================== ==========================
 
 The model scopes from ``ssm_in_proj`` down sit INSIDE ``train_fwd_bwd`` (a
 phase reader files their ops by that ancestor); ``attn_qk_norm`` and
-``attn_rope`` sit inside ``gqa_attention`` besides. ``models/lfm2.py``
-reuses ``lm_head`` / ``lm_loss`` and the ``moe_*`` scopes of the expert
-layer it shares with ``models/nemotron_h.py``.
+``attn_rope`` sit inside ``gqa_attention`` (``models/lfm2.py``) or
+``blockdiff_attention`` (``models/sdar.py``) besides. ``models/lfm2.py``
+and ``models/sdar.py`` reuse ``lm_head`` / ``lm_loss`` and the ``moe_*``
+scopes of the expert layer they share with ``models/nemotron_h.py``.
 
 Pallas kernels carry a stable ``name=`` that says kernel and direction,
 never the caller (:data:`KERNEL_NAMES`); the name becomes the HLO
 instruction's name and so the device event's: ``flash_fwd``,
 ``flash_bwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``layer_norm_fwd``,
 ``layer_norm_bwd``, ``softmax_fwd``, ``softmax_bwd``, ``dropout_apply``,
-``dropout_mask``, ``short_conv_fwd``, ``short_conv_bwd``. The dropless
+``dropout_mask``, ``short_conv_fwd``, ``short_conv_bwd``; a flash call
+under a ``score_mask`` description carries the description's tag, so that
+a trace tells it from a causal call: ``flash_blockdiff_fwd``,
+``flash_blockdiff_bwd``, ``flash_blockdiff_bwd_dq``,
+``flash_blockdiff_bwd_dkv``. The dropless
 expert layer runs the grouped-matmul kernels that ship with JAX
 (``jax.experimental.pallas.ops.tpu.megablox``),
 which name themselves: ``gmm`` (forward and the rows' gradient) and
@@ -98,7 +110,10 @@ ordered them.
 
 A model may report step counters beside its loss
 (``build_train_step(has_aux=True)``; they arrive with the loss in
-``metrics["aux"]``, no extra sync): :data:`STEP_COUNTERS`.
+``metrics["aux"]``, no extra sync): :data:`STEP_COUNTERS` (the expert
+layers') and, for the block-diffusion objective,
+``diffusion_masked_tokens`` (:data:`DIFFUSION_COUNTERS`: the positions the
+step's draw masked, which are the positions its loss runs over).
 
 Host annotations (``jax.profiler.TraceAnnotation``, on the host plane
 of the same capture; a flag test when no capture runs):
@@ -167,6 +182,9 @@ CONV_IN_PROJ = "conv_in_proj"
 CONV_GATE = "conv_gate"
 CONV_OUT_PROJ = "conv_out_proj"
 MLP_DENSE = "mlp_dense"
+DIFFUSION_NOISE = "diffusion_noise"
+BLOCKDIFF_ATTENTION = "blockdiff_attention"
+DIFFUSION_LOSS = "diffusion_loss"
 
 STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
                AMP_SCALE_LOSS, AMP_UNSCALE, AMP_FOUND_INF, AMP_UPDATE_SCALE,
@@ -180,7 +198,8 @@ MODEL_SCOPES = (LM_HEAD, LM_LOSS, MLM_HEAD, NSP_HEAD, PRETRAINING_LOSS)
 LAYER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_OUT, MOE_ROUTER,
                 MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE,
                 GQA_ATTENTION, ATTN_QK_NORM, ATTN_ROPE, CONV_IN_PROJ,
-                CONV_GATE, CONV_OUT_PROJ, MLP_DENSE)
+                CONV_GATE, CONV_OUT_PROJ, MLP_DENSE, DIFFUSION_NOISE,
+                BLOCKDIFF_ATTENTION, DIFFUSION_LOSS)
 SCOPES = STEP_SCOPES + OPTIMIZER_SCOPES + DDP_SCOPES + MODEL_SCOPES
 
 # -- step metrics a model reports beside its loss (``has_aux``) ----------------
@@ -189,12 +208,17 @@ MOE_LOAD_MAX_OVER_MEAN = "moe_load_max_over_mean"
 MOE_TOKENS_DROPPED = "moe_tokens_dropped"
 STEP_COUNTERS = (MOE_ASSIGNMENTS_HELD, MOE_LOAD_MAX_OVER_MEAN,
                  MOE_TOKENS_DROPPED)
+# the block-diffusion objective's own (``models/sdar.py``), beside the above
+DIFFUSION_MASKED_TOKENS = "diffusion_masked_tokens"
+DIFFUSION_COUNTERS = (DIFFUSION_MASKED_TOKENS,)
 
 # -- Pallas kernel names (``pl.pallas_call(name=...)``) ------------------------
 KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "layer_norm_fwd", "layer_norm_bwd", "softmax_fwd",
                 "softmax_bwd", "dropout_apply", "dropout_mask",
-                "short_conv_fwd", "short_conv_bwd")
+                "short_conv_fwd", "short_conv_bwd", "flash_blockdiff_fwd",
+                "flash_blockdiff_bwd", "flash_blockdiff_bwd_dq",
+                "flash_blockdiff_bwd_dkv")
 
 # kernels of a library the train path calls (named by the library)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")

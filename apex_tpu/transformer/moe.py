@@ -409,14 +409,21 @@ def add_step_counters(total, counters):
     return total
 
 
+# the router's score of a token over ALL the experts, float32 in and out
+_SCORE_FUNCTIONS = {"sigmoid": jax.nn.sigmoid,
+                    "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 class DroplessMoE(nn.Module):
     """One rank's share of a dropless mixture-of-experts layer.
 
     The router scores every token over all ``num_experts`` experts in
-    float32 (``sigmoid``), picks the ``top_k`` largest of ``score +
-    selection_bias`` and weighs them by their scores, normalised over the
-    chosen ``top_k`` (``norm_topk_prob``: ``s_i / (sum + norm_topk_eps)``)
-    and times ``routed_scaling_factor``. This rank holds experts
+    float32 - ``score_function`` ``"sigmoid"`` (each expert alone) or
+    ``"softmax"`` (over all the experts), which the model sets from its
+    family - picks the ``top_k`` largest of ``score + selection_bias`` and
+    weighs them by their scores, normalised over the chosen ``top_k``
+    (``norm_topk_prob``: ``s_i / (sum + norm_topk_eps)``) and times
+    ``routed_scaling_factor``. This rank holds experts
     ``expert_offset .. expert_offset + experts_held - 1`` and returns the
     part of the sum that THEY give, ``sum_{i chosen, held} w_i expert_i(h)``,
     with no bias anywhere. An expert has one of two forms, which the model
@@ -462,6 +469,7 @@ class DroplessMoE(nn.Module):
     params_dtype: jnp.dtype = jnp.float32
     gated: bool = False
     norm_topk_eps: float = 1e-20
+    score_function: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x, selection_bias=None):
@@ -472,6 +480,9 @@ class DroplessMoE(nn.Module):
                              f"among the {E} experts")
         if k > E:
             raise ValueError(f"top_k ({k}) exceeds num_experts ({E})")
+        if self.score_function not in _SCORE_FUNCTIONS:
+            raise ValueError(f"score_function {self.score_function!r}: one "
+                             f"of {sorted(_SCORE_FUNCTIONS)}")
         init = nn.initializers.normal(stddev=0.02)
         router = self.param("router", init, (H, E), self.params_dtype)
         if self.gated:
@@ -488,7 +499,7 @@ class DroplessMoE(nn.Module):
 
         # the ``checkpoint_name``s below are ``profiler.MOE_RESIDUALS``
         with jax.named_scope(profiler.MOE_ROUTER):
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = _SCORE_FUNCTIONS[self.score_function](jnp.dot(
                 tokens.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
             chosen_by = scores if selection_bias is None else (

@@ -112,6 +112,70 @@ def test_nemotron_h_calls_by_hand():
         2 * ops, 2 * nbytes)
 
 
+# -- the sdar family's own count (block diffusion: live pairs, not "causal at half") ---
+
+SDAR_CONFIG, SDAR_CELL = "sdar_30b_a3b_chat", "sdar_30b_a3b_chat.bd8192"
+
+
+def test_sdar_forward_by_hand():
+    c, t = M.config(SDAR_CONFIG), M.traffic(SDAR_CELL)
+    counts = flops.counts(c)
+    rows, L, g, H, V = 1, 8192, 4, 2048, 18992
+    qw, kvw, F = 32 * 128, 4 * 128, 768
+    # the mask's live pairs of a head, by hand and against the kernel's
+    # own helper (the count file imports nothing of the program): a noised
+    # block sees itself, the clean copy of strictly earlier blocks; the
+    # clean copy is block-causal
+    noised = L * g + L * (L - g) // 2
+    clean = L * (L + g) // 2
+    assert noised + clean == L * L + g * L
+    assert counts.live_pairs(c, L) == L * L + g * L
+    assert counts.live_pairs(c, L, clean_queries=False) == noised
+    from apex_tpu.ops.flash_attention import BlockDiffusionMask, tile_classes
+    for clean_queries, pairs in ((True, noised + clean), (False, noised)):
+        mask = BlockDiffusionMask(64, g, clean_queries)
+        dead, partial, full = tile_classes(mask.q_len, 128, 1, 1,
+                                           score_mask=mask)
+        assert partial == 0 and full == counts.live_pairs(
+            {"block_length": g}, 64, clean_queries)
+
+    def layer(positions, pairs):       # positions: the query side's
+        return (2 * positions * H * qw * 2 + 2 * 2 * L * H * kvw * 2
+                + 2 * 2 * pairs * qw + 2 * positions * H * 128
+                + 3 * 2 * positions * (8 * 16 / 128) * H * F)
+
+    want = int(3 * layer(2 * L, noised + clean) + layer(L, noised)
+               + 2 * L * H * V)
+    assert counts.forward_flops(c, t, rows) == want
+    assert flops.step_flops(c, t, 1) == 3 * want
+    assert flops.step_flops(c, t, 1) == pytest.approx(2.177e13, rel=1e-3)
+    assert counts.attention_shape(c) == {
+        "query_heads": 32, "kv_heads": 4, "head_size": 128, "causal": False}
+
+
+def test_sdar_calls_by_hand():
+    c = M.config(SDAR_CONFIG)
+    counts = flops.counts(c)
+    L, g, qw, kvw = 8192, 4, 4096, 512
+    pair = 2 * 2 * (L * L + g * L) * qw
+    ops, nbytes = counts.blockdiff_attention_call(c, 1, L,
+                                                  "attention_forward")
+    assert ops == pair
+    assert nbytes == (2 * L * 2 * qw + 2 * L * 2 * kvw) * 2
+    for kind, pairs, at_q, at_kv in (("attention_backward_dq", 1, 3, 2),
+                                     ("attention_backward_dkv", 1, 2, 4),
+                                     ("attention_backward", 2, 4, 4)):
+        assert counts.blockdiff_attention_call(c, 1, L, kind) == (
+            pairs * pair, (2 * L * at_q * qw + 2 * L * at_kv * kvw) * 2)
+    # the last layer's call: the noised copy's queries on the same keys
+    ops, nbytes = counts.blockdiff_attention_call(
+        c, 1, L, "attention_forward", clean_queries=False)
+    assert ops == 2 * 2 * (L * g + L * (L - g) // 2) * qw
+    assert nbytes == (L * 2 * qw + 2 * L * 2 * kvw) * 2
+    # a forward call's 288 live tiles of 512 x 512 hold its live pairs
+    assert 288 * 512 * 512 >= L * L + g * L > 240 * 512 * 512
+
+
 def test_the_configuration_file_states_its_cut():
     entry = M._entry("configs", CONFIG)
     c = M.config(CONFIG)

@@ -484,7 +484,7 @@ def test_causal_row_with_no_visible_key_is_uniform_over_its_live_tiles(
 def test_causal_tile_classes_match_brute_force(Sq, Sk, bq, bk, expected):
     """The predicates (the kernels skip on ``dead``), counted over the
     grid, against a classification read off the ``row >= col`` matrix."""
-    from apex_tpu.ops.flash_attention import causal_tile_classes
+    from apex_tpu.ops.flash_attention import tile_classes
 
     nq, nk = -(-Sq // bq), -(-Sk // bk)
     visible = np.arange(nq * bq)[:, None] >= np.arange(nk * bk)[None, :]
@@ -492,7 +492,7 @@ def test_causal_tile_classes_match_brute_force(Sq, Sk, bq, bk, expected):
     dead = int((~tiles.any(axis=(2, 3))).sum())
     full = int(tiles.all(axis=(2, 3)).sum())
     brute = (dead, nq * nk - dead - full, full)
-    assert causal_tile_classes(Sq, Sk, bq, bk) == brute
+    assert tile_classes(Sq, Sk, bq, bk, causal=True) == brute
     if expected is not None:
         assert brute == expected
 
@@ -610,3 +610,174 @@ def test_multi_head_is_bit_identical_to_the_parent(monkeypatch, S, causal,
         assert a.dtype == b.dtype == jnp.bfloat16, name
         assert np.array_equal(np.asarray(a.astype(jnp.float32)),
                               np.asarray(b.astype(jnp.float32))), name
+
+
+# ------------------------------------- a mask by function: block diffusion
+
+def _dense_blockdiff(L, g, clean_queries=True):
+    """The block-diffusion mask written out from its definition (the
+    issue's, not the kernel's arithmetic): rows and columns are ``[noised ;
+    clean]``, ``b(i) = (i mod L) // g``."""
+    r = np.arange(2 * L if clean_queries else L)[:, None]
+    c = np.arange(2 * L)[None, :]
+    rn, cn, rb, cb = r < L, c < L, (r % L) // g, (c % L) // g
+    return ((rn & cn & (rb == cb)) | (rn & ~cn & (cb < rb))
+            | (~rn & ~cn & (cb <= rb)))
+
+
+@pytest.mark.parametrize("L,g,clean_queries", [
+    (64, 4, True),      # one tile (128 x 128)
+    (192, 32, True),    # one tile of 384, L no multiple of a tile
+    (320, 1, True),     # 640 -> 768 at 384-blocks: L straddles a tile
+    (512, 4, True),     # 1024 at 512-blocks: 8 live tiles of 16
+    (576, 4, False),    # the last layer's call: L queries on 2 L keys
+    (512, 32, False),
+])
+def test_block_diffusion_mask_matches_the_dense_mask(L, g, clean_queries):
+    """The block-masked kernels (dead tiles skipped by table, live tiles
+    masked from iotas; 4 query heads on 2 key/value heads): forward and
+    the three gradients against composed attention under the mask built
+    densely from its definition; ``mha_reference`` takes the same
+    description and builds the same mask."""
+    from apex_tpu.ops.flash_attention import (FILL, BlockDiffusionMask,
+                                              _block_sizes)
+
+    mask = BlockDiffusionMask(L, g, clean_queries)
+    H, Hkv, D, scale = 4, 2, 32, 32 ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(L + g), 4)
+    q = jax.random.normal(ks[0], (1, H, mask.q_len, D))
+    k = jax.random.normal(ks[1], (1, Hkv, 2 * L, D))
+    v = jax.random.normal(ks[2], (1, Hkv, 2 * L, D))
+    go = jax.random.normal(ks[3], q.shape)
+    dense = jnp.asarray(_dense_blockdiff(L, g, clean_queries))
+    np.testing.assert_array_equal(
+        np.asarray(mask.visible(np.arange(mask.q_len)[:, None],
+                                np.arange(2 * L)[None, :])),
+        np.asarray(dense))
+
+    def composed(q, k, v):
+        kk, vv = (jnp.repeat(t, H // Hkv, axis=1) for t in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kk) * scale
+        p = jax.nn.softmax(jnp.where(dense, s, FILL), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vv)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, None, False, scale, score_mask=mask)
+
+    def scalar(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * go)
+
+    want = composed(q, k, v)
+    assert _max_err(kernel(q, k, v), want) < 2e-6
+    assert _max_err(mha_reference(q, k, v, None, False, scale,
+                                  score_mask=mask), want) < 2e-6
+    got = jax.grad(scalar(kernel), (0, 1, 2))(q, k, v)
+    ref = jax.grad(scalar(composed), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, ref):
+        assert _max_err(a, b) < 1e-5, name
+    # the sizes above are one tile and past one tile
+    assert (_block_sizes(mask.q_len, 2 * L) == (mask.q_len, 2 * L)) == (
+        L in (64, 192))
+
+
+@pytest.mark.parametrize("L,g,bq,bk,clean_queries,expected", [
+    (1024, 4, 512, 512, True, (8, 6, 2)),         # 8 live of 16
+    (8192, 4, 512, 512, True, (736, 48, 240)),    # 288 live of 1,024
+    (8192, 4, 512, 512, False, (360, 32, 120)),   # the last layer's call
+    (320, 1, 384, 384, True, None),               # L straddles a tile
+    (192, 32, 128, 256, True, None),
+    (96, 3, 64, 32, True, None),                  # blocks across tile edges
+    (96, 8, 32, 64, False, None),
+])
+def test_block_diffusion_tile_classes_and_tables(L, g, bq, bk, clean_queries,
+                                                 expected):
+    """``tile_classes`` under the description against a classification
+    read off the dense mask; the prefetched tables name, on every dead
+    step, the block of the nearest live step of its row (its column), so
+    the block index changes only where a live tile needs another block."""
+    from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                              _mask_tables, tile_classes)
+
+    mask = BlockDiffusionMask(L, g, clean_queries)
+    Sq, Sk = mask.q_len, mask.k_len
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    dense = np.zeros((nq * bq, nk * bk), bool)
+    dense[:Sq, :Sk] = _dense_blockdiff(L, g, clean_queries)
+    real = np.zeros_like(dense)
+    real[:Sq, :Sk] = True
+    tiles = dense.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    real = real.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    live = tiles.any(axis=(2, 3))
+    full = (tiles | ~real).all(axis=(2, 3)) & live
+    brute = (int((~live).sum()), int((live & ~full).sum()), int(full.sum()))
+    assert tile_classes(Sq, Sk, bq, bk, score_mask=mask) == brute
+    if expected is not None:
+        assert brute == expected
+    with pytest.raises(ValueError, match="one of the two"):
+        tile_classes(Sq, Sk, bq, bk, causal=True, score_mask=mask)
+    live_t, fetch_k, fetch_q = _mask_tables(mask, bq, bk)
+    np.testing.assert_array_equal(live_t.reshape(nq, nk) != 0, live)
+    for table, alive in ((fetch_k.reshape(nq, nk), live),
+                         (fetch_q.reshape(nk, nq), live.T)):
+        for row, ok in zip(table, alive):
+            assert ok.any()                  # every row and column is read
+            assert np.all(ok[row])           # only live blocks are named
+            assert np.all(row[ok] == np.flatnonzero(ok))
+            # fetched blocks in order, each fetched once: no DMA on a
+            # dead step
+            changes = np.flatnonzero(np.diff(row)) + 1
+            assert np.all(ok[changes]) and len(changes) == ok.sum() - 1
+
+
+def test_without_a_description_the_kernels_hold_no_mask_operation():
+    """"No score mask" is static, as "no key mask" is: a call with
+    ``causal=True/False`` and no description builds the kernels it built
+    before - no table operand, no scalar prefetch, none of the
+    description's integer arithmetic - and a described call does."""
+    from apex_tpu.ops.flash_attention import BlockDiffusionMask
+
+    q, k, v = _mk(1, 2, 1024, 1024, 64)
+
+    def kernels(causal, score_mask=None):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, None, causal, 0.125,
+                                           score_mask=score_mask))
+
+        text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
+        return text
+
+    for causal in (False, True):
+        text = kernels(causal)
+        assert text.count("pallas_call") == 3
+        assert "blockdiff" not in text and "shift_right" not in text
+        assert "num_scalar_prefetch=0" in text.replace(" ", "") or \
+            "num_scalar_prefetch" not in text
+    text = kernels(False, BlockDiffusionMask(512, 4))
+    assert text.count("pallas_call") == 3
+    for name in ("flash_blockdiff_fwd", "flash_blockdiff_bwd_dq",
+                 "flash_blockdiff_bwd_dkv"):
+        assert name in text
+    assert "shift_right" in text
+
+
+def test_a_description_stands_alone_and_fits_its_call():
+    from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                              flash_attention_bsh)
+
+    mask = BlockDiffusionMask(64, 4)
+    q, k, v = _mk(1, 2, 128, 128, 32)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, None, True, 1.0, score_mask=mask)
+    with pytest.raises(ValueError, match="key_mask"):
+        flash_attention(q, k, v, jnp.zeros((1, 128), bool), False, 1.0,
+                        score_mask=mask)
+    with pytest.raises(ValueError, match="dropout"):
+        flash_attention(q, k, v, None, False, 1.0, 0.1, 3, score_mask=mask)
+    with pytest.raises(ValueError, match="64 queries"):
+        flash_attention(q[:, :, :64], k, v, None, False, 1.0,
+                        score_mask=mask)
+    with pytest.raises(ValueError, match="multiple"):
+        BlockDiffusionMask(66, 4)
+    with pytest.raises(TypeError):       # the _bsh layout takes none
+        flash_attention_bsh(q[:, 0], k[:, 0], v[:, 0], None, 1, False, 1.0,
+                            0.0, None, mask)

@@ -480,15 +480,26 @@ def test_dropless_gradients_match_the_uncut_layer(dropless_params):
 # -- the gated form: W_down (act(W_gate h) * W_up h), gate and up side by side --
 
 _GATED = dict(gated=True, activation=jax.nn.silu, norm_topk_eps=1e-6)
+# the two score functions of the gated form: sigmoid with the 1e-6 of
+# ``models/lfm2.py``; softmax over all the experts, normalised over the
+# chosen with no epsilon to speak of (``models/sdar.py``)
+_SCORED = {"sigmoid": (_GATED, jax.nn.sigmoid, 1e-6),
+           "softmax": (dict(gated=True, activation=jax.nn.silu,
+                            score_function="softmax"),
+                       lambda z: jnp.exp(z - jax.nn.logsumexp(
+                           z, -1, keepdims=True)), 0.0)}
+scored = pytest.mark.parametrize("score", sorted(_SCORED))
 
 
-def _uncut_gated_reference(p, x, k=_K, scale=2.5):
-    """The whole gated layer as a loop over every expert with a 0/1 mask."""
+def _uncut_gated_reference(p, x, k=_K, scale=2.5, score="sigmoid"):
+    """The whole gated layer as a loop over every expert with a 0/1 mask;
+    the scores by the explicit formula."""
+    _, score_of, eps = _SCORED[score]
     t = x.reshape(-1, _H)
-    s = jax.nn.sigmoid(t @ p["router"])
+    s = score_of(t @ p["router"])
     _, idx = jax.lax.top_k(s, k)
     w = jnp.take_along_axis(s, idx, -1)
-    w = w / (w.sum(-1, keepdims=True) + 1e-6) * scale
+    w = w / (w.sum(-1, keepdims=True) + eps) * scale
     y = 0.0
     for e in range(p["w_gate_up"].shape[0]):
         mine = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
@@ -507,18 +518,25 @@ def gated_params():
     return jax.tree.map(lambda a: a * 10.0, p), x
 
 
-def test_gated_experts_match_a_loop_over_experts(gated_params):
+@scored
+def test_gated_experts_match_a_loop_over_experts(gated_params, score):
     """Values and gradients of the gated form (one grouped matmul of width
-    2F, the row weighed with the gate) against the plain loop."""
+    2F, the row weighed with the gate) against the plain loop, under both
+    score functions (one code path after the scores)."""
     p, x = gated_params
-    layer = _dropless(_E, **_GATED)
+    layer = _dropless(_E, **_SCORED[score][0])
     with jax.default_matmul_precision("highest"):
         y, counters = layer.apply({"params": p}, x)
-        want = _uncut_gated_reference(p, x)
+        want = _uncut_gated_reference(p, x, score=score)
         got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
             layer.apply({"params": p}, x)[0])), argnums=(0, 1))(p, x)
         ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(
-            _uncut_gated_reference(p, x))), argnums=(0, 1))(p, x)
+            _uncut_gated_reference(p, x, score=score))),
+            argnums=(0, 1))(p, x)
+    if score == "softmax":      # the scores are a distribution
+        with pytest.raises(ValueError, match="score_function"):
+            _dropless(_E, gated=True, score_function="tanh").apply(
+                {"params": p}, x)
     assert float(counters["moe_tokens_dropped"]) == 0.0
     assert float(jnp.max(jnp.abs(y - want))) < 1e-4 * float(
         jnp.max(jnp.abs(want)))
@@ -527,19 +545,23 @@ def test_gated_experts_match_a_loop_over_experts(gated_params):
             jnp.max(jnp.abs(b)))
 
 
-def test_the_eight_gated_shares_add_up_to_the_uncut_layer(gated_params):
+@scored
+def test_the_eight_gated_shares_add_up_to_the_uncut_layer(gated_params,
+                                                          score):
     """8 ranks of 2 experts each (offsets 0, 2, .., 14 of 16: the cut of
-    ``lfm2_24b_a2b`` - offsets 0, 8, .., 56 of 64 - at this size) give
-    what the uncut layer gives; there is no shared expert to count once."""
+    ``lfm2_24b_a2b`` - offsets 0, 8, .., 56 of 64 - and of
+    ``sdar_30b_a3b_chat`` - 0, 16, .., 112 of 128, softmax-scored - at
+    this size) give what the uncut layer gives; there is no shared expert
+    to count once."""
     p, x = gated_params
     with jax.default_matmul_precision("highest"):
-        whole = _uncut_gated_reference(p, x)
+        whole = _uncut_gated_reference(p, x, score=score)
         total, pairs = 0.0, 0.0
         for first in range(0, _E, 2):
             mine = {"router": p["router"],
                     "w_gate_up": p["w_gate_up"][first:first + 2],
                     "w_down": p["w_down"][first:first + 2]}
-            y, counters = _dropless(2, first, **_GATED).apply(
+            y, counters = _dropless(2, first, **_SCORED[score][0]).apply(
                 {"params": mine}, x)
             total = total + y
             pairs += float(counters["moe_assignments_held"])

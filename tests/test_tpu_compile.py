@@ -23,6 +23,7 @@ failing collection.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -628,3 +629,53 @@ def test_lfm2_step_runs_no_grouped_matmul_no_sort_and_no_flash_twice(
     print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
           f"{live / 2 ** 30:.2f} GiB")
     assert live <= LFM2_LIVE_BYTES < 15 * 2 ** 30 < _hbm_bytes()
+
+
+# -- the sixth cell's step: block diffusion over two copies of a row ----------
+
+# what the ``sdar`` step may hold live at 1 x 8,192 tokens read as 16,384
+# positions with every layer's matmul outputs, flash residuals, routing and
+# expert rows kept (12.16 GiB by this compile; PERF.md section 4, PR 36)
+SDAR_LIVE_BYTES = int(13.0 * 2 ** 30)
+
+
+def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
+        one_chip, compiled_kernels):
+    """The whole train step of ``sdar_30b_a3b_chat.bd8192`` (four layers at
+    the published widths, the block-diffusion loss, amp O2 + FusedAdam
+    through ``build_train_step``, as the cell builds it) compiled from
+    shapes for one described chip: every layer makes ONE block-masked
+    flash call over the two copies (forward once, ``dq``, ``dkv``: the
+    block keeps ``o`` and ``lse``) and six grouped-matmul calls, none of
+    them and no sort on a recomputed path; no causal flash kernel is in
+    the step; under the chip's memory."""
+    cell = "sdar_30b_a3b_chat.bd8192"
+    _, _, config, built, paths, live = _cell_step(cell, one_chip)
+    assert built.n_params == 456_346_624
+    kernels = paths(r'custom_call_target="tpu_custom_call"')
+    layers = range(config["num_hidden_layers"])
+    for i in layers:
+        mine = [p for p in kernels if f"/layers_{i}/expert_ffn/" in p]
+        assert len(mine) == 6, mine
+        assert sum("jit(tgmm)" in p for p in mine) == 2
+        assert sum("jit(gmm)" in p for p in mine) == 4
+        assert all("/moe_experts/" in p for p in mine)
+        flash = [p for p in kernels if f"/layers_{i}/self_attn/" in p]
+        assert all("/blockdiff_attention/" in p for p in flash)
+        assert sorted(p.rsplit("/", 2)[-2] for p in flash) == [
+            "flash_blockdiff_bwd_dkv", "flash_blockdiff_bwd_dq",
+            "flash_blockdiff_fwd"], flash
+    assert not any("rematted_computation" in p for p in kernels)
+    assert not [p for p in kernels
+                if re.search(r"/flash_(fwd|bwd|bwd_dq|bwd_dkv)(/|$)", p)]
+    # the rest: the RMSNorm backward of two norms a layer and the last
+    norms = [p for p in kernels if "layer_norm_bwd" in p]
+    assert len(norms) == 2 * len(layers) + 1
+    assert len(kernels) == (6 + 3) * len(layers) + len(norms)
+    sorts = paths(r" sort\(")
+    assert not [p for p in sorts if "rematted_computation" in p], sorts
+    sorts = [p for p in sorts if "/experts/" in p]
+    assert len(sorts) == 5 * len(layers), sorts
+    print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
+          f"{live / 2 ** 30:.2f} GiB")
+    assert live <= SDAR_LIVE_BYTES < 15 * 2 ** 30 < _hbm_bytes()

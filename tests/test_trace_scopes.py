@@ -226,7 +226,14 @@ def test_every_train_path_kernel_has_a_stable_name(module):
     text = (ROOT / "apex_tpu" / "ops" / f"{module}.py").read_text()
     calls = len(re.findall(r"pl\.pallas_call\(", text))
     names = re.findall(r'^\s+name="(\w+)",$', text, re.M)
-    assert calls and len(names) == calls
+    # a flash call that may carry a mask description is named by kind and,
+    # under a description, by the description's tag as well
+    kinds = re.findall(r'^\s+name=_kernel_name\("(\w+)", score_mask\),$',
+                       text, re.M)
+    assert calls and len(names) + len(kinds) == calls
+    assert bool(kinds) == (module == "flash_attention")
+    names += [f"flash_{k}" for k in kinds]
+    names += [f"flash_blockdiff_{k}" for k in kinds]
     assert set(names) <= set(profiler.KERNEL_NAMES)
 
 
