@@ -28,8 +28,11 @@ dtype; one RMSNorm after the last layer; an untied head.
   same functions, each copy on its own so that both count from 0); ONE
   :func:`~apex_tpu.ops.flash_attention.flash_attention` call over the two
   copies under the mask description, so a noised query's softmax runs over
-  its noised block and its clean prefix at once; keys and values stay at
-  their own head count into the kernel; no mask or score tensor exists.
+  its noised block and its clean prefix at once; past one tile the
+  kernels' grid is the list of the mask's live tiles (288 of 1,024 a head
+  at ``L`` = 8,192 in tiles of 512; 152 of 512 in the last layer), so no
+  grid step is spent on a dead one; keys and values stay at their own
+  head count into the kernel; no mask or score tensor exists.
 - :class:`ExpertFFN`: the dropless share of
   :class:`apex_tpu.transformer.moe.DroplessMoE`, gated, scored by
   ``softmax`` over all ``num_experts`` in float32 and normalised over the

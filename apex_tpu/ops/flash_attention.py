@@ -28,26 +28,30 @@ TPU design notes:
   :func:`tile_classes` counts them for either kind of mask (causal S=1024
   at 512-blocks: dead 1, partial 2, full 1; 2048: (6, 4, 6); 640 -> 768
   at 384-blocks: (1, 2, 1); block diffusion over two copies of L=8192 in
-  blocks of 4: 736 dead, 48 partial, 240 full of 1,024). On a dead tile
-  the body does not run (``pl.when``), and the index maps of the operands
-  that vary along the inner grid axis re-name the nearest live block (k,
-  v, key mask and the interpret-mode dropout bits in fwd/dq; q, do, lse,
-  delta and the bits in dkv), so the pipeline sees an unchanged block
-  index and issues no DMA. Such a tile contributed exactly 0
-  (``p = exp(FILL - m) = 0`` in fp32), so outputs, lse and gradients are
-  bit-identical. Full tiles run the partly masked tiles' body: a maskless
-  second body measured slower on the v5e (see the comment at
-  ``_causal_dead``). The grid's shape and order, ``_tile_id`` and so the
-  dropout stream are those of the unskipped kernel;
-  ``_init``/``_finish`` stay tied to the first and last inner step, dead
-  or not. ``causal`` finds a tile's class and the block to re-name from
+  blocks of 4: 736 dead, 48 partial, 240 full of 1,024). A dead tile
+  contributed exactly 0 (``p = exp(FILL - m) = 0`` in fp32), so outputs,
+  lse and gradients are bit-identical to the unskipped kernels'. Full
+  tiles run the partly masked tiles' body: a maskless second body
+  measured slower on the v5e (see the comment at ``_causal_dead``).
+  Under ``causal`` the grid stays ``(B, H, nq, nk)`` (and with it
+  ``_tile_id`` and the dropout stream): a tile's class comes from
   ``program_id`` and the static block sizes in closed form (its live
-  tiles are one run a row); a ``score_mask``'s live tiles are several
-  runs a row, so its classes and the blocks to re-name are two small
-  int32 tables, made from the description when the call is traced and
-  read from SMEM by ``program_id`` (scalar prefetch), and its element
-  mask comes from iotas and the description's own arithmetic. No mask or
-  score tensor larger than a tile exists anywhere.
+  tiles are one run a row); on a dead tile the body does not run
+  (``pl.when``) and the index maps of the operands that vary along the
+  inner grid axis re-name the nearest live block (k, v, key mask and the
+  interpret-mode dropout bits in fwd/dq; q, do, lse, delta and the bits
+  in dkv), so the pipeline sees an unchanged block index and issues no
+  DMA; ``_init``/``_finish`` stay tied to the first and last inner step,
+  dead or not. A ``score_mask``'s live tiles are several runs a row and
+  under a third of the grid, so its kernels' grid is ``(B, H, live
+  tiles)``: the list of live tiles in walking order (query block, key
+  block, first / last of its row - of its column in dkv) is made from
+  the description when the call is traced and read from SMEM at the grid
+  step (scalar prefetch) by every index map and by the body, which runs
+  ``_init`` / ``_finish`` where the list says; no grid step is spent on
+  a dead tile (:func:`grid_steps`). Its element mask comes from iotas
+  and the description's own arithmetic. No mask or score tensor larger
+  than a tile exists anywhere.
 - "No key mask" is static: with ``key_mask=None`` and no key padding the
   multi-tile kernels are built without the two key-mask selects.
 - Forward also emits the per-row logsumexp; backward recomputes score
@@ -90,6 +94,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -302,8 +307,10 @@ def _tile_class_table(Sq, Sk, bq, bk, causal=False, score_mask=None):
 def tile_classes(Sq, Sk, bq, bk, causal=False, score_mask=None):
     """``(dead, partial, full)`` tile counts of one head's score matrix at
     block sizes (bq, bk) under ``causal=True`` or a ``score_mask``
-    description: the multi-tile kernels skip the dead ones (no compute, no
-    DMA) and run the rest. Static in the shapes."""
+    description: the multi-tile kernels run the partial and the full ones;
+    a dead one costs no compute and no DMA under ``causal`` and is no grid
+    step at all under a description (:func:`grid_steps`). Static in the
+    shapes."""
     if causal == (score_mask is not None):
         raise ValueError("tile_classes counts under causal=True or under "
                          "a score_mask, one of the two")
@@ -312,29 +319,66 @@ def tile_classes(Sq, Sk, bq, bk, causal=False, score_mask=None):
     return dead, table.size - dead - full, full
 
 
+def grid_steps(Sq, Sk, bq, bk, causal=False, score_mask=None):
+    """Grid steps a head that each multi-tile kernel (fwd, dq, dkv)
+    launches at block sizes (bq, bk): every tile of the score matrix under
+    ``causal`` or no mask (a dead causal tile is still a step, which runs
+    no body and fetches nothing), the live tiles alone under a
+    ``score_mask`` description, whose kernels walk a list of them
+    (:func:`tile_classes`' partial + full). Static in the shapes."""
+    if score_mask is None:
+        return -(-Sq // bq) * -(-Sk // bk)
+    if causal:
+        raise ValueError("grid_steps counts under causal=True or under a "
+                         "score_mask, not both")
+    return len(_mask_tables(score_mask, bq, bk)[0].iq)
+
+
+class _TileList(NamedTuple):
+    """One walking order of a description's live tiles: flat int32 arrays
+    with an entry a live tile, which a ``score_mask`` call's kernel and
+    index maps read from SMEM at the grid step ``t`` (scalar prefetch)."""
+
+    iq: np.ndarray      # the step's query block
+    ik: np.ndarray      # the step's key block
+    first: np.ndarray   # 1 where the step opens its row (k-major: column)
+    last: np.ndarray    # 1 where the step closes it
+
+
 @functools.lru_cache(maxsize=None)
 def _mask_tables(score_mask, bq, bk):
-    """The three flat int32 tables a ``score_mask`` call's kernels read
-    from SMEM by ``program_id``: ``live[iq * nk + ik]`` (1 where the tile
-    is not dead), ``fetch_k[iq * nk + ik]`` (the key block the q-major
-    kernels name at that step) and ``fetch_q[ik * nq + iq]`` (the query
-    block the k-major kernel names). A dead step names the block of the
-    last live step before it on its row (its column, k-major), or of the
-    first live step where none came before, so the pipeline sees the block
-    index change only when a live tile needs another block: no DMA is
-    issued for a dead tile."""
+    """``(q_major, k_major)``: the live tiles of a ``score_mask`` call as
+    the two :class:`_TileList` its kernels walk, made from the same
+    classification :func:`tile_classes` counts. q-major (fwd, dq): row by
+    row of query blocks, key blocks ascending within a row; k-major (dkv):
+    column by column of key blocks, query blocks ascending. A row's (a
+    column's) tiles are one run of steps, so its output block is written
+    back once, and they come in the order a walk over every tile would
+    meet them, so the accumulations see the same tiles in the same order.
+    A row or a column with no live tile would never be written: such a
+    description raises."""
     live = _tile_class_table(score_mask.q_len, score_mask.k_len, bq, bk,
                              score_mask=score_mask) != "dead"
+    if not (live.any(axis=1).all() and live.any(axis=0).all()):
+        raise ValueError(
+            f"{score_mask}: a block of {bq} queries or of {bk} keys has no "
+            f"live tile; its output would never be written")
 
-    def fetch(live):                   # along the last axis
-        n = live.shape[-1]
-        index = np.where(live, np.arange(n), -1)
-        before = np.maximum.accumulate(index, axis=-1)
-        first = np.where(live.any(-1), live.argmax(-1), 0)[:, None]
-        return np.where(before >= 0, before, first)
+    def walk(live):
+        outer, inner = np.nonzero(live)         # row-major: outer ascending
+        first = np.append(True, outer[1:] != outer[:-1])
+        return outer, inner, first, np.append(first[1:], True)
 
-    flat = lambda a: np.ascontiguousarray(a, np.int32).reshape(-1)  # noqa: E731
-    return flat(live), flat(fetch(live)), flat(fetch(live.T))
+    def frozen(*arrays):
+        arrays = [np.ascontiguousarray(a, np.int32) for a in arrays]
+        for a in arrays:                        # the cache hands them out
+            a.flags.writeable = False
+        return _TileList(*arrays)
+
+    iq, ik, first, last = walk(live)
+    q_major = frozen(iq, ik, first, last)
+    ik, iq, first, last = walk(live.T)
+    return q_major, frozen(iq, ik, first, last)
 
 
 def _live_k(causal, iq, ik, bq, bk):
@@ -357,13 +401,10 @@ def _live_q(causal, iq, ik, bq, bk, nq):
                      jnp.minimum((ik * bk) // bq, nq - 1), iq)
 
 
-def _on_live_tile(causal, iq, ik, bq, bk, body, live=None):
-    """Run ``body()`` for tile (iq, ik) unless its mask kills it: by the
-    causal predicate, or by ``live``, a ``score_mask`` call's table entry
-    for the tile."""
-    if live is not None:
-        pl.when(live != 0)(body)
-    elif not causal:
+def _on_live_tile(causal, iq, ik, bq, bk, body):
+    """Run ``body()`` for tile (iq, ik) unless the causal mask kills it (a
+    ``score_mask`` call's grid holds no dead tile)."""
+    if not causal:
         body()
     else:
         pl.when(jnp.logical_not(_causal_dead(iq, ik, bq, bk)))(body)
@@ -378,22 +419,44 @@ def _visible_tile(score_mask, iq, ik, bq, bk):
     return score_mask.visible(row, col)
 
 
-def _tabled(kernel):
-    """``kernel`` for a ``score_mask`` call, whose two scalar-prefetched
-    tables come before the operands: the kernel body reads ``live`` (the
-    index maps read the other, the blocks to name)."""
-    def with_tables(live_ref, fetch_ref, *refs):
-        return kernel(*refs, live_ref=live_ref)
-    return with_tables
+def _walk(tiles, k_major=False):
+    """Where a multi-tile kernel's grid step stands: ``(iq, ik, inner
+    steps, first, last)`` - the score tile's block indices, the length of
+    the inner grid axis, and two thunks that say whether the step opens
+    and closes the accumulation of its row of query blocks (``k_major``,
+    the dkv kernel: of its column of key blocks).
+
+    Without ``tiles`` the grid is ``(B, H, outer, inner)`` over every tile
+    and the inner index says; a ``score_mask`` call's grid is ``(B, H,
+    live tiles)`` and its prefetched :class:`_TileList` says (it has no
+    inner axis: None). Thunks, so that each compare is emitted where the
+    kernel asks."""
+    if tiles is None:
+        outer, inner = pl.program_id(2), pl.program_id(3)
+        n = pl.num_programs(3)
+        iq, ik = (inner, outer) if k_major else (outer, inner)
+        return iq, ik, n, lambda: inner == 0, lambda: inner == n - 1
+    t = pl.program_id(2)
+    return (tiles.iq[t], tiles.ik[t], None,
+            lambda: tiles.first[t] != 0, lambda: tiles.last[t] != 0)
 
 
-def _grid(spec, tables):
+def _listed(kernel):
+    """``kernel`` for a ``score_mask`` call, whose scalar-prefetched tile
+    list comes before the operands."""
+    def with_tiles(iq_ref, ik_ref, first_ref, last_ref, *refs):
+        return kernel(*refs,
+                      tiles=_TileList(iq_ref, ik_ref, first_ref, last_ref))
+    return with_tiles
+
+
+def _grid(spec, tiles):
     """``pl.pallas_call``'s grid arguments: as they are, or as a grid with
-    the ``score_mask`` call's tables prefetched into SMEM."""
-    if not tables:
+    the ``score_mask`` call's tile list prefetched into SMEM."""
+    if not tiles:
         return spec
     return {"grid_spec": pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(tables), **spec)}
+        num_scalar_prefetch=len(tiles), **spec)}
 
 
 def _score_tile(q, k, mask_ref, iq, ik, *, scale, causal, bq, bk, has_mask,
@@ -430,16 +493,15 @@ def _zero_padded_keys(p, mrow):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
                 has_mask=True, dropout_rate=0.0, native_prng=True,
-                score_mask=None, live_ref=None):
+                score_mask=None, tiles=None):
     if dropout_rate > 0.0:
         drop_ref, o_ref, lse_ref, acc_s, m_s, l_s = rest
     else:
         drop_ref, (o_ref, lse_ref, acc_s, m_s, l_s) = None, rest
     b, hh = pl.program_id(0), pl.program_id(1)
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    iq, ik, nk, first, last = _walk(tiles)
 
-    @pl.when(ik == 0)
+    @pl.when(first())
     def _init():
         m_s[:] = jnp.full_like(m_s, -1e30)
         l_s[:] = jnp.zeros_like(l_s)
@@ -476,10 +538,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal, bq, bk,
         m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
-    _on_live_tile(causal, iq, ik, bq, bk, _tile,
-                  None if live_ref is None else live_ref[iq * nk + ik])
+    _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last())
     def _finish():
         l = l_s[:, :1]
         safe_l = jnp.where(l > 0, l, 1.0)
@@ -536,16 +597,15 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                    *rest, scale, causal, bq, bk, has_mask=True,
                    dropout_rate=0.0, native_prng=True, score_mask=None,
-                   live_ref=None):
+                   tiles=None):
     if dropout_rate > 0.0:
         drop_ref, dq_ref, dq_s = rest
     else:
         drop_ref, (dq_ref, dq_s) = None, rest
     b, hh = pl.program_id(0), pl.program_id(1)
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    iq, ik, nk, first, last = _walk(tiles)
 
-    @pl.when(ik == 0)
+    @pl.when(first())
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
 
@@ -574,10 +634,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta) * scale              # (bq, bk)
         dq_s[:] = dq_s[:] + _dot(ds.astype(k.dtype), k, ((1,), (0,)), prec)
 
-    _on_live_tile(causal, iq, ik, bq, bk, _tile,
-                  None if live_ref is None else live_ref[iq * nk + ik])
+    _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last())
     def _finish():
         dq_ref[0, 0] = dq_s[:].astype(dq_ref.dtype)
 
@@ -636,16 +695,15 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                     *rest, scale, causal, bq, bk, has_mask=True,
                     dropout_rate=0.0, native_prng=True, score_mask=None,
-                    live_ref=None):
+                    tiles=None):
     if dropout_rate > 0.0:
         drop_ref, dk_ref, dv_ref, dk_s, dv_s = rest
     else:
         drop_ref, (dk_ref, dv_ref, dk_s, dv_s) = None, rest
     b, hh = pl.program_id(0), pl.program_id(1)
-    ik, iq = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
+    iq, ik, nq, first, last = _walk(tiles, k_major=True)
 
-    @pl.when(iq == 0)
+    @pl.when(first())
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
@@ -683,11 +741,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         # dk += ds^T @ q
         dk_s[:] = dk_s[:] + _dot(ds.astype(q.dtype), q, ((0,), (0,)), prec)
 
-    _on_live_tile(causal, iq, ik, bq, bk, _tile,
-                  None if live_ref is None
-                  else live_ref[iq * pl.num_programs(2) + ik])
+    _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(last())
     def _finish():
         dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
@@ -737,21 +793,51 @@ def _sum_groups(dk, k):
     return dk.reshape(B, Hkv, -1, Sk, D).sum(axis=2)
 
 
-def _q_major_maps(causal, bq, bk, kv_head=lambda h: h, tabled_nk=None):
-    """Index maps on the q-major grid ``(b, h, iq, ik)`` of fwd and dq for
-    the operands blocked along keys: (k / v, key mask, interpret-mode
-    dropout bits). Under ``causal`` a dead step keeps the row's last live
-    block (``_live_k``); in a ``score_mask`` call (``tabled_nk``: its key
-    blocks a row) every step names the block its prefetched table gives
-    (``_mask_tables``), which the maps get as their last argument."""
-    def live(iq, ik, *tables):
-        if tabled_nk is not None:
-            return tables[1][iq * tabled_nk + ik]
-        return _live_k(causal, iq, ik, bq, bk)
+class _Maps(NamedTuple):
+    """Index maps of a multi-tile call's operands, by how each is blocked."""
 
-    return (lambda b, h, iq, ik, *t: (b, kv_head(h), live(iq, ik, *t), 0),
-            lambda b, h, iq, ik, *t: (b, 0, live(iq, ik, *t)),
-            lambda b, h, iq, ik, *t: (b, h, iq, live(iq, ik, *t)))
+    q: object         # (B, H, Sq, D) by query block: q, o, do, dq
+    row: object       # (B, H, 1, Sq) by query block: lse, delta
+    kv: object        # (B, Hkv, Sk, D) by key block, through the group
+    dkv: object       # (B, H, Sk, D) by key block: dk, dv a query head
+    key_mask: object  # (B, 1, Sk)
+    bits: object      # (B, H, Sq, Sk): the interpret-mode dropout bits
+
+
+def _index_maps(causal, bq, bk, nq, kv_head, k_major=False, listed=False):
+    """:class:`_Maps` of one multi-tile call. After ``b`` and ``h`` an
+    index map gets the grid step: ``(iq, ik)`` on the q-major grid over
+    every tile (fwd, dq), where a dead causal step keeps the row's last
+    live key block (``_live_k``); ``(ik, iq)`` on the k-major one (dkv),
+    where it keeps the column's first live query block (``_live_q``); or,
+    ``listed``, a ``score_mask`` call's ``t`` and then its prefetched
+    :class:`_TileList`, which names both blocks in either order."""
+    if listed:
+        def q_block(t, iq_ref, ik_ref, first_ref, last_ref):
+            return iq_ref[t]
+
+        def k_block(t, iq_ref, ik_ref, first_ref, last_ref):
+            return ik_ref[t]
+    elif k_major:
+        def q_block(ik, iq):
+            return _live_q(causal, iq, ik, bq, bk, nq)
+
+        def k_block(ik, iq):
+            return ik
+    else:
+        def q_block(iq, ik):
+            return iq
+
+        def k_block(iq, ik):
+            return _live_k(causal, iq, ik, bq, bk)
+
+    return _Maps(
+        q=lambda b, h, *step: (b, h, q_block(*step), 0),
+        row=lambda b, h, *step: (b, h, 0, q_block(*step)),
+        kv=lambda b, h, *step: (b, kv_head(h), k_block(*step), 0),
+        dkv=lambda b, h, *step: (b, h, k_block(*step), 0),
+        key_mask=lambda b, h, *step: (b, 0, k_block(*step)),
+        bits=lambda b, h, *step: (b, h, q_block(*step), k_block(*step)))
 
 
 def _kernel_name(kind, score_mask):
@@ -767,8 +853,8 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
     ``mask`` is all zeros) builds the multi-tile kernel without its two
     key-mask selects; the single-tile kernel ignores it. ``score_mask``
     (static; the lengths it was checked against are the unpadded ones)
-    adds its element mask to the kernels and, past one tile, its two
-    prefetched tables to the call."""
+    adds its element mask to the kernels and, past one tile, makes the
+    grid the list of its live tiles (``_mask_tables``)."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     native = drop_in is not None and drop_in.ndim == 1
@@ -800,45 +886,43 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
             name=_kernel_name("fwd", score_mask),
             interpret=_interpret(),
         )(q, k, v, mask, *extra)
-    nk = Sk // bk
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
                                bk=bk, has_mask=has_mask,
                                dropout_rate=dropout_rate, native_prng=native,
                                score_mask=score_mask)
-    tables = ()
-    if score_mask is not None:
-        live, fetch_k, _ = _mask_tables(score_mask, bq, bk)
-        tables, kernel = (live, fetch_k), _tabled(kernel)
-    kv_map, mask_map, bits_map = _q_major_maps(
-        causal, bq, bk, kv_head, nk if tables else None)
-    extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
+    tiles, steps = (), (Sq // bq, Sk // bk)
+    listed = score_mask is not None
+    if listed:
+        tiles, _ = _mask_tables(score_mask, bq, bk)
+        kernel, steps = _listed(kernel), (len(tiles.iq),)
+    maps = _index_maps(causal, bq, bk, Sq // bq, kv_head, listed=listed)
+    extra, extra_specs = _drop_arg(drop_in, bq, bk, maps.bits)
     out, lse = pl.pallas_call(
         kernel,
         **_grid(dict(
-            grid=(B, H, Sq // bq, nk),
+            grid=(B, H, *steps),
             in_specs=[
-                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
-                _spec4(bk, D, kv_map),
-                _spec4(bk, D, kv_map),
-                pl.BlockSpec((1, 1, bk), mask_map),
+                _spec4(bq, D, maps.q),
+                _spec4(bk, D, maps.kv),
+                _spec4(bk, D, maps.kv),
+                pl.BlockSpec((1, 1, bk), maps.key_mask),
             ] + extra_specs,
             out_specs=(
-                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
-                pl.BlockSpec((1, 1, 1, bq),
-                             lambda b, h, iq, ik, *t: (b, h, 0, iq)),
+                _spec4(bq, D, maps.q),
+                pl.BlockSpec((1, 1, 1, bq), maps.row),
             ),
             scratch_shapes=[
                 pltpu.VMEM((bq, D), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
-            ]), tables),
+            ]), tiles),
         out_shape=(
             out_struct((B, H, Sq, D), q.dtype, q, k, v),
             out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
         ),
         name=_kernel_name("fwd", score_mask),
         interpret=_interpret(),
-    )(*tables, q, k, v, mask, *extra)
+    )(*tiles, q, k, v, mask, *extra)
     return out, lse
 
 
@@ -894,81 +978,62 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
                 score_mask=score_mask)
     dq_kernel = functools.partial(_bwd_dq_kernel, **kern)
     dkv_kernel = functools.partial(_bwd_dkv_kernel, **kern)
-    dq_tables = dkv_tables = ()
-    if score_mask is not None:
-        live, fetch_k, fetch_q = _mask_tables(score_mask, bq, bk)
-        dq_tables, dkv_tables = (live, fetch_k), (live, fetch_q)
-        dq_kernel, dkv_kernel = _tabled(dq_kernel), _tabled(dkv_kernel)
+    dq_tiles, dkv_tiles = (), ()
+    dq_steps, dkv_steps = (nq, nk), (nk, nq)
+    listed = score_mask is not None
+    if listed:
+        dq_tiles, dkv_tiles = _mask_tables(score_mask, bq, bk)
+        dq_kernel, dkv_kernel = _listed(dq_kernel), _listed(dkv_kernel)
+        dq_steps = dkv_steps = (len(dq_tiles.iq),)
 
-    kv_map, mask_map, bits_map = _q_major_maps(
-        causal, bq, bk, kv_head, nk if dq_tables else None)
-    extra, extra_specs = _drop_arg(drop_in, bq, bk, bits_map)
+    def operands(maps):
+        """(inputs past the tile list, their specs): q, k, v, key mask,
+        do, lse, delta and the dropout source."""
+        extra, extra_specs = _drop_arg(drop_in, bq, bk, maps.bits)
+        return (q, k, v, mask, do, lse, delta, *extra), [
+            _spec4(bq, D, maps.q),
+            _spec4(bk, D, maps.kv),
+            _spec4(bk, D, maps.kv),
+            pl.BlockSpec((1, 1, bk), maps.key_mask),
+            _spec4(bq, D, maps.q),
+            pl.BlockSpec((1, 1, 1, bq), maps.row),
+            pl.BlockSpec((1, 1, 1, bq), maps.row),
+        ] + extra_specs
+
+    maps = _index_maps(causal, bq, bk, nq, kv_head, listed=listed)
+    inputs, in_specs = operands(maps)
     dq = pl.pallas_call(
         dq_kernel,
         **_grid(dict(
-            grid=(B, H, nq, nk),
-            in_specs=[
-                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
-                _spec4(bk, D, kv_map),
-                _spec4(bk, D, kv_map),
-                pl.BlockSpec((1, 1, bk), mask_map),
-                _spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
-                pl.BlockSpec((1, 1, 1, bq),
-                             lambda b, h, iq, ik, *t: (b, h, 0, iq)),
-                pl.BlockSpec((1, 1, 1, bq),
-                             lambda b, h, iq, ik, *t: (b, h, 0, iq)),
-            ] + extra_specs,
-            out_specs=_spec4(bq, D, lambda b, h, iq, ik, *t: (b, h, iq, 0)),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]), dq_tables),
+            grid=(B, H, *dq_steps),
+            in_specs=in_specs,
+            out_specs=_spec4(bq, D, maps.q),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]), dq_tiles),
         out_shape=out_struct((B, H, Sq, D), q.dtype, q, k, v, do),
         name=_kernel_name("bwd_dq", score_mask),
         interpret=_interpret(),
-    )(*dq_tables, q, k, v, mask, do, lse, delta, *extra)
+    )(*dq_tiles, *inputs)
 
-    # k-major grid (ik outer, iq inner): a dead step keeps the column's
-    # first live q/do/lse/delta/bits block (causal), or the block the
-    # ``score_mask`` call's table names
-    def live(ik, iq, *tables):
-        if tables:
-            return tables[1][ik * nq + iq]
-        return _live_q(causal, iq, ik, bq, bk, nq)
-
-    def q_map(b, h, ik, iq, *t):
-        return (b, h, live(ik, iq, *t), 0)
-
-    def row_map(b, h, ik, iq, *t):
-        return (b, h, 0, live(ik, iq, *t))
-
-    extra, extra_specs = _drop_arg(
-        drop_in, bq, bk, lambda b, h, ik, iq, *t: (b, h, live(ik, iq, *t), ik))
+    maps = _index_maps(causal, bq, bk, nq, kv_head, k_major=True,
+                       listed=listed)
+    inputs, in_specs = operands(maps)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         **_grid(dict(
-            grid=(B, H, nk, nq),
-            in_specs=[
-                _spec4(bq, D, q_map),
-                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, kv_head(h), ik, 0)),
-                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, kv_head(h), ik, 0)),
-                pl.BlockSpec((1, 1, bk), lambda b, h, ik, iq, *t: (b, 0, ik)),
-                _spec4(bq, D, q_map),
-                pl.BlockSpec((1, 1, 1, bq), row_map),
-                pl.BlockSpec((1, 1, 1, bq), row_map),
-            ] + extra_specs,
-            out_specs=(
-                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, h, ik, 0)),
-                _spec4(bk, D, lambda b, h, ik, iq, *t: (b, h, ik, 0)),
-            ),
+            grid=(B, H, *dkv_steps),
+            in_specs=in_specs,
+            out_specs=(_spec4(bk, D, maps.dkv), _spec4(bk, D, maps.dkv)),
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
                 pltpu.VMEM((bk, D), jnp.float32),
-            ]), dkv_tables),
+            ]), dkv_tiles),
         out_shape=(
             out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
             out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
         ),
         name=_kernel_name("bwd_dkv", score_mask),
         interpret=_interpret(),
-    )(*dkv_tables, q, k, v, mask, do, lse, delta, *extra)
+    )(*dkv_tiles, *inputs)
     return dq, dk, dv
 
 
@@ -1167,11 +1232,12 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
       causal: apply the upper-triangular causal mask in-kernel.
       score_mask: a static description of a mask that is a function of
         (query index, key index), beyond ``causal``
-        (:class:`BlockDiffusionMask`; hashable, not traced). Its dead
-        tiles are skipped as causal's are and its live tiles are masked
-        from iotas; no mask tensor exists. The call's lengths must be the
-        description's. Not together with ``causal``, ``key_mask`` or
-        dropout (nothing needs the combinations; they raise).
+        (:class:`BlockDiffusionMask`; hashable, not traced). Past one
+        tile the kernels walk the list of its live tiles (no grid step on
+        a dead one) and mask them from iotas; no mask tensor exists. The
+        call's lengths must be the description's. Not together with
+        ``causal``, ``key_mask`` or dropout (nothing needs the
+        combinations; they raise).
       scale: softmax temperature (typically ``1/sqrt(D)``).
       dropout_rate: attention-probability dropout, fused in-kernel (the
         reference fmha's Philox dropout; static Python float).
@@ -1190,7 +1256,7 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
 
 def _check_score_mask(score_mask, Sq, Sk, key_mask, causal, dropout_rate):
     """A ``score_mask`` call stands alone: the description holds the whole
-    mask, and its tables are made for the call's own lengths."""
+    mask, and its tile lists are made for the call's own lengths."""
     if score_mask is None:
         return
     score_mask.check(Sq, Sk)
@@ -1198,8 +1264,8 @@ def _check_score_mask(score_mask, Sq, Sk, key_mask, causal, dropout_rate):
         raise ValueError(
             "flash_attention: score_mask describes the whole mask (what it "
             "lets a query see is already behind it); causal=True on top "
-            "would need the product of two tile tables that no model asks "
-            "for")
+            "would need the product of two tile classifications that no "
+            "model asks for")
     if key_mask is not None:
         raise ValueError(
             "flash_attention: score_mask with a key_mask is not built: a "

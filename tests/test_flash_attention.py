@@ -632,10 +632,12 @@ def _dense_blockdiff(L, g, clean_queries=True):
     (512, 4, True),     # 1024 at 512-blocks: 8 live tiles of 16
     (576, 4, False),    # the last layer's call: L queries on 2 L keys
     (512, 32, False),
+    (640, 4, False),    # 768 x 1536 in tiles of 384 x 512: bq != bk
 ])
 def test_block_diffusion_mask_matches_the_dense_mask(L, g, clean_queries):
-    """The block-masked kernels (dead tiles skipped by table, live tiles
-    masked from iotas; 4 query heads on 2 key/value heads): forward and
+    """The block-masked kernels (past one tile a grid over the list of
+    live tiles, which are masked from iotas; 4 query heads on 2 key/value
+    heads): forward and
     the three gradients against composed attention under the mask built
     densely from its definition; ``mha_reference`` takes the same
     description and builds the same mask."""
@@ -675,9 +677,28 @@ def test_block_diffusion_mask_matches_the_dense_mask(L, g, clean_queries):
     ref = jax.grad(scalar(composed), (0, 1, 2))(q, k, v)
     for name, a, b in zip(("dq", "dk", "dv"), got, ref):
         assert _max_err(a, b) < 1e-5, name
-    # the sizes above are one tile and past one tile
+    # the sizes above are one tile and past one tile, square tiles and not
     assert (_block_sizes(mask.q_len, 2 * L) == (mask.q_len, 2 * L)) == (
         L in (64, 192))
+    assert (len(set(_block_sizes(mask.q_len, 2 * L))) == 2) == (L == 640)
+
+
+def _brute_tile_classes(L, g, bq, bk, clean_queries):
+    """``(live, (dead, partial, full))`` of the block-diffusion mask's
+    tiles at (bq, bk), read off the dense mask; padding past the call's
+    lengths is not counted."""
+    Sq, Sk = (2 * L if clean_queries else L), 2 * L
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    dense = np.zeros((nq * bq, nk * bk), bool)
+    dense[:Sq, :Sk] = _dense_blockdiff(L, g, clean_queries)
+    real = np.zeros_like(dense)
+    real[:Sq, :Sk] = True
+    tiles = dense.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    real = real.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    live = tiles.any(axis=(2, 3))
+    full = (tiles | ~real).all(axis=(2, 3)) & live
+    return live, (int((~live).sum()), int((live & ~full).sum()),
+                  int(full.sum()))
 
 
 @pytest.mark.parametrize("L,g,bq,bk,clean_queries,expected", [
@@ -692,41 +713,101 @@ def test_block_diffusion_mask_matches_the_dense_mask(L, g, clean_queries):
 def test_block_diffusion_tile_classes_and_tables(L, g, bq, bk, clean_queries,
                                                  expected):
     """``tile_classes`` under the description against a classification
-    read off the dense mask; the prefetched tables name, on every dead
-    step, the block of the nearest live step of its row (its column), so
-    the block index changes only where a live tile needs another block."""
+    read off the dense mask; the two prefetched tile lists hold every live
+    tile once and no dead one, a row (k-major: a column) as one run of
+    steps in ascending order with its ends flagged, so that every output
+    block is opened, accumulated in the dense walk's order and closed
+    once."""
     from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
                                               _mask_tables, tile_classes)
 
     mask = BlockDiffusionMask(L, g, clean_queries)
     Sq, Sk = mask.q_len, mask.k_len
-    nq, nk = -(-Sq // bq), -(-Sk // bk)
-    dense = np.zeros((nq * bq, nk * bk), bool)
-    dense[:Sq, :Sk] = _dense_blockdiff(L, g, clean_queries)
-    real = np.zeros_like(dense)
-    real[:Sq, :Sk] = True
-    tiles = dense.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
-    real = real.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
-    live = tiles.any(axis=(2, 3))
-    full = (tiles | ~real).all(axis=(2, 3)) & live
-    brute = (int((~live).sum()), int((live & ~full).sum()), int(full.sum()))
+    live, brute = _brute_tile_classes(L, g, bq, bk, clean_queries)
     assert tile_classes(Sq, Sk, bq, bk, score_mask=mask) == brute
     if expected is not None:
         assert brute == expected
     with pytest.raises(ValueError, match="one of the two"):
         tile_classes(Sq, Sk, bq, bk, causal=True, score_mask=mask)
-    live_t, fetch_k, fetch_q = _mask_tables(mask, bq, bk)
-    np.testing.assert_array_equal(live_t.reshape(nq, nk) != 0, live)
-    for table, alive in ((fetch_k.reshape(nq, nk), live),
-                         (fetch_q.reshape(nk, nq), live.T)):
-        for row, ok in zip(table, alive):
-            assert ok.any()                  # every row and column is read
-            assert np.all(ok[row])           # only live blocks are named
-            assert np.all(row[ok] == np.flatnonzero(ok))
-            # fetched blocks in order, each fetched once: no DMA on a
-            # dead step
-            changes = np.flatnonzero(np.diff(row)) + 1
-            assert np.all(ok[changes]) and len(changes) == ok.sum() - 1
+    q_major, k_major = _mask_tables(mask, bq, bk)
+    for tiles, alive, outer, inner in ((q_major, live, "iq", "ik"),
+                                       (k_major, live.T, "ik", "iq")):
+        assert all(a.dtype == np.int32 and a.shape == (live.sum(),)
+                   for a in tiles)
+        outer, inner = getattr(tiles, outer), getattr(tiles, inner)
+        # every live tile once, no dead one: in row-major order
+        want_outer, want_inner = np.nonzero(alive)
+        np.testing.assert_array_equal(outer, want_outer)
+        np.testing.assert_array_equal(inner, want_inner)
+        # every row (column) is there, one run of steps, flagged at its
+        # two ends and nowhere else
+        assert set(outer) == set(range(alive.shape[0]))
+        starts = np.flatnonzero(np.append(True, np.diff(outer) != 0))
+        assert len(starts) == alive.shape[0]
+        np.testing.assert_array_equal(np.flatnonzero(tiles.first), starts)
+        np.testing.assert_array_equal(
+            np.flatnonzero(tiles.last),
+            np.append(starts[1:], len(outer)) - 1)
+        assert set(tiles.first) | set(tiles.last) <= {0, 1}
+
+
+@pytest.mark.parametrize("Sq,Sk,bq,bk,kind,expected", [
+    (1024, 1024, 512, 512, "causal", 4),         # every tile, dead or not
+    (8192, 8192, 512, 512, "causal", 256),
+    (640, 640, 384, 384, "causal", 4),           # 640 -> 768
+    (1024, 512, 512, 512, None, 2),
+    (16384, 16384, 512, 512, (8192, 4, True), 288),    # was 1,024
+    (8192, 16384, 512, 512, (8192, 4, False), 152),    # was 512
+    (640, 640, 384, 384, (320, 1, True), None),  # L straddles a tile
+    (192, 192, 64, 32, (96, 3, True), None),
+    (96, 192, 32, 64, (96, 8, False), None),
+    (640, 1280, 384, 512, (640, 4, False), None),
+])
+def test_grid_steps_are_every_tile_or_the_live_ones(Sq, Sk, bq, bk, kind,
+                                                    expected):
+    """The grid steps a head of each multi-tile kernel: ``nq * nk`` with
+    or without ``causal`` (a dead causal tile is still a step), the live
+    tiles alone under a description."""
+    from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                              grid_steps, tile_classes)
+
+    if not isinstance(kind, tuple):
+        steps = grid_steps(Sq, Sk, bq, bk, causal=kind == "causal")
+        assert steps == -(-Sq // bq) * -(-Sk // bk) == expected
+        return
+    mask = BlockDiffusionMask(*kind)
+    steps = grid_steps(Sq, Sk, bq, bk, score_mask=mask)
+    live, (dead, partial, full) = _brute_tile_classes(kind[0], kind[1], bq,
+                                                      bk, kind[2])
+    assert steps == live.sum() == partial + full
+    assert steps == sum(tile_classes(Sq, Sk, bq, bk, score_mask=mask)[1:])
+    assert expected in (None, steps)
+    with pytest.raises(ValueError, match="not both"):
+        grid_steps(Sq, Sk, bq, bk, causal=True, score_mask=mask)
+
+
+@pytest.mark.parametrize("empty", ["row", "column"])
+def test_a_description_with_an_empty_row_or_column_raises(empty):
+    """A block of queries (of keys) with no live tile would be a block of
+    ``o`` (of ``dk`` / ``dv``) that no grid step writes: the list builder
+    refuses the description."""
+    import dataclasses
+
+    from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                              _mask_tables, grid_steps)
+
+    @dataclasses.dataclass(frozen=True)
+    class Holed(BlockDiffusionMask):
+        def tile_class(self, r0, r1, c0, c1):
+            first = (r0 if empty == "row" else c0) == 0
+            return "dead" if first else super().tile_class(r0, r1, c0, c1)
+
+    assert grid_steps(256, 256, 64, 64, score_mask=BlockDiffusionMask(
+        128, 4)) == 8
+    with pytest.raises(ValueError, match="no live tile"):
+        _mask_tables(Holed(128, 4), 64, 64)
+    with pytest.raises(ValueError, match="no live tile"):
+        grid_steps(256, 256, 64, 64, score_mask=Holed(128, 4))
 
 
 def test_without_a_description_the_kernels_hold_no_mask_operation():
@@ -743,21 +824,38 @@ def test_without_a_description_the_kernels_hold_no_mask_operation():
             return jnp.sum(flash_attention(q, k, v, None, causal, 0.125,
                                            score_mask=score_mask))
 
-        text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
-        return text
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v)
+        return str(jaxpr), _pallas_grids(jaxpr.jaxpr)
 
     for causal in (False, True):
-        text = kernels(causal)
+        text, grids = kernels(causal)
         assert text.count("pallas_call") == 3
         assert "blockdiff" not in text and "shift_right" not in text
-        assert "num_scalar_prefetch=0" in text.replace(" ", "") or \
-            "num_scalar_prefetch" not in text
-    text = kernels(False, BlockDiffusionMask(512, 4))
+        # every tile is a grid step, (B, H, 2, 2), and nothing is
+        # prefetched
+        assert grids == [((1, 2, 2, 2), 0)] * 3, grids
+    text, grids = kernels(False, BlockDiffusionMask(512, 4))
     assert text.count("pallas_call") == 3
     for name in ("flash_blockdiff_fwd", "flash_blockdiff_bwd_dq",
                  "flash_blockdiff_bwd_dkv"):
         assert name in text
     assert "shift_right" in text
+    # the described call walks its 3 live tiles of 4, (B, H, live tiles),
+    # named by the four prefetched lists
+    assert grids == [((1, 2, 3), 4)] * 3, grids
+
+
+def _pallas_grids(jaxpr):
+    """``(grid, scalar-prefetch operands)`` of every ``pallas_call`` in a
+    jaxpr, nested ones too, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            mapping = eqn.params["grid_mapping"]
+            found.append((tuple(mapping.grid), mapping.num_index_operands))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_grids(sub)
+    return found
 
 
 def test_a_description_stands_alone_and_fits_its_call():

@@ -462,7 +462,8 @@ def _cell_step(cell, one_chip):
     """The train step of a benchmark cell compiled from shapes for one
     described chip (call with the kernels steered): ``(builder, reference,
     config, built, paths, live bytes)``, where ``paths(regex)`` gives the
-    ``op_name`` of every instruction whose line matches."""
+    ``op_name`` of every instruction whose line matches (or what another
+    ``want`` captures there)."""
     import re
 
     import chip_smoke
@@ -482,9 +483,9 @@ def _cell_step(cell, one_chip):
     compiled = built.step.lower(built.state, batch).compile()
     lines = compiled.as_text().splitlines()
 
-    def paths(pattern):
+    def paths(pattern, want=r'op_name="([^"]*)"'):
         return [m.group(1) for line in lines if re.search(pattern, line)
-                for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+                for m in [re.search(want, line)] if m]
 
     return (builder, reference, config, built, paths,
             chip_smoke.live_bytes(compiled.memory_analysis()))
@@ -633,6 +634,28 @@ def test_lfm2_step_runs_no_grouped_matmul_no_sort_and_no_flash_twice(
 
 # -- the sixth cell's step: block diffusion over two copies of a row ----------
 
+def _mosaic_grid(body):
+    """``(grid, scalar-prefetch operands)`` of a compiled Pallas kernel,
+    from the ``body`` of its custom call (base64 of the serialized Mosaic
+    module, whose function carries both as attributes)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    context = mlir.make_ir_context()
+    tpu.register_dialect(context)
+    context.allow_unregistered_dialects = True
+    with context:
+        text = ir.Module.parse(base64.b64decode(body)).operation.get_asm()
+    bounds = re.search(r"iteration_bounds = array<i64: ([0-9, ]+)>", text)
+    prefetch = re.search(r"scalar_prefetch = (\d+)", text)
+    return (tuple(int(n) for n in bounds.group(1).split(",")),
+            int(prefetch.group(1)) if prefetch else 0)
+
+
 # what the ``sdar`` step may hold live at 1 x 8,192 tokens read as 16,384
 # positions with every layer's matmul outputs, flash residuals, routing and
 # expert rows kept (12.16 GiB by this compile; PERF.md section 4, PR 36)
@@ -647,13 +670,29 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
     shapes for one described chip: every layer makes ONE block-masked
     flash call over the two copies (forward once, ``dq``, ``dkv``: the
     block keeps ``o`` and ``lse``) and six grouped-matmul calls, none of
-    them and no sort on a recomputed path; no causal flash kernel is in
-    the step; under the chip's memory."""
+    them and no sort on a recomputed path; each flash kernel's grid is (1
+    row, 32 heads, the mask's live tiles: 288 of 1,024, and 152 of 512 in
+    the last layer, whose queries are the noised copy alone) under four
+    prefetched lists; no causal flash kernel is in the step; under the
+    chip's memory."""
+    from apex_tpu.ops.flash_attention import BlockDiffusionMask, grid_steps
+
     cell = "sdar_30b_a3b_chat.bd8192"
     _, _, config, built, paths, live = _cell_step(cell, one_chip)
     assert built.n_params == 456_346_624
-    kernels = paths(r'custom_call_target="tpu_custom_call"')
+    called = r'custom_call_target="tpu_custom_call"'
+    kernels = paths(called)
+    bodies = paths(called, want=r'\\?"body\\?":\s*\\?"([A-Za-z0-9+/=]+)')
+    assert len(bodies) == len(kernels)
+    grids = {p: _mosaic_grid(body) for p, body in zip(kernels, bodies)
+             if "/blockdiff_attention/" in p}
     layers = range(config["num_hidden_layers"])
+    L, heads = 8192, config["num_attention_heads"]
+    live_tiles = [grid_steps((1 + (i < layers[-1])) * L, 2 * L, 512, 512,
+                             score_mask=BlockDiffusionMask(
+                                 L, config["block_length"], i < layers[-1]))
+                  for i in layers]
+    assert live_tiles == [288, 288, 288, 152]
     for i in layers:
         mine = [p for p in kernels if f"/layers_{i}/expert_ffn/" in p]
         assert len(mine) == 6, mine
@@ -665,6 +704,8 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
         assert sorted(p.rsplit("/", 2)[-2] for p in flash) == [
             "flash_blockdiff_bwd_dkv", "flash_blockdiff_bwd_dq",
             "flash_blockdiff_fwd"], flash
+        assert [grids[p] for p in flash] == [
+            ((1, heads, live_tiles[i]), 4)] * 3, [grids[p] for p in flash]
     assert not any("rematted_computation" in p for p in kernels)
     assert not [p for p in kernels
                 if re.search(r"/flash_(fwd|bwd|bwd_dq|bwd_dkv)(/|$)", p)]
