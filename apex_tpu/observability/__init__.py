@@ -156,6 +156,25 @@ def register_train_metrics(registry: MetricsRegistry) -> Dict[str, object]:
             "train_nonfinite_total", "non-finite losses observed"),
         "checkpoints": registry.counter(
             "train_checkpoints_total", "checkpoints saved"),
+        # from the compile record (apex_tpu.profiler): the stage spans of
+        # a dispatch under which anything traced, lowered or compiled
+        "trace": registry.histogram(
+            "train_trace_s",
+            "one jaxpr trace under a TrainLoop dispatch, seconds"),
+        "lower": registry.histogram(
+            "train_lower_s",
+            "one lowering to an MLIR module under a TrainLoop dispatch, "
+            "seconds"),
+        "compile": registry.histogram(
+            "train_compile_s",
+            "one backend compile or persistent-cache read under a "
+            "TrainLoop dispatch, seconds"),
+        "compiles": registry.counter(
+            "train_compiles_total",
+            "backend compiles (or cache reads) under TrainLoop dispatches"),
+        "cache_misses": registry.counter(
+            "train_cache_misses_total",
+            "of those, compiles the persistent cache did not hold"),
     }
 
 

@@ -59,6 +59,7 @@ RECORDER_EVENT_KINDS = (
     "watchdog",             # TrainLoop non-finite-loss watchdog action
     "checkpoint",           # TrainLoop checkpoint saved
     "train_step",           # per-train-step summary (TrainLoop)
+    "compile",              # a program compiled under a TrainLoop dispatch
 )
 
 _KIND_SET = frozenset(RECORDER_EVENT_KINDS)

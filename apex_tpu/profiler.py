@@ -118,7 +118,25 @@ step's draw masked, which are the positions its loss runs over).
 Host annotations (``jax.profiler.TraceAnnotation``, on the host plane
 of the same capture; a flag test when no capture runs):
 ``train_dispatch`` and ``train_fetch`` (:class:`apex_tpu.train.TrainLoop`)
-and ``data_wait`` (the loaders of :mod:`apex_tpu.data.loader`).
+and ``data_wait`` (the loaders of :mod:`apex_tpu.data.loader`);
+``train_init`` and ``train_lower`` (:class:`apex_tpu.train.TrainStep`'s
+``init`` and ``lower``).
+
+The compile record (:func:`compile_record`, one per process, always on)
+holds set-up under the program's own spans. Listeners on JAX's compile
+events give a *stage* span for each ``trace`` (jaxpr), ``lower`` (MLIR
+module; the Pallas kernels' Python lowering runs here) and ``compile``
+(``compile_or_get_cached``: on a persistent-cache hit it is the read, and
+the span says so), timed on ``time.perf_counter``; each nests under the
+stage span it ran inside or the innermost *program* span open on its
+thread: ``train_init``, ``train_lower``, and a ``train_dispatch`` under
+which anything compiled (one that compiled nothing is not kept: a steady
+step adds nothing to the record). A ``TrainStep`` claims its program
+(:data:`TRAIN_STEP_PROGRAM`), so a reader asks
+:meth:`CompileRecord.program_of` and matches no name of its own. The
+record is bounded (the first :attr:`CompileRecord.CAPACITY` spans; later
+ones are counted and dropped) and costs a few listener calls per compile
+(:meth:`CompileRecord.stats`).
 
 A scope is a ``jax.named_scope`` (metadata only: the compiled arithmetic
 is unchanged). Its name holds no ``.`` and no ``/``: a scope's last
@@ -137,6 +155,9 @@ The apex-shaped surface:
 from __future__ import annotations
 
 import contextlib
+import functools
+import sys
+import threading
 import time
 from typing import Optional
 
@@ -251,7 +272,32 @@ MOE_RESIDUALS = (MOE_INPUT, MOE_CHOSEN, MOE_WEIGHTS, MOE_PERM, MOE_INV_PERM,
 TRAIN_DISPATCH = "train_dispatch"
 TRAIN_FETCH = "train_fetch"
 DATA_WAIT = "data_wait"
-ANNOTATIONS = (TRAIN_DISPATCH, TRAIN_FETCH, DATA_WAIT)
+# program spans of the compile record (below), annotations as well
+TRAIN_INIT = "train_init"
+TRAIN_LOWER = "train_lower"
+PROGRAM_SPANS = (TRAIN_INIT, TRAIN_LOWER, TRAIN_DISPATCH)
+ANNOTATIONS = (TRAIN_DISPATCH, TRAIN_FETCH, DATA_WAIT, TRAIN_INIT,
+               TRAIN_LOWER)
+
+# -- the compile record: stages, and the program a TrainStep claims ------------
+TRACE = "trace"
+LOWER = "lower"
+COMPILE = "compile"
+STAGES = (TRACE, LOWER, COMPILE)
+TRAIN_STEP_PROGRAM = "train_step"
+# JAX's own compile events (``jax._src.dispatch``, ``jax._src.compiler``)
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE,
+}
+_CACHE_USED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
 
 
 @contextlib.contextmanager
@@ -331,3 +377,299 @@ class StepTimer:
         self._seen = 0
         self._times.clear()
         self._last = None
+
+
+# -- the compile record ----------------------------------------------------------
+
+class CompileSpan:
+    """One span of the compile record, on ``time.perf_counter``'s clock.
+
+    A *stage* span (``stage`` in :data:`STAGES`) is one of JAX's own
+    compile events: ``fun_name`` is JAX's name of the function traced
+    (``fused_step``) or of the module lowered and compiled
+    (``jit(fused_step)``); a ``compile`` span carries the persistent
+    cache's ``cache`` outcome (``"hit"``, ``"miss"``, or ``"off"`` where
+    the cache was not asked) and, on a hit, the seconds JAX reports for
+    the read (``retrieval_s``) and saved (``saved_s``). A *program* span
+    (``stage`` None) is one of :data:`PROGRAM_SPANS` around set-up the
+    program does; ``end`` is None while it is open. ``parent`` is the
+    ``seq`` of the span it nests in (a stage span: a jitted function
+    traced inside its caller's trace; else the innermost program span
+    open on its thread), ``step`` the :class:`~apex_tpu.train.TrainLoop`
+    step being dispatched, or None."""
+
+    __slots__ = ("seq", "name", "stage", "fun_name", "start", "end",
+                 "parent", "thread", "step", "cache", "retrieval_s",
+                 "saved_s")
+
+    def __init__(self, seq, name, stage, fun_name, start, end, parent,
+                 thread, step):
+        self.seq, self.name, self.stage = seq, name, stage
+        self.fun_name, self.start, self.end = fun_name, start, end
+        self.parent, self.thread, self.step = parent, thread, step
+        self.cache = self.retrieval_s = self.saved_s = None
+
+    @property
+    def seconds(self) -> float:
+        return 0.0 if self.end is None else self.end - self.start
+
+
+class _Frame:
+    """A program span open on a thread. It enters the record once a stage
+    opens under it (or at its end, if always kept); ``kids`` are the
+    stage spans directly under it, closed (None while there are none)."""
+
+    __slots__ = ("name", "start", "step", "up", "span", "kids")
+
+    def __init__(self, name, start, step, up):
+        self.name, self.start, self.step, self.up = name, start, step, up
+        self.span = self.kids = None
+
+
+class _Open:
+    """A stage JAX has begun on a thread; its ``seq`` is taken at the
+    start, so that what nests inside can name its parent."""
+
+    __slots__ = ("seq", "event", "parent", "step")
+
+    def __init__(self, seq, event, parent, step):
+        self.seq, self.event, self.parent, self.step = (seq, event, parent,
+                                                        step)
+
+
+class CompileRecord:
+    """Set-up under the program's own spans: every trace, lower and
+    compile-or-cache-read JAX reports, nested under the span that caused
+    it. One per process (:func:`compile_record`), its listeners on JAX's
+    compile events registered once, on first use. In memory and bounded:
+    the first :attr:`CAPACITY` spans are kept (set-up is what the record
+    is for) and later ones are counted in ``dropped``; a program span
+    sees its own stage spans (``kids``) either way. A dispatch that
+    compiles nothing leaves nothing in the record."""
+
+    CAPACITY = 65536
+
+    def __init__(self):
+        self._spans: list = []
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self._seq = 0
+        self._names = {}           # fun_name -> program label
+        self.dropped = 0
+        self.callbacks = 0         # listener calls, and their own time
+        self.callback_s = 0.0
+        self._installed = False
+
+    # -- JAX's listeners ------------------------------------------------------
+    def install(self) -> "CompileRecord":
+        """Register the listeners (idempotent)."""
+        with self._lock:
+            if not self._installed:
+                jax.monitoring.register_scalar_listener(self._on_start)
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_duration)
+                jax.monitoring.register_event_listener(self._on_event)
+                self._installed = True
+        return self
+
+    def uninstall(self) -> None:
+        with self._lock:
+            if self._installed:
+                jax.monitoring.unregister_scalar_listener(self._on_start)
+                jax.monitoring.unregister_event_duration_listener(
+                    self._on_duration)
+                jax.monitoring.unregister_event_listener(self._on_event)
+                self._installed = False
+
+    def _stack(self) -> list:
+        """This thread's open spans, outermost first."""
+        local = self._local
+        if not hasattr(local, "stack"):
+            local.stack = []
+            local.cache = None     # the open compile's cache outcome
+        return local.stack
+
+    def _seq_of(self, entry) -> int:
+        """``seq`` of an open entry; a program span enters the record
+        now if it is not in it yet (and so do its enclosing ones)."""
+        if isinstance(entry, _Open):
+            return entry.seq
+        if entry.span is None:
+            up = None if entry.up is None else self._seq_of(entry.up)
+            self._seq += 1
+            entry.span = CompileSpan(self._seq, entry.name, None, None,
+                                     entry.start, None, up,
+                                     threading.get_ident(), entry.step)
+            self._append(entry.span)
+        return entry.span.seq
+
+    def _append(self, span) -> None:
+        if len(self._spans) < self.CAPACITY:
+            self._spans.append(span)
+        else:
+            self.dropped += 1
+
+    def _on_start(self, event, value, **_):
+        # JAX reports a stage's start as a scalar (``log_elapsed_time``)
+        t = time.perf_counter()
+        if event in _STAGE_EVENTS:
+            stack = self._stack()
+            up = stack[-1] if stack else None
+            with self._lock:
+                parent = None if up is None else self._seq_of(up)
+                self._seq += 1
+                stack.append(_Open(self._seq, event, parent,
+                                   None if up is None else up.step))
+        self.callbacks += 1
+        self.callback_s += time.perf_counter() - t
+
+    def _on_event(self, event, **_):
+        t = time.perf_counter()
+        if event in (_CACHE_USED, _CACHE_HIT, _CACHE_MISS):
+            local = self._local
+            self._stack()
+            if event == _CACHE_HIT:
+                local.cache = {"cache": "hit"}
+            elif local.cache is None:
+                local.cache = {"cache": "miss"}
+        self.callbacks += 1
+        self.callback_s += time.perf_counter() - t
+
+    def _on_duration(self, event, duration, **kw):
+        end = time.perf_counter()
+        stage = _STAGE_EVENTS.get(event)
+        if stage is not None:
+            self._close(stage, event, str(kw.get("fun_name", "")),
+                        end - float(duration), end)
+        elif event in _CACHE_SECONDS:
+            local = self._local
+            self._stack()
+            if local.cache is not None:
+                local.cache[_CACHE_SECONDS[event]] = float(duration)
+        self.callbacks += 1
+        self.callback_s += time.perf_counter() - end
+
+    def _close(self, stage, event, fun_name, start, end) -> None:
+        stack = self._stack()
+        with self._lock:
+            if stack and isinstance(stack[-1], _Open) \
+                    and stack[-1].event == event:
+                opened = stack.pop()
+                seq, parent, step = opened.seq, opened.parent, opened.step
+            else:               # begun before the listeners were there
+                up = stack[-1] if stack else None
+                parent = None if up is None else self._seq_of(up)
+                step = None if up is None else up.step
+                self._seq += 1
+                seq = self._seq
+            span = CompileSpan(seq, stage, stage, fun_name, start, end,
+                               parent, threading.get_ident(), step)
+            if stage == COMPILE:
+                got, self._local.cache = self._local.cache, None
+                got = got or {"cache": "off"}
+                span.cache = got["cache"]
+                span.retrieval_s = got.get("retrieval_s")
+                span.saved_s = got.get("saved_s")
+            self._append(span)
+        if stack and isinstance(stack[-1], _Frame):
+            frame = stack[-1]
+            if frame.kids is None:
+                frame.kids = []
+            frame.kids.append(span)
+
+    @contextlib.contextmanager
+    def program_span(self, name: str, step: Optional[int] = None,
+                     keep: bool = True):
+        """Open program span ``name`` (an :func:`annotate` region too) on
+        this thread; ``step``: the loop step it dispatches (else its
+        enclosing span's). ``keep=False``: it enters the record only if a
+        stage opens under it. Yields the open frame (``kids``, ``span``)."""
+        stack = self._stack()
+        up = stack[-1] if stack else None
+        frame = _Frame(name, time.perf_counter(),
+                       step if step is not None or up is None else up.step,
+                       up)
+        stack.append(frame)
+        try:
+            with annotate(name):
+                yield frame
+        finally:
+            end = time.perf_counter()
+            stack.remove(frame)
+            if frame.span is not None or keep:
+                with self._lock:
+                    self._seq_of(frame)
+                    frame.span.end = end
+
+    def claim(self, label: str, fn) -> None:
+        """Say that the program ``jax.jit`` traces from ``fn`` is
+        ``label``: its trace spans carry ``fn``'s name as JAX reads it,
+        its lower and compile spans the module's, ``jit(<name>)``."""
+        name = getattr(fn, "__name__", None)
+        while name is None and isinstance(fn, functools.partial):
+            fn = fn.func
+            name = getattr(fn, "__name__", None)
+        if name is not None:
+            self._names[name] = label
+            self._names[f"jit({name})"] = label
+
+    # -- reading ----------------------------------------------------------------
+    def spans(self) -> list:
+        with self._lock:
+            return list(self._spans)
+
+    def program_of(self, span, by_seq) -> Optional[str]:
+        """The label a :meth:`claim` gave the program a stage span
+        belongs to: that of the outermost stage span enclosing it, walked
+        up through ``by_seq`` (``{seq: span}`` of the spans read; ``{}``
+        for a span known to nest in no stage)."""
+        if span.stage is None:
+            return None
+        top = span
+        while top.parent in by_seq and by_seq[top.parent].stage is not None:
+            top = by_seq[top.parent]
+        return self._names.get(top.fun_name)
+
+    def split(self, spans, program: str = TRAIN_STEP_PROGRAM) -> dict:
+        """Seconds by stage of ``program``'s outermost stage spans among
+        ``spans`` (a nested span is in its caller's), and under
+        ``"cache"`` the persistent cache's outcome of each of its
+        compiles."""
+        by_seq = {s.seq: s for s in spans}
+        out = {stage: 0.0 for stage in STAGES}
+        out["cache"] = []
+        for s in spans:
+            if s.stage is None or (s.parent in by_seq and
+                                   by_seq[s.parent].stage is not None):
+                continue
+            if self.program_of(s, by_seq) == program:
+                out[s.stage] += s.seconds
+                if s.stage == COMPILE:
+                    out["cache"].append(s.cache)
+        return out
+
+    def stats(self) -> dict:
+        """What the record holds and what it cost: spans kept, dropped,
+        the listener calls and their seconds, and the bytes held (the
+        spans, their times and the list; names are JAX's own strings)."""
+        spans = self.spans()
+        held = sys.getsizeof(spans) + sum(
+            sys.getsizeof(s) + sum(sys.getsizeof(getattr(s, k)) for k in
+                                   ("start", "end", "retrieval_s", "saved_s")
+                                   if getattr(s, k) is not None)
+            for s in spans)
+        return {"spans": len(spans), "dropped": self.dropped,
+                "callbacks": self.callbacks,
+                "callback_s": self.callback_s, "bytes": held}
+
+
+_RECORD: Optional[CompileRecord] = None
+
+
+def compile_record() -> CompileRecord:
+    """The process's :class:`CompileRecord`, its listeners registered on
+    the first call."""
+    global _RECORD
+    if _RECORD is None:
+        _RECORD = CompileRecord().install()
+    return _RECORD

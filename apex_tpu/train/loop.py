@@ -157,6 +157,8 @@ class TrainLoop:
         self._watchdog_halts = 0
         self._checkpoints_saved = 0
         self._last_checkpoint_step: Optional[int] = None
+        self._record = profiler.compile_record()
+        self._step_compiles: Dict[int, int] = {}   # loop step -> compiles
         # metrics collected by the current/last run(), INCLUDING the
         # finally-drained last step when run() unwinds on an exception
         self.last_run_metrics: List[Dict[str, Any]] = []
@@ -181,13 +183,17 @@ class TrainLoop:
                            attempt=attempt)
                 obs.inc("retries")
 
-        with profiler.annotate(profiler.TRAIN_DISPATCH):
+        n = self._steps_dispatched + 1
+        with self._record.program_span(profiler.TRAIN_DISPATCH, step=n,
+                                       keep=False) as dispatch:
             (new_state, metrics), nan_hit = guarded_call(
                 self._train_step, self.state, batch, plan=self._faults,
                 site="train_step", retries=self._max_retries,
                 backoff_s=self._retry_backoff_s, on_retry=count)
         self.state = new_state
-        self._steps_dispatched += 1
+        self._steps_dispatched = n
+        if dispatch.kids is not None:      # something compiled: rare
+            self._note_compiles(dispatch)
         if nan_hit:
             # the injected silent failure: the step ran, its loss is
             # garbage — exactly what the watchdog exists to catch
@@ -264,6 +270,26 @@ class TrainLoop:
             if m is not None:
                 out.append(m)
         return out
+
+    def _note_compiles(self, dispatch) -> None:
+        """A dispatch under which JAX traced, lowered or compiled: count
+        its compiles at that loop step and tell the observer (a histogram
+        per stage, the compile and cache-miss counters, and a ``compile``
+        recorder event each)."""
+        compiles = [s for s in dispatch.kids if s.stage == profiler.COMPILE]
+        if compiles:
+            self._step_compiles[dispatch.step] = len(compiles)
+        obs = self._obs
+        if obs is None:
+            return
+        for s in dispatch.kids:
+            obs.observe(s.stage, s.seconds)
+        obs.inc("compiles", len(compiles))
+        obs.inc("cache_misses", sum(s.cache != "hit" for s in compiles))
+        for s in compiles:
+            obs.record("compile", step=dispatch.step, fun_name=s.fun_name,
+                       seconds=s.seconds, cache=s.cache,
+                       program=self._record.program_of(s, {}))
 
     # -- the non-finite-loss watchdog --------------------------------------
 
@@ -371,6 +397,7 @@ class TrainLoop:
             "watchdog_halts": self._watchdog_halts,
             "checkpoints_saved": self._checkpoints_saved,
             "last_checkpoint_step": self._last_checkpoint_step,
+            "step_compiles": dict(self._step_compiles),
         }
         if deep and self._obs is not None:
             out["observability"] = self._obs.deep_stats()

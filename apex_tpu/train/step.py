@@ -514,6 +514,9 @@ class TrainStep:
             )
         self._jitted = (jax.jit(fn, donate_argnums=(0,)) if donate
                         else jax.jit(fn))
+        # the compile record files this program's trace, lower and
+        # compile spans under ``profiler.TRAIN_STEP_PROGRAM``
+        profiler.compile_record().claim(profiler.TRAIN_STEP_PROGRAM, fn)
 
     def init(self, params, scaler_state: Optional[ScalerState] = None
              ) -> TrainState:
@@ -521,22 +524,24 @@ class TrainStep:
         initial scale — or carry in a checkpointed ``scaler_state``).
         On the GSPMD path the params are committed to their mesh layout
         first and the whole state comes back committed (stable jit
-        cache keys; pass uncommitted host params freely)."""
-        if self._plan is not None:
-            from apex_tpu.serving.mesh import shard_params
+        cache keys; pass uncommitted host params freely). A
+        ``train_init`` program span of the compile record."""
+        with profiler.compile_record().program_span(profiler.TRAIN_INIT):
+            if self._plan is not None:
+                from apex_tpu.serving.mesh import shard_params
 
-            params = shard_params(self._mesh, params,
-                                  pspec_fn=self._plan.pspec_fn)
-        state = TrainState(
-            step=jnp.zeros((), jnp.int32),
-            params=params,
-            opt_state=self._core.optimizer.init(params),
-            scaler_state=(self._core.scaler.init() if scaler_state is None
-                          else scaler_state),
-        )
-        if self._plan is not None:
-            state = self._plan.commit_state(state)
-        return state
+                params = shard_params(self._mesh, params,
+                                      pspec_fn=self._plan.pspec_fn)
+            state = TrainState(
+                step=jnp.zeros((), jnp.int32),
+                params=params,
+                opt_state=self._core.optimizer.init(params),
+                scaler_state=(self._core.scaler.init()
+                              if scaler_state is None else scaler_state),
+            )
+            if self._plan is not None:
+                state = self._plan.commit_state(state)
+            return state
 
     def step(self, state: TrainState, batch):
         _check_batch(batch, self.accum_steps)
@@ -550,9 +555,12 @@ class TrainStep:
         """AOT-lower the jitted step for ``state``/``batch`` (arrays or
         sharded ``ShapeDtypeStruct``s): nothing is dispatched and a
         donating step's state is not consumed. ``.compile()`` on the
-        result gives the program's text and memory analysis."""
+        result gives the program's text and memory analysis. A
+        ``train_lower`` program span of the compile record (the trace and
+        the lowering nest under it; ``.compile()`` is the caller's)."""
         _check_batch(batch, self.accum_steps)
-        return self._jitted.lower(state, batch)
+        with profiler.compile_record().program_span(profiler.TRAIN_LOWER):
+            return self._jitted.lower(state, batch)
 
     @property
     def program(self):

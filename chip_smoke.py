@@ -441,14 +441,21 @@ def phase_train(cfg=None, batch: int = BATCH, seq: int = SEQ,
     log(f"  {run.n_params / 1e6:.1f}M params; state built in "
         f"{time.perf_counter() - t0:.1f} s")
 
-    # the compiled program, ahead of the first step: kernels, memory
-    t0 = time.perf_counter()
+    # the compiled program, ahead of the first step: kernels, memory; the
+    # program's compile record splits its set-up by stage
+    from apex_tpu import profiler
+
+    record = profiler.compile_record()
+    seen = len(record.spans())
     compiled = run.step.lower(run.state, run.batch).compile()
-    compile_s = time.perf_counter() - t0
+    split = record.split(record.spans()[seen:])
     text = compiled.as_text()
     n_kernels = text.count("tpu_custom_call")
     mem = compiled.memory_analysis()
-    log(f"  compile (lower + compile, AOT): {compile_s:.1f} s; "
+    log(f"  the step ahead of time, by the compile record: trace "
+        f"{split['trace']:.1f} s, lower {split['lower']:.1f} s, compile "
+        f"{split['compile']:.1f} s (persistent cache: "
+        f"{', '.join(split['cache']) or 'not asked'}); "
         f"tpu_custom_call x{n_kernels}; program memory: arguments "
         f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, aliased "
         f"{mem.alias_size_in_bytes / 2**30:.2f}, temp "
