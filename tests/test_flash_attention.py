@@ -879,3 +879,145 @@ def test_a_description_stands_alone_and_fits_its_call():
     with pytest.raises(TypeError):       # the _bsh layout takes none
         flash_attention_bsh(q[:, 0], k[:, 0], v[:, 0], None, 1, False, 1.0,
                             0.0, None, mask)
+
+
+# ------------------------------ the multi-tile forward's row sub-blocks
+
+def _forward_kernel_jaxpr(jaxpr):
+    """The jaxpr of the first forward kernel (``flash_fwd`` or
+    ``flash_<tag>_fwd``) in a jaxpr, nested ones searched too."""
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"].endswith("_fwd")):
+            return eqn.params["jaxpr"]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _forward_kernel_jaxpr(sub)
+            if found is not None:
+                return found
+    return None
+
+
+def _ops_in_order(jaxpr, names):
+    """Primitive names of ``jaxpr`` among ``names``, nested jaxprs (the
+    live-tile ``cond``) in place, in program order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in names:
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _ops_in_order(sub, names)
+    return found
+
+
+def _split_rows(monkeypatch, rows):
+    """Make the multi-tile forward split a tile step's query rows into
+    sub-blocks of ``rows`` (all of them where ``rows`` does not divide the
+    block): patched, not an option of the program. Returns a jit that is
+    traced anew under the patch."""
+    import importlib
+
+    mod = importlib.import_module("apex_tpu.ops.flash_attention")
+    monkeypatch.setattr(mod, "_FWD_ROWS", rows)
+    return lambda f: jax.jit(lambda *args: f(*args))
+
+
+def _traced(f, *args):
+    """``f``'s jaxpr, traced anew (a jaxpr is cached by function)."""
+    return jax.make_jaxpr(lambda *a: f(*a))(*args).jaxpr
+
+
+_SPLIT_CASES = {
+    # causal at 512-blocks with dropout and a key mask: the sub-blocks'
+    # row offsets, the slices of the tile's one keep mask
+    "causal_dropout_mask": dict(Sq=1024, Sk=1024, causal=True, rate=0.1,
+                                key_mask=True, dtype=jnp.float32),
+    # 640 pads to 768 at 384-blocks: three sub-blocks of 128
+    "causal_384": dict(Sq=640, Sk=640, causal=True, rate=0.0,
+                       key_mask=False, dtype=jnp.bfloat16),
+    # no mask function past one tile, Sq > Sk, 2 query heads a group
+    "plain_gqa": dict(Sq=1024, Sk=512, causal=False, rate=0.0,
+                      key_mask=False, dtype=jnp.bfloat16, Hkv=1),
+    # the block-diffusion mask: every row of a sub-block keeps its own
+    # noised / clean arithmetic; and the last layer's noised queries
+    "blockdiff": dict(L=512, g=4, clean_queries=True, dtype=jnp.bfloat16),
+    "blockdiff_noised": dict(L=512, g=4, clean_queries=False,
+                             dtype=jnp.float32),
+}
+
+
+def _split_case(name):
+    """``(args, f)``: inputs and a function of them that gives the
+    forward's output and every gradient (and lse where the entry has
+    one)."""
+    from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                              flash_attention_with_lse)
+
+    c = _SPLIT_CASES[name]
+    H, D, dtype = 2, 64, c["dtype"]
+    if "L" in c:
+        mask = BlockDiffusionMask(c["L"], c["g"], c["clean_queries"])
+        Sq, Sk, Hkv = mask.q_len, mask.k_len, 1
+    else:
+        mask, Sq, Sk, Hkv = None, c["Sq"], c["Sk"], c.get("Hkv", H)
+    ks = jax.random.split(jax.random.PRNGKey(Sq + Sk), 4)
+    q = jax.random.normal(ks[0], (1, H, Sq, D), dtype)
+    k = jax.random.normal(ks[1], (1, Hkv, Sk, D), dtype)
+    v = jax.random.normal(ks[2], (1, Hkv, Sk, D), dtype)
+    g = jax.random.normal(ks[3], q.shape, dtype)
+    if mask is not None:
+        def f(q, k, v):
+            out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+                q, k, v, None, False, 0.125, score_mask=mask), q, k, v)
+            return (out,) + vjp(g)
+        return (q, k, v), f
+    km = (jnp.zeros((1, Sk), bool).at[:, 7].set(True) if c["key_mask"]
+          else None)
+    seed = 29 if c["rate"] else None
+
+    def f(q, k, v):
+        (out, lse), vjp = jax.vjp(lambda q, k, v: flash_attention_with_lse(
+            q, k, v, km, c["causal"], 0.125, c["rate"], seed), q, k, v)
+        return (out, lse) + vjp((g, jnp.cos(lse)))
+    return (q, k, v), f
+
+
+@pytest.mark.parametrize("rows", [256, 128])
+@pytest.mark.parametrize("name", sorted(_SPLIT_CASES))
+def test_forward_row_sub_blocks_are_bit_identical_to_one_block(
+        monkeypatch, name, rows):
+    """A tile step's query rows in sub-blocks of 256 or 128 give the
+    output, lse and the three gradients of the unsplit step (sub-blocks of
+    all ``bq`` rows: the parent's kernel) in every bit: the running
+    statistics are per row, so no row's arithmetic moves. Each forward
+    kernel is checked to hold the split it was asked for (two matmuls a
+    sub-block)."""
+    from apex_tpu.ops.flash_attention import _block_sizes
+
+    args, f = _split_case(name)
+    parent = _split_rows(monkeypatch, 1 << 20)(f)(*args)
+    whole = _forward_kernel_jaxpr(_traced(f, *args))
+    split = _split_rows(monkeypatch, rows)(f)(*args)
+    parts = _forward_kernel_jaxpr(_traced(f, *args))
+    bq, _ = _block_sizes(args[0].shape[2], args[1].shape[2])
+    assert _ops_in_order(whole, {"dot_general"}).count("dot_general") == 2
+    assert _ops_in_order(parts, {"dot_general"}).count("dot_general") == (
+        2 * (bq // rows if bq % rows == 0 else 1))
+    for i, (a, b) in enumerate(zip(split, parent)):
+        assert a.dtype == b.dtype, i
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)),
+                                      err_msg=f"{name}: output {i}")
+
+
+def test_each_sub_block_is_its_own_chain_of_matmul_softmax_matmul(
+        monkeypatch):
+    """What the tile step's program gives the scheduler: four sub-blocks
+    of 128 rows in a 512-row tile, each its own q k^T, softmax (its two
+    ``exp``) and p v, sharing no value with another - the chains it may
+    interleave. (Issuing the next sub-block's q k^T before this one's
+    softmax measured slower on the v5e: PERF.md section 6, PR 39.)"""
+    args, f = _split_case("causal_dropout_mask")
+    _split_rows(monkeypatch, 128)
+    kernel = _forward_kernel_jaxpr(_traced(f, *args))
+    got = _ops_in_order(kernel, {"dot_general", "exp"})
+    assert got == ["dot_general", "exp", "exp", "dot_general"] * 4, got

@@ -634,12 +634,10 @@ def test_lfm2_step_runs_no_grouped_matmul_no_sort_and_no_flash_twice(
 
 # -- the sixth cell's step: block diffusion over two copies of a row ----------
 
-def _mosaic_grid(body):
-    """``(grid, scalar-prefetch operands)`` of a compiled Pallas kernel,
-    from the ``body`` of its custom call (base64 of the serialized Mosaic
-    module, whose function carries both as attributes)."""
+def _mosaic_text(body):
+    """The Mosaic module of a compiled Pallas kernel as text, from the
+    ``body`` of its custom call (base64 of the serialized module)."""
     import base64
-    import re
 
     from jax._src.interpreters import mlir
     from jax._src.lib import tpu
@@ -649,7 +647,15 @@ def _mosaic_grid(body):
     tpu.register_dialect(context)
     context.allow_unregistered_dialects = True
     with context:
-        text = ir.Module.parse(base64.b64decode(body)).operation.get_asm()
+        return ir.Module.parse(base64.b64decode(body)).operation.get_asm()
+
+
+def _mosaic_grid(body):
+    """``(grid, scalar-prefetch operands)`` of a compiled Pallas kernel,
+    from its body (whose function carries both as attributes)."""
+    import re
+
+    text = _mosaic_text(body)
     bounds = re.search(r"iteration_bounds = array<i64: ([0-9, ]+)>", text)
     prefetch = re.search(r"scalar_prefetch = (\d+)", text)
     return (tuple(int(n) for n in bounds.group(1).split(",")),
@@ -673,8 +679,9 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
     them and no sort on a recomputed path; each flash kernel's grid is (1
     row, 32 heads, the mask's live tiles: 288 of 1,024, and 152 of 512 in
     the last layer, whose queries are the noised copy alone) under four
-    prefetched lists; no causal flash kernel is in the step; under the
-    chip's memory."""
+    prefetched lists, the forward's tile step in four sub-blocks of 128
+    query rows; no causal flash kernel is in the step; under the chip's
+    memory."""
     from apex_tpu.ops.flash_attention import BlockDiffusionMask, grid_steps
 
     cell = "sdar_30b_a3b_chat.bd8192"
@@ -686,6 +693,9 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
     assert len(bodies) == len(kernels)
     grids = {p: _mosaic_grid(body) for p, body in zip(kernels, bodies)
              if "/blockdiff_attention/" in p}
+    matmuls = {p: _mosaic_text(body).count("tpu.matmul")
+               for p, body in zip(kernels, bodies)
+               if "/blockdiff_attention/" in p}
     layers = range(config["num_hidden_layers"])
     L, heads = 8192, config["num_attention_heads"]
     live_tiles = [grid_steps((1 + (i < layers[-1])) * L, 2 * L, 512, 512,
@@ -706,6 +716,11 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
             "flash_blockdiff_fwd"], flash
         assert [grids[p] for p in flash] == [
             ((1, heads, live_tiles[i]), 4)] * 3, [grids[p] for p in flash]
+        # the forward's tile step in four sub-blocks of 128 query rows,
+        # two matmuls each (PR 39); dq's three and dkv's four as before
+        assert sorted((p.rsplit("/", 2)[-2], matmuls[p]) for p in flash) == [
+            ("flash_blockdiff_bwd_dkv", 4), ("flash_blockdiff_bwd_dq", 3),
+            ("flash_blockdiff_fwd", 8)], flash
     assert not any("rematted_computation" in p for p in kernels)
     assert not [p for p in kernels
                 if re.search(r"/flash_(fwd|bwd|bwd_dq|bwd_dkv)(/|$)", p)]
