@@ -24,13 +24,15 @@ TPU design notes:
   specification for every row with at least one visible key.
 - Attention past one tile skips the tiles its mask kills. A mask that is
   a static function of (query index, key index) - ``causal`` (``row >=
-  col``) or a ``score_mask`` description (:class:`BlockDiffusionMask`) -
-  makes each score tile (iq, ik) of the multi-tile kernels (fwd, dq, dkv)
-  dead (every pair masked), full (none) or partly masked;
-  :func:`tile_classes` counts them for either kind of mask (causal S=1024
-  at 512-blocks: dead 1, partial 2, full 1; 2048: (6, 4, 6); 640 -> 768
-  at 384-blocks: (1, 2, 1); block diffusion over two copies of L=8192 in
-  blocks of 4: 736 dead, 48 partial, 240 full of 1,024). A dead tile
+  col``) or a ``score_mask`` description (:class:`BlockDiffusionMask`,
+  :class:`SlidingWindowMask`) - makes each score tile (iq, ik) of the
+  multi-tile kernels (fwd, dq, dkv) dead (every pair masked), full (none)
+  or partly masked; :func:`tile_classes` counts them for any kind of mask
+  (causal S=1024 at 512-blocks: dead 1, partial 2, full 1; 2048: (6, 4,
+  6); 640 -> 768 at 384-blocks: (1, 2, 1); block diffusion over two
+  copies of L=8192 in blocks of 4: 736 dead, 48 partial, 240 full of
+  1,024; a window of 2,048 over S=8192: 186 dead, 28 partial, 42 full of
+  256). A dead tile
   contributed exactly 0 (``p = exp(FILL - m) = 0`` in fp32), so outputs,
   lse and gradients are bit-identical to the unskipped kernels'. Full
   tiles run the partly masked tiles' body: a maskless second body
@@ -273,6 +275,60 @@ class BlockDiffusionMask:
             raise ValueError(
                 f"{self}: the call has {Sq} queries and {Sk} keys, the "
                 f"description {self.q_len} and {self.k_len}")
+
+
+@dataclasses.dataclass(frozen=True)
+class SlidingWindowMask:
+    """Static description of the sliding-window causal mask over one
+    sequence of ``seq_len`` tokens (queries and keys alike): the query at
+    ``r`` sees the key at ``c`` iff ``0 <= r - c < window``, itself and
+    the ``window - 1`` keys before it (transformers' sliding-window causal
+    mask). A window of ``seq_len`` or more is the causal mask.
+
+    The visible pairs lie in a band of the score matrix, so a tile's class
+    is a closed form in its least and greatest ``r - c`` (``r0 - c1`` and
+    ``r1 - c0``): dead where that range misses ``[0, window)``, full where
+    it lies inside. At ``seq_len`` 8,192, ``window`` 2,048 and tiles of 512
+    a head has 70 live tiles of 256.
+
+    Hashable, so it rides through ``jax.custom_vjp`` as a static argument.
+    ``tag`` names the kernels of such a call (``flash_window_fwd`` ...)."""
+
+    seq_len: int
+    window: int
+    tag = "window"
+
+    def __post_init__(self):
+        if self.seq_len < 1 or self.window < 1:
+            raise ValueError(
+                f"SlidingWindowMask: seq_len ({self.seq_len}) and window "
+                f"({self.window}) must be positive")
+
+    @property
+    def q_len(self) -> int:
+        return self.seq_len
+
+    @property
+    def k_len(self) -> int:
+        return self.seq_len
+
+    def visible(self, row, col):
+        """Boolean ``row sees col`` on absolute indices (int arrays that
+        broadcast against each other; numpy or traced): two compares of
+        ``row - col``, joined by and."""
+        d = row - col
+        return (d >= 0) & (d < self.window)
+
+    def tile_class(self, r0, r1, c0, c1) -> str:
+        """``"dead"``, ``"full"`` or ``"partial"``: whether no, every or
+        some pair of the rows ``r0 .. r1`` and columns ``c0 .. c1``
+        (inclusive) is visible. Python ints."""
+        lo, hi = r0 - c1, r1 - c0
+        if hi < 0 or lo >= self.window:
+            return "dead"
+        return "full" if lo >= 0 and hi < self.window else "partial"
+
+    check = BlockDiffusionMask.check
 
 
 def _div(x, g):
@@ -1270,7 +1326,8 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
       causal: apply the upper-triangular causal mask in-kernel.
       score_mask: a static description of a mask that is a function of
         (query index, key index), beyond ``causal``
-        (:class:`BlockDiffusionMask`; hashable, not traced). Past one
+        (:class:`BlockDiffusionMask`, :class:`SlidingWindowMask`;
+        hashable, not traced). Past one
         tile the kernels walk the list of its live tiles (no grid step on
         a dead one) and mask them from iotas; no mask tensor exists. The
         call's lengths must be the description's. Not together with

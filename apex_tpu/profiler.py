@@ -70,14 +70,24 @@ blockdiff_attention q/k/v projections, q/k norm, rotary,      models/sdar.py
                     projection
 diffusion_loss      the masked positions' cross-entropy,      models/sdar.py
                     weighed by 1 / p (inside ``lm_loss``)
+window_attention    a window layer's attention: q/k/v/gate    models/afmoe.py
+                    projections, q/k norm, rotary, window-
+                    masked grouped-query flash, gate, output
+                    projection
+global_attention    a global layer's attention: the same      models/afmoe.py
+                    with no position term and causal flash
+attn_gate           the output gate ``o * sigmoid(g)``        models/afmoe.py
 =================== ======================================== ==========================
 
 The model scopes from ``ssm_in_proj`` down sit INSIDE ``train_fwd_bwd`` (a
 phase reader files their ops by that ancestor); ``attn_qk_norm`` and
-``attn_rope`` sit inside ``gqa_attention`` (``models/lfm2.py``) or
-``blockdiff_attention`` (``models/sdar.py``) besides. ``models/lfm2.py``
-and ``models/sdar.py`` reuse ``lm_head`` / ``lm_loss`` and the ``moe_*``
-scopes of the expert layer they share with ``models/nemotron_h.py``.
+``attn_rope`` sit inside ``gqa_attention`` (``models/lfm2.py``),
+``blockdiff_attention`` (``models/sdar.py``) or ``window_attention`` /
+``global_attention`` (``models/afmoe.py``, which puts ``attn_gate`` inside
+both) besides. ``models/lfm2.py``, ``models/sdar.py`` and
+``models/afmoe.py`` reuse ``lm_head`` / ``lm_loss`` and the ``moe_*``
+scopes of the expert layer they share with ``models/nemotron_h.py``
+(``models/afmoe.py`` ``moe_shared`` and ``mlp_dense`` too).
 
 Pallas kernels carry a stable ``name=`` that says kernel and direction,
 never the caller (:data:`KERNEL_NAMES`); the name becomes the HLO
@@ -88,7 +98,8 @@ instruction's name and so the device event's: ``flash_fwd``,
 under a ``score_mask`` description carries the description's tag, so that
 a trace tells it from a causal call: ``flash_blockdiff_fwd``,
 ``flash_blockdiff_bwd``, ``flash_blockdiff_bwd_dq``,
-``flash_blockdiff_bwd_dkv``. The dropless
+``flash_blockdiff_bwd_dkv`` and ``flash_window_fwd``, ``flash_window_bwd``,
+``flash_window_bwd_dq``, ``flash_window_bwd_dkv``. The dropless
 expert layer runs the grouped-matmul kernels that ship with JAX
 (``jax.experimental.pallas.ops.tpu.megablox``),
 which name themselves: ``gmm`` (forward and the rows' gradient) and
@@ -105,8 +116,9 @@ block's input (:data:`MOE_RESIDUALS`): the routing - ``moe_chosen``,
 ``moe_perm`` with ``moe_weights`` in its order, ``moe_inv_perm``,
 ``moe_group_sizes`` - and the rows the routing ordered, ``moe_hidden`` (the
 up projection's output); the expert mixer's ``moe_input`` (the normed
-tokens) goes with them. Rows are kept only together with the routing that
-ordered them.
+tokens) goes with them, and ``moe_output`` (the layer's output, where a
+norm's backward pass reads it: ``models/afmoe.py``) too. Rows are kept only
+together with the routing that ordered them.
 
 A model may report step counters beside its loss
 (``build_train_step(has_aux=True)``; they arrive with the loss in
@@ -206,6 +218,9 @@ MLP_DENSE = "mlp_dense"
 DIFFUSION_NOISE = "diffusion_noise"
 BLOCKDIFF_ATTENTION = "blockdiff_attention"
 DIFFUSION_LOSS = "diffusion_loss"
+WINDOW_ATTENTION = "window_attention"
+GLOBAL_ATTENTION = "global_attention"
+ATTN_GATE = "attn_gate"
 
 STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
                AMP_SCALE_LOSS, AMP_UNSCALE, AMP_FOUND_INF, AMP_UPDATE_SCALE,
@@ -220,7 +235,8 @@ LAYER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_OUT, MOE_ROUTER,
                 MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE,
                 GQA_ATTENTION, ATTN_QK_NORM, ATTN_ROPE, CONV_IN_PROJ,
                 CONV_GATE, CONV_OUT_PROJ, MLP_DENSE, DIFFUSION_NOISE,
-                BLOCKDIFF_ATTENTION, DIFFUSION_LOSS)
+                BLOCKDIFF_ATTENTION, DIFFUSION_LOSS, WINDOW_ATTENTION,
+                GLOBAL_ATTENTION, ATTN_GATE)
 SCOPES = STEP_SCOPES + OPTIMIZER_SCOPES + DDP_SCOPES + MODEL_SCOPES
 
 # -- step metrics a model reports beside its loss (``has_aux``) ----------------
@@ -239,7 +255,9 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "softmax_bwd", "dropout_apply", "dropout_mask",
                 "short_conv_fwd", "short_conv_bwd", "flash_blockdiff_fwd",
                 "flash_blockdiff_bwd", "flash_blockdiff_bwd_dq",
-                "flash_blockdiff_bwd_dkv")
+                "flash_blockdiff_bwd_dkv", "flash_window_fwd",
+                "flash_window_bwd", "flash_window_bwd_dq",
+                "flash_window_bwd_dkv")
 
 # kernels of a library the train path calls (named by the library)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
@@ -265,8 +283,11 @@ MOE_PERM = "moe_perm"
 MOE_INV_PERM = "moe_inv_perm"
 MOE_GROUP_SIZES = "moe_group_sizes"
 MOE_HIDDEN = "moe_hidden"
+# a sparse layer's output that a norm follows (``models/afmoe.py``): kept,
+# or the norm's backward pass would run the down projection again
+MOE_OUTPUT = "moe_output"
 MOE_RESIDUALS = (MOE_INPUT, MOE_CHOSEN, MOE_WEIGHTS, MOE_PERM, MOE_INV_PERM,
-                 MOE_GROUP_SIZES, MOE_HIDDEN)
+                 MOE_GROUP_SIZES, MOE_HIDDEN, MOE_OUTPUT)
 
 # -- host annotations ----------------------------------------------------------
 TRAIN_DISPATCH = "train_dispatch"

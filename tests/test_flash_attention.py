@@ -881,6 +881,119 @@ def test_a_description_stands_alone_and_fits_its_call():
                             0.0, None, mask)
 
 
+# ------------------------------------- a mask by function: a sliding window
+
+def _dense_window(S, W):
+    """The sliding-window causal mask written out from its definition:
+    the query at ``i`` sees the key at ``j`` iff ``0 <= i - j < W``."""
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    return (i >= j) & (i - j < W)
+
+
+@pytest.mark.parametrize("S,W", [
+    (128, 40),       # one tile
+    (1024, 300),     # 512-blocks, a window no multiple of the tile
+    (640, 200),      # 640 -> 768 at 384-blocks: padded keys and queries
+    (1024, 5000),    # a window past the sequence: the causal mask
+])
+def test_sliding_window_mask_matches_the_dense_mask(S, W):
+    """The window-masked kernels (past one tile a grid over the list of
+    the band's live tiles, masked from iotas; 4 query heads on 2
+    key/value heads): forward and the three gradients against composed
+    attention under the band built densely from its definition;
+    ``mha_reference`` takes the same description. A window of the whole
+    sequence or more is the causal call, kernel for kernel."""
+    from apex_tpu.ops.flash_attention import FILL, SlidingWindowMask
+
+    mask = SlidingWindowMask(S, W)
+    H, Hkv, D, scale = 4, 2, 32, 32 ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(S + W), 4)
+    q = jax.random.normal(ks[0], (1, H, S, D))
+    k = jax.random.normal(ks[1], (1, Hkv, S, D))
+    v = jax.random.normal(ks[2], (1, Hkv, S, D))
+    go = jax.random.normal(ks[3], q.shape)
+    dense = jnp.asarray(_dense_window(S, W))
+    np.testing.assert_array_equal(
+        np.asarray(mask.visible(np.arange(S)[:, None],
+                                np.arange(S)[None, :])), np.asarray(dense))
+
+    def composed(q, k, v):
+        kk, vv = (jnp.repeat(t, H // Hkv, axis=1) for t in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kk) * scale
+        p = jax.nn.softmax(jnp.where(dense, s, FILL), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vv)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, None, False, scale, score_mask=mask)
+
+    def scalar(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * go)
+
+    want = composed(q, k, v)
+    assert _max_err(kernel(q, k, v), want) < 2e-6
+    assert _max_err(mha_reference(q, k, v, None, False, scale,
+                                  score_mask=mask), want) < 2e-6
+    got = jax.grad(scalar(kernel), (0, 1, 2))(q, k, v)
+    ref = jax.grad(scalar(composed), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, ref):
+        assert _max_err(a, b) < 1e-5, name
+    if W >= S:
+        def causal(q, k, v):
+            return flash_attention(q, k, v, None, True, scale)
+
+        assert _max_err(kernel(q, k, v), causal(q, k, v)) < 1e-6
+        for name, a, b in zip(("dq", "dk", "dv"), got, jax.grad(
+                scalar(causal), (0, 1, 2))(q, k, v)):
+            assert _max_err(a, b) < 1e-6, name
+
+
+@pytest.mark.parametrize("S,W,bq,bk,expected", [
+    (8192, 2048, 512, 512, (186, 28, 42)),   # 70 live of 256
+    (8192, 8192, 512, 512, (120, 16, 120)),  # the causal mask's classes
+    (1024, 300, 512, 512, None),
+    (640, 200, 384, 384, None),              # padding past S not counted
+    (192, 50, 64, 32, None),                 # bq != bk
+    (96, 7, 32, 64, None),
+])
+def test_sliding_window_tile_classes_and_tables(S, W, bq, bk, expected):
+    """``tile_classes`` under the description (a closed form over the
+    band) against a classification read off the dense mask; the grid is
+    the live tiles alone, every row and column of tiles has one, and a
+    window of the whole sequence classifies as ``causal=True`` does."""
+    from apex_tpu.ops.flash_attention import (SlidingWindowMask,
+                                              _mask_tables, grid_steps,
+                                              tile_classes)
+
+    mask = SlidingWindowMask(S, W)
+    nq, nk = -(-S // bq), -(-S // bk)
+    dense = np.zeros((nq * bq, nk * bk), bool)
+    dense[:S, :S] = _dense_window(S, W)
+    real = np.zeros_like(dense)
+    real[:S, :S] = True
+    tiles = dense.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    real = real.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    live = tiles.any(axis=(2, 3))
+    full = (tiles | ~real).all(axis=(2, 3)) & live
+    brute = (int((~live).sum()), int((live & ~full).sum()), int(full.sum()))
+    assert tile_classes(S, S, bq, bk, score_mask=mask) == brute
+    assert expected in (None, brute)
+    assert grid_steps(S, S, bq, bk, score_mask=mask) == live.sum()
+    q_major, k_major = _mask_tables(mask, bq, bk)
+    np.testing.assert_array_equal(np.stack([q_major.iq, q_major.ik]),
+                                  np.stack(np.nonzero(live)))
+    np.testing.assert_array_equal(np.stack([k_major.ik, k_major.iq]),
+                                  np.stack(np.nonzero(live.T)))
+    if W >= S and bq == bk:
+        assert brute == tile_classes(S, S, bq, bk, causal=True)
+    if expected is not None and W < S:
+        assert grid_steps(S, S, bq, bk, score_mask=mask) == 70
+    with pytest.raises(ValueError, match="positive"):
+        SlidingWindowMask(S, 0)
+    with pytest.raises(ValueError, match="queries"):
+        flash_attention(*_mk(1, 1, 64, 64, 32), None, False, 1.0,
+                        score_mask=SlidingWindowMask(128, W))
+
+
 # ------------------------------ the multi-tile forward's row sub-blocks
 
 def _forward_kernel_jaxpr(jaxpr):

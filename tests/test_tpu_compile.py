@@ -735,3 +735,74 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
     print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
           f"{live / 2 ** 30:.2f} GiB")
     assert live <= SDAR_LIVE_BYTES < 15 * 2 ** 30 < _hbm_bytes()
+
+
+# -- the seventh cell's step: window and global layers, gated experts ---------
+
+# what the ``afmoe`` step may hold live at 2 x 8,192 tokens a chip with every
+# layer's matmul outputs, flash residuals, routing and expert rows kept
+# (14.63 GiB by this compile; PERF.md section 4)
+TRINITY_LIVE_BYTES = int(15.0 * 2 ** 30)
+
+
+def test_trinity_step_runs_no_flash_call_and_no_grouped_matmul_twice(
+        one_chip, compiled_kernels):
+    """The whole train step of ``trinity_mini.lm8192`` (five layers at the
+    published widths, amp O2 + FusedAdam through ``build_train_step``, as
+    the cell builds it) compiled from shapes for one described chip: a
+    window layer makes ONE window-masked flash call a pass (forward once,
+    ``dq``, ``dkv``: the layer keeps ``o`` and ``lse``), each on a grid of
+    (2 rows, 32 heads, the band's 70 live tiles of 256) under four
+    prefetched lists; the global layer makes the causal calls; a sparse
+    layer six grouped-matmul calls; none of them and no sort on a
+    recomputed path; under the chip's memory."""
+    from apex_tpu.ops.flash_attention import SlidingWindowMask, grid_steps
+
+    cell = "trinity_mini.lm8192"
+    _, reference, config, built, paths, live = _cell_step(cell, one_chip)
+    assert built.n_params == 504_147_712
+    called = r'custom_call_target="tpu_custom_call"'
+    kernels = paths(called)
+    bodies = paths(called, want=r'\\?"body\\?":\s*\\?"([A-Za-z0-9+/=]+)')
+    assert len(bodies) == len(kernels)
+    grids = {p: _mosaic_grid(body) for p, body in zip(kernels, bodies)
+             if "/window_attention/" in p}
+    S, W, heads = 8192, config["sliding_window"], config["num_attention_heads"]
+    live_tiles = grid_steps(S, S, 512, 512,
+                            score_mask=SlidingWindowMask(S, W))
+    assert live_tiles == 70
+    kinds = reference.kinds(config)
+    for i, (layer_type, (_, ffn)) in enumerate(zip(config["layer_types"],
+                                                   kinds)):
+        flash = [p for p in kernels if f"/layers_{i}/self_attn/" in p
+                 and "/flash_" in p]
+        names = sorted(p.rsplit("/", 2)[-2] for p in flash)
+        if layer_type == "sliding_attention":
+            assert all("/window_attention/" in p for p in flash)
+            assert names == ["flash_window_bwd_dkv", "flash_window_bwd_dq",
+                             "flash_window_fwd"], flash
+            assert [grids[p] for p in flash] == [
+                ((2, heads, live_tiles), 4)] * 3, [grids[p] for p in flash]
+        else:
+            assert all("/global_attention/" in p for p in flash)
+            assert names == ["flash_bwd_dkv", "flash_bwd_dq",
+                             "flash_fwd"], flash
+        mine = [p for p in kernels if f"/layers_{i}/moe/" in p]
+        assert len(mine) == (6 if ffn == "moe" else 0), mine
+        assert sum("jit(tgmm)" in p for p in mine) == len(mine) // 3
+        assert all("/moe_experts/" in p for p in mine)
+    assert not any("rematted_computation" in p for p in kernels)
+    # the rest: the RMSNorm backward of four norms a layer and the last
+    norms = [p for p in kernels if "layer_norm_bwd" in p]
+    assert len(norms) == 4 * len(kinds) + 1
+    sparse = sum(ffn == "moe" for _, ffn in kinds)
+    assert len(kernels) == 3 * len(kinds) + 6 * sparse + len(norms), [
+        p for p in kernels if "/flash_" not in p and "gmm" not in p
+        and "layer_norm_bwd" not in p]
+    sorts = paths(r" sort\(")
+    assert not [p for p in sorts if "rematted_computation" in p], sorts
+    sorts = [p for p in sorts if "/experts/" in p]
+    assert len(sorts) == 5 * sparse, sorts
+    print(f"{cell}: tpu_custom_call x{len(kernels)}, live "
+          f"{live / 2 ** 30:.2f} GiB")
+    assert live <= TRINITY_LIVE_BYTES < 15.5 * 2 ** 30 < _hbm_bytes()

@@ -233,7 +233,8 @@ def test_every_train_path_kernel_has_a_stable_name(module):
     assert calls and len(names) + len(kinds) == calls
     assert bool(kinds) == (module == "flash_attention")
     names += [f"flash_{k}" for k in kinds]
-    names += [f"flash_blockdiff_{k}" for k in kinds]
+    names += [f"flash_{tag}_{k}" for k in kinds
+              for tag in ("blockdiff", "window")]
     assert set(names) <= set(profiler.KERNEL_NAMES)
 
 
