@@ -13,7 +13,8 @@ TPU design notes:
   online-softmax recurrence. Score tiles live only in VMEM; HBM traffic is
   O(S*D) instead of O(S^2). A step takes its query rows in sub-blocks of
   ``_FWD_ROWS`` (128), each its own q k^T, softmax and p v, so that the
-  scheduler can run one sub-block's softmax beside another's matmuls.
+  scheduler can run one sub-block's softmax beside another's matmuls; the
+  dq kernel's step likewise, in sub-blocks of ``_DQ_ROWS`` (256).
 - The padding mask is a per-key boolean (True = masked), folded in with
   the same finite ``-30000`` fill the reference kernels use (finite so
   fully-masked rows degrade to a uniform distribution instead of NaN,
@@ -470,8 +471,8 @@ def _on_live_tile(causal, iq, ik, bq, bk, body):
 
 def _rows(shape, iq, bq, r0):
     """Absolute query index along axis 0 of ``shape``: the rows from
-    ``r0`` on of query block ``iq`` (no add of a zero ``r0``: the backward
-    kernels, whose blocks are whole, keep their program)."""
+    ``r0`` on of query block ``iq`` (no add of a zero ``r0``: a kernel
+    that takes its block whole keeps its program)."""
     return jax.lax.broadcasted_iota(jnp.int32, shape, 0) + (
         iq * bq + r0 if r0 else iq * bq)
 
@@ -531,7 +532,7 @@ def _score_tile(q, k, mask_ref, iq, ik, *, scale, causal, bq, bk, has_mask,
     """fp32 (rows, bk) masked scores of tile (iq, ik), and the key-mask row
     (None when the call has neither a user mask nor key padding). ``q``
     holds the rows from ``r0`` on of query block ``iq``: all ``bq`` of
-    them, or one of the forward's row sub-blocks.
+    them, or one of the forward's or dq's row sub-blocks.
 
     mask codes: 0 = live, 1 = user-masked (finite FILL — a fully-masked
     row degrades to uniform over the TRUE keys), 2 = wrapper padding
@@ -684,7 +685,13 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, causal,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                    *rest, scale, causal, bq, bk, has_mask=True,
                    dropout_rate=0.0, native_prng=True, score_mask=None,
-                   tiles=None):
+                   tiles=None, rows):
+    """Multi-tile dq: one grid step is one live (bq, bk) score tile, whose
+    ``ds k`` is added into its query rows' dq. The step's query rows go
+    in sub-blocks of ``rows`` (``_DQ_ROWS``), each its own q k^T,
+    softmax, do v^T, ds and ds k, sharing only the tile's k, v and keep
+    mask: a row of dq reads nothing of another row, so every row's
+    arithmetic is the unsplit step's, bit for bit."""
     if dropout_rate > 0.0:
         drop_ref, dq_ref, dq_s = rest
     else:
@@ -697,29 +704,37 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dq_s[:] = jnp.zeros_like(dq_s)
 
     def _tile():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        prec = _prec(q.dtype)
-        s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
-                              causal=causal, bq=bq, bk=bk, has_mask=has_mask,
-                              score_mask=score_mask)
+        prec = _prec(q_ref.dtype)
+        keep = None
+        for r0 in range(0, bq, rows):
+            r = slice(r0, r0 + rows)
+            q = q_ref[0, 0, r]                     # (rows, D)
+            k = k_ref[0, 0]                        # (bk, D)
+            s, mrow = _score_tile(q, k, mask_ref, iq, ik, scale=scale,
+                                  causal=causal, bq=bq, bk=bk,
+                                  has_mask=has_mask, score_mask=score_mask,
+                                  r0=r0)
 
-        lse = lse_ref[0, 0, 0][:, None]            # (bq, 1)
-        p = _zero_padded_keys(jnp.exp(s - lse), mrow)     # (bq, bk)
-        do = do_ref[0, 0]                          # (bq, D)
-        v = v_ref[0, 0]                            # (bk, D)
-        dp = _dot(do, v, ((1,), (1,)), prec)
-        if dropout_rate > 0.0:
-            # replay the forward's exact keep-mask onto dp (dP = mask/keep
-            # * dO·V); delta already carries the dropout through O
-            tid = _tile_id(b, hh, iq, ik, pl.num_programs(1),
-                           pl.num_programs(2), nk)
-            keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate,
-                              native_prng)
-            dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout_rate))
-        delta = delta_ref[0, 0, 0][:, None]        # (bq, 1)
-        ds = p * (dp - delta) * scale              # (bq, bk)
-        dq_s[:] = dq_s[:] + _dot(ds.astype(k.dtype), k, ((1,), (0,)), prec)
+            lse = lse_ref[0, 0, 0, r][:, None]     # (rows, 1)
+            p = _zero_padded_keys(jnp.exp(s - lse), mrow)  # (rows, bk)
+            do = do_ref[0, 0, r]                   # (rows, D)
+            v = v_ref[0, 0]                        # (bk, D)
+            dp = _dot(do, v, ((1,), (1,)), prec)
+            if dropout_rate > 0.0:
+                # replay the forward's exact keep-mask onto dp (dP =
+                # mask/keep * dO·V); delta already carries the dropout
+                # through O. Drawn whole, once, and sliced by rows.
+                if keep is None:
+                    tid = _tile_id(b, hh, iq, ik, pl.num_programs(1),
+                                   pl.num_programs(2), nk)
+                    keep = _keep_mask(drop_ref, tid, bq, bk, dropout_rate,
+                                      native_prng)
+                dp = jnp.where(keep[r], dp, 0.0) * (
+                    1.0 / (1.0 - dropout_rate))
+            delta = delta_ref[0, 0, 0, r][:, None]  # (rows, 1)
+            ds = p * (dp - delta) * scale          # (rows, bk)
+            dq_s[r] = dq_s[r] + _dot(ds.astype(k.dtype), k, ((1,), (0,)),
+                                     prec)
 
     _on_live_tile(causal, iq, ik, bq, bk, _tile)
 
@@ -934,10 +949,22 @@ def _kernel_name(kind, score_mask):
         f"flash_{score_mask.tag}_{kind}")
 
 
-# Query rows of one sub-block of the multi-tile forward's tile step
-# (``_fwd_kernel``): on the v5e 128 rows beat the whole 512-row block by
-# 9-16% a call and 256, 64 and 32 rows at head sizes 64 and 128 (PR 39).
+# Query rows of one sub-block of a multi-tile kernel's tile step, on the
+# v5e at head sizes 64 and 128 (calls alone, ``PERF.md`` section 6 and
+# ``docs/kernels.md``): the forward's (``_fwd_kernel``) 128 beat the whole
+# 512-row block by 9-16% a call and 256, 64 and 32 rows; dq's
+# (``_bwd_dq_kernel``) 256 beat it by 0.1-3.5% at every shape the cells
+# send, where 128 lost 1.4-2.0% in the window and causal calls at 8,192
+# and 64 lost 14-27%. The dk / dv kernel takes its step whole: sub-blocks
+# of its key rows cost 19-33% a call at 256 and 52-86% at 128.
 _FWD_ROWS = 128
+_DQ_ROWS = 256
+
+
+def _sub_block(block, rows):
+    """Rows of one sub-block of a tile step over ``block`` rows: ``rows``
+    where it divides the block, else the whole block (not split)."""
+    return rows if block % rows == 0 else block
 
 
 def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
@@ -983,7 +1010,7 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
                                bk=bk, has_mask=has_mask,
                                dropout_rate=dropout_rate, native_prng=native,
                                score_mask=score_mask,
-                               rows=_FWD_ROWS if bq % _FWD_ROWS == 0 else bq)
+                               rows=_sub_block(bq, _FWD_ROWS))
     tiles, steps = (), (Sq // bq, Sk // bk)
     listed = score_mask is not None
     if listed:
@@ -1070,7 +1097,8 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
     kern = dict(scale=scale, causal=causal, bq=bq, bk=bk, has_mask=has_mask,
                 dropout_rate=dropout_rate, native_prng=native,
                 score_mask=score_mask)
-    dq_kernel = functools.partial(_bwd_dq_kernel, **kern)
+    dq_kernel = functools.partial(_bwd_dq_kernel, **kern,
+                                  rows=_sub_block(bq, _DQ_ROWS))
     dkv_kernel = functools.partial(_bwd_dkv_kernel, **kern)
     dq_tiles, dkv_tiles = (), ()
     dq_steps, dkv_steps = (nq, nk), (nk, nq)

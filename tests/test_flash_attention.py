@@ -994,20 +994,21 @@ def test_sliding_window_tile_classes_and_tables(S, W, bq, bk, expected):
                         score_mask=SlidingWindowMask(128, W))
 
 
-# ------------------------------ the multi-tile forward's row sub-blocks
+# ----------------------------------- the multi-tile kernels' sub-blocks
 
-def _forward_kernel_jaxpr(jaxpr):
-    """The jaxpr of the first forward kernel (``flash_fwd`` or
-    ``flash_<tag>_fwd``) in a jaxpr, nested ones searched too."""
+def _kernel_jaxprs(jaxpr, found=None):
+    """``{kind: jaxpr}`` of the flash kernels in a jaxpr, nested ones
+    searched too: the first ``flash_<kind>`` or ``flash_<tag>_<kind>`` of
+    each kind (``fwd``, ``bwd_dq``, ``bwd_dkv``; ``bwd`` single-tile)."""
+    found = {} if found is None else found
     for eqn in jaxpr.eqns:
-        if (eqn.primitive.name == "pallas_call"
-                and eqn.params["name"].endswith("_fwd")):
-            return eqn.params["jaxpr"]
+        if eqn.primitive.name == "pallas_call":
+            kind = next(k for k in ("bwd_dkv", "bwd_dq", "fwd", "bwd")
+                        if eqn.params["name"].endswith("_" + k))
+            found.setdefault(kind, eqn.params["jaxpr"])
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found = _forward_kernel_jaxpr(sub)
-            if found is not None:
-                return found
-    return None
+            _kernel_jaxprs(sub, found)
+    return found
 
 
 def _ops_in_order(jaxpr, names):
@@ -1023,14 +1024,15 @@ def _ops_in_order(jaxpr, names):
 
 
 def _split_rows(monkeypatch, rows):
-    """Make the multi-tile forward split a tile step's query rows into
-    sub-blocks of ``rows`` (all of them where ``rows`` does not divide the
-    block): patched, not an option of the program. Returns a jit that is
-    traced anew under the patch."""
+    """Make the multi-tile forward and dq kernels split a tile step's query
+    rows into sub-blocks of ``rows`` (all of them where ``rows`` does not
+    divide the block): patched, not an option of the program. Returns a
+    jit that is traced anew under the patch."""
     import importlib
 
     mod = importlib.import_module("apex_tpu.ops.flash_attention")
     monkeypatch.setattr(mod, "_FWD_ROWS", rows)
+    monkeypatch.setattr(mod, "_DQ_ROWS", rows)
     return lambda f: jax.jit(lambda *args: f(*args))
 
 
@@ -1055,6 +1057,12 @@ _SPLIT_CASES = {
     "blockdiff": dict(L=512, g=4, clean_queries=True, dtype=jnp.bfloat16),
     "blockdiff_noised": dict(L=512, g=4, clean_queries=False,
                              dtype=jnp.float32),
+    # the sliding window: a band of live tiles, the element mask by row
+    # offset
+    "window": dict(S=1536, W=512, dtype=jnp.bfloat16),
+    # one tile: the single-tile kernels, which are never split
+    "single_tile": dict(Sq=512, Sk=512, causal=True, rate=0.1,
+                        key_mask=True, dtype=jnp.float32),
 }
 
 
@@ -1063,12 +1071,16 @@ def _split_case(name):
     forward's output and every gradient (and lse where the entry has
     one)."""
     from apex_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                              SlidingWindowMask,
                                               flash_attention_with_lse)
 
     c = _SPLIT_CASES[name]
     H, D, dtype = 2, 64, c["dtype"]
     if "L" in c:
         mask = BlockDiffusionMask(c["L"], c["g"], c["clean_queries"])
+        Sq, Sk, Hkv = mask.q_len, mask.k_len, 1
+    elif "W" in c:
+        mask = SlidingWindowMask(c["S"], c["W"])
         Sq, Sk, Hkv = mask.q_len, mask.k_len, 1
     else:
         mask, Sq, Sk, Hkv = None, c["Sq"], c["Sk"], c.get("Hkv", H)
@@ -1098,23 +1110,39 @@ def _split_case(name):
 @pytest.mark.parametrize("name", sorted(_SPLIT_CASES))
 def test_forward_row_sub_blocks_are_bit_identical_to_one_block(
         monkeypatch, name, rows):
-    """A tile step's query rows in sub-blocks of 256 or 128 give the
-    output, lse and the three gradients of the unsplit step (sub-blocks of
-    all ``bq`` rows: the parent's kernel) in every bit: the running
-    statistics are per row, so no row's arithmetic moves. Each forward
-    kernel is checked to hold the split it was asked for (two matmuls a
-    sub-block)."""
+    """The forward's and dq's tile steps with their query rows in
+    sub-blocks of 256 or 128 give the output, lse and the three gradients
+    of the unsplit steps (sub-blocks of all ``bq`` rows: the kernels
+    before the split) in every bit: the forward's statistics and a row of
+    dq are per row. Each kernel holds the split it was asked for - two
+    matmuls a sub-block in the forward, three in dq, dkv's four whole (its
+    split measured slower on the v5e: PERF.md section 6), a single-tile
+    call never split - and a sub-block of the whole block traces to the
+    unsplit kernel, text for text."""
     from apex_tpu.ops.flash_attention import _block_sizes
 
     args, f = _split_case(name)
+    Sq, Sk = args[0].shape[2], args[1].shape[2]
+    bq, bk = _block_sizes(Sq, Sk)
     parent = _split_rows(monkeypatch, 1 << 20)(f)(*args)
-    whole = _forward_kernel_jaxpr(_traced(f, *args))
+    whole = _kernel_jaxprs(_traced(f, *args))
+    _split_rows(monkeypatch, bq)
+    assert ({k: str(j) for k, j in _kernel_jaxprs(_traced(f, *args)).items()}
+            == {k: str(j) for k, j in whole.items()})
     split = _split_rows(monkeypatch, rows)(f)(*args)
-    parts = _forward_kernel_jaxpr(_traced(f, *args))
-    bq, _ = _block_sizes(args[0].shape[2], args[1].shape[2])
-    assert _ops_in_order(whole, {"dot_general"}).count("dot_general") == 2
-    assert _ops_in_order(parts, {"dot_general"}).count("dot_general") == (
-        2 * (bq // rows if bq % rows == 0 else 1))
+    parts = _kernel_jaxprs(_traced(f, *args))
+
+    def matmuls(kernels):
+        return {kind: _ops_in_order(j, {"dot_general"}).count("dot_general")
+                for kind, j in kernels.items()}
+
+    if Sq <= bq and Sk <= bk:
+        assert matmuls(whole) == matmuls(parts) == {"fwd": 2, "bwd": 5}
+    else:
+        n = bq // rows if bq % rows == 0 else 1
+        assert matmuls(whole) == {"fwd": 2, "bwd_dq": 3, "bwd_dkv": 4}
+        assert matmuls(parts) == {"fwd": 2 * n, "bwd_dq": 3 * n,
+                                  "bwd_dkv": 4}
     for i, (a, b) in enumerate(zip(split, parent)):
         assert a.dtype == b.dtype, i
         np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
@@ -1125,12 +1153,16 @@ def test_forward_row_sub_blocks_are_bit_identical_to_one_block(
 def test_each_sub_block_is_its_own_chain_of_matmul_softmax_matmul(
         monkeypatch):
     """What the tile step's program gives the scheduler: four sub-blocks
-    of 128 rows in a 512-row tile, each its own q k^T, softmax (its two
-    ``exp``) and p v, sharing no value with another - the chains it may
-    interleave. (Issuing the next sub-block's q k^T before this one's
-    softmax measured slower on the v5e: PERF.md section 6, PR 39.)"""
+    of 128 rows in a 512-row tile, each its own chain of matmuls and
+    softmax (its ``exp``), sharing no value with another - the chains it
+    may interleave: q k^T, softmax, p v in the forward; q k^T, softmax,
+    do v^T, ds k in dq. (Issuing the forward's next q k^T before this
+    sub-block's softmax measured slower on the v5e: docs/kernels.md.)"""
     args, f = _split_case("causal_dropout_mask")
     _split_rows(monkeypatch, 128)
-    kernel = _forward_kernel_jaxpr(_traced(f, *args))
-    got = _ops_in_order(kernel, {"dot_general", "exp"})
-    assert got == ["dot_general", "exp", "exp", "dot_general"] * 4, got
+    kernels = _kernel_jaxprs(_traced(f, *args))
+    mm, exp = "dot_general", "exp"
+    for kind, chain in (("fwd", [mm, exp, exp, mm]),
+                        ("bwd_dq", [mm, exp, mm, mm])):
+        got = _ops_in_order(kernels[kind], {mm, exp})
+        assert got == chain * 4, (kind, got)

@@ -680,8 +680,8 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
     row, 32 heads, the mask's live tiles: 288 of 1,024, and 152 of 512 in
     the last layer, whose queries are the noised copy alone) under four
     prefetched lists, the forward's tile step in four sub-blocks of 128
-    query rows; no causal flash kernel is in the step; under the chip's
-    memory."""
+    query rows and ``dq``'s in two of 256; no causal flash kernel is in the
+    step; under the chip's memory."""
     from apex_tpu.ops.flash_attention import BlockDiffusionMask, grid_steps
 
     cell = "sdar_30b_a3b_chat.bd8192"
@@ -716,10 +716,10 @@ def test_sdar_step_runs_no_flash_call_and_no_grouped_matmul_twice(
             "flash_blockdiff_fwd"], flash
         assert [grids[p] for p in flash] == [
             ((1, heads, live_tiles[i]), 4)] * 3, [grids[p] for p in flash]
-        # the forward's tile step in four sub-blocks of 128 query rows,
-        # two matmuls each (PR 39); dq's three and dkv's four as before
+        # the forward's tile step in four sub-blocks of 128 query rows, two
+        # matmuls each; dq's in two of 256, three each; dkv's four whole
         assert sorted((p.rsplit("/", 2)[-2], matmuls[p]) for p in flash) == [
-            ("flash_blockdiff_bwd_dkv", 4), ("flash_blockdiff_bwd_dq", 3),
+            ("flash_blockdiff_bwd_dkv", 4), ("flash_blockdiff_bwd_dq", 6),
             ("flash_blockdiff_fwd", 8)], flash
     assert not any("rematted_computation" in p for p in kernels)
     assert not [p for p in kernels
