@@ -69,6 +69,11 @@ TPU design notes:
 - Head dim and sequence lengths are padded to the 128-lane tile in the
   wrapper; padded keys are masked, padded query rows are sliced away (and
   receive zero cotangents in backward).
+- v may be narrower than q and k (latent attention: scores over heads of
+  192, values of 128): v, o, do and dv are blocked at v's own head size
+  and q, k, dq and dk at theirs, with no padding of v to the wider one;
+  such a call's kernels are ``flash_mla_fwd``, ``flash_mla_bwd_dq`` and
+  ``flash_mla_bwd_dkv`` (``flash_mla_bwd`` at one tile).
 
 On non-TPU backends the kernels run under ``interpret=True`` (same code
 path, CPU-sim testable); a pure-jnp reference is used under shard_map vma
@@ -942,9 +947,13 @@ def _index_maps(causal, bq, bk, nq, kv_head, k_major=False, listed=False):
         bits=lambda b, h, *step: (b, h, q_block(*step), k_block(*step)))
 
 
-def _kernel_name(kind, score_mask):
+def _kernel_name(kind, score_mask, q, v):
     """``flash_<kind>``, or ``flash_<tag>_<kind>`` for a ``score_mask``
-    call: a trace tells the two apart by name."""
+    call, with ``mla_`` before ``<kind>`` where v's blocks are not as
+    wide as q's and k's (latent attention; the widths after the wrapper's
+    padding to 64 lanes): a trace tells the calls apart by name."""
+    if q.shape[3] != v.shape[3]:
+        kind = f"mla_{kind}"
     return f"flash_{kind}" if score_mask is None else (
         f"flash_{score_mask.tag}_{kind}")
 
@@ -974,9 +983,10 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
     key-mask selects; the single-tile kernel ignores it. ``score_mask``
     (static; the lengths it was checked against are the unpadded ones)
     adds its element mask to the kernels and, past one tile, makes the
-    grid the list of its live tiles (``_mask_tables``)."""
+    grid the list of its live tiles (``_mask_tables``). q and k are at
+    head size ``D``; v, and with it the output, at its own ``Dv``."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     native = drop_in is not None and drop_in.ndim == 1
     kv_head = _kv_head(q, k)
 
@@ -992,18 +1002,18 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
             in_specs=[
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
                 _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
+                _spec4(bk, Dv, lambda b, h: (b, kv_head(h), 0, 0)),
                 pl.BlockSpec((1, 1, bk), lambda b, h: (b, 0, 0)),
             ] + extra_specs,
             out_specs=(
-                _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
+                _spec4(bq, Dv, lambda b, h: (b, h, 0, 0)),
                 pl.BlockSpec((1, 1, 1, bq), lambda b, h: (b, h, 0, 0)),
             ),
             out_shape=(
-                out_struct((B, H, Sq, D), q.dtype, q, k, v),
+                out_struct((B, H, Sq, Dv), q.dtype, q, k, v),
                 out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
             ),
-            name=_kernel_name("fwd", score_mask),
+            name=_kernel_name("fwd", score_mask, q, v),
             interpret=_interpret(),
         )(q, k, v, mask, *extra)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
@@ -1025,23 +1035,23 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
             in_specs=[
                 _spec4(bq, D, maps.q),
                 _spec4(bk, D, maps.kv),
-                _spec4(bk, D, maps.kv),
+                _spec4(bk, Dv, maps.kv),
                 pl.BlockSpec((1, 1, bk), maps.key_mask),
             ] + extra_specs,
             out_specs=(
-                _spec4(bq, D, maps.q),
+                _spec4(bq, Dv, maps.q),
                 pl.BlockSpec((1, 1, 1, bq), maps.row),
             ),
             scratch_shapes=[
-                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
             ]), tiles),
         out_shape=(
-            out_struct((B, H, Sq, D), q.dtype, q, k, v),
+            out_struct((B, H, Sq, Dv), q.dtype, q, k, v),
             out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
         ),
-        name=_kernel_name("fwd", score_mask),
+        name=_kernel_name("fwd", score_mask, q, v),
         interpret=_interpret(),
     )(*tiles, q, k, v, mask, *extra)
     return out, lse
@@ -1050,8 +1060,10 @@ def _flash_fwd_call(q, k, v, mask, *, scale, causal, bq, bk, has_mask=True,
 def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
                     has_mask=True, dropout_rate=0.0, drop_in=None,
                     score_mask=None):
+    """dq, dk at q's and k's head size ``D``; dv (and the ``do`` read) at
+    v's ``Dv``."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     native = drop_in is not None and drop_in.ndim == 1
     kv_head = _kv_head(q, k)
     # grouped keys/values: the kernels write dk, dv per QUERY head (in
@@ -1073,23 +1085,23 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             in_specs=[
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
                 _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, kv_head(h), 0, 0)),
+                _spec4(bk, Dv, lambda b, h: (b, kv_head(h), 0, 0)),
                 pl.BlockSpec((1, 1, bk), lambda b, h: (b, 0, 0)),
-                _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
+                _spec4(bq, Dv, lambda b, h: (b, h, 0, 0)),
                 pl.BlockSpec((1, 1, 1, bq), lambda b, h: (b, h, 0, 0)),
                 pl.BlockSpec((1, 1, 1, bq), lambda b, h: (b, h, 0, 0)),
             ] + extra_specs,
             out_specs=(
                 _spec4(bq, D, lambda b, h: (b, h, 0, 0)),
                 _spec4(bk, D, lambda b, h: (b, h, 0, 0)),
-                _spec4(bk, D, lambda b, h: (b, h, 0, 0)),
+                _spec4(bk, Dv, lambda b, h: (b, h, 0, 0)),
             ),
             out_shape=(
                 out_struct((B, H, Sq, D), q.dtype, q, k, v, do),
                 out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
-                out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
+                out_struct((B, H, Sk, Dv), dv_dtype, q, k, v, do),
             ),
-            name=_kernel_name("bwd", score_mask),
+            name=_kernel_name("bwd", score_mask, q, v),
             interpret=_interpret(),
         )(q, k, v, mask, do, lse, delta, *extra)
 
@@ -1115,9 +1127,9 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
         return (q, k, v, mask, do, lse, delta, *extra), [
             _spec4(bq, D, maps.q),
             _spec4(bk, D, maps.kv),
-            _spec4(bk, D, maps.kv),
+            _spec4(bk, Dv, maps.kv),
             pl.BlockSpec((1, 1, bk), maps.key_mask),
-            _spec4(bq, D, maps.q),
+            _spec4(bq, Dv, maps.q),
             pl.BlockSpec((1, 1, 1, bq), maps.row),
             pl.BlockSpec((1, 1, 1, bq), maps.row),
         ] + extra_specs
@@ -1132,7 +1144,7 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
             out_specs=_spec4(bq, D, maps.q),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]), dq_tiles),
         out_shape=out_struct((B, H, Sq, D), q.dtype, q, k, v, do),
-        name=_kernel_name("bwd_dq", score_mask),
+        name=_kernel_name("bwd_dq", score_mask, q, v),
         interpret=_interpret(),
     )(*dq_tiles, *inputs)
 
@@ -1144,16 +1156,16 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
         **_grid(dict(
             grid=(B, H, *dkv_steps),
             in_specs=in_specs,
-            out_specs=(_spec4(bk, D, maps.dkv), _spec4(bk, D, maps.dkv)),
+            out_specs=(_spec4(bk, D, maps.dkv), _spec4(bk, Dv, maps.dkv)),
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
             ]), dkv_tiles),
         out_shape=(
             out_struct((B, H, Sk, D), dk_dtype, q, k, v, do),
-            out_struct((B, H, Sk, D), dv_dtype, q, k, v, do),
+            out_struct((B, H, Sk, Dv), dv_dtype, q, k, v, do),
         ),
-        name=_kernel_name("bwd_dkv", score_mask),
+        name=_kernel_name("bwd_dkv", score_mask, q, v),
         interpret=_interpret(),
     )(*dkv_tiles, *inputs)
     return dq, dk, dv
@@ -1165,23 +1177,23 @@ def _flash_bwd_call(q, k, v, mask, do, lse, delta, *, scale, causal, bq, bk,
 
 def _pad_inputs(q, k, v, key_mask, bq, bk):
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     # Pad D to a 64 multiple, NOT the 128 lane width: Mosaic handles a
     # 64-lane minor block (verified identical outputs on-chip), while
     # padding 64->128 physically doubles q/k/v/o (+ their gradients')
     # HBM traffic AND pays a pad-copy of every operand per call — the
     # D=64-per-head flagship shape was paying both on every layer.
-    Dp = _round_up(D, 64)
+    Dp, Dvp = _round_up(D, 64), _round_up(Dv, 64)
     Sqp = _round_up(Sq, bq)
     Skp = _round_up(Sk, bk)
     if key_mask is None:
         mask = jnp.zeros((B, 1, Sk), jnp.int32)
     else:
         mask = key_mask.astype(jnp.int32)[:, None, :]
-    if (Dp, Sqp, Skp) != (D, Sq, Sk):
+    if (Dp, Dvp, Sqp, Skp) != (D, Dv, Sq, Sk):
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Sqp - Sq), (0, Dp - D)))
         k = jnp.pad(k, ((0, 0), (0, 0), (0, Skp - Sk), (0, Dp - D)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, Skp - Sk), (0, Dp - D)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, Skp - Sk), (0, Dvp - Dv)))
         # padding code 2: excluded from the softmax denominator in-kernel
         # (code 1 = user-masked keys still count toward a fully-masked
         # row's uniform fallback, matching the composed reference)
@@ -1344,7 +1356,10 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
 
     Args:
       q, k, v: ``(B, H, S, D)`` (any floating dtype; fp32 accumulation).
-        Grouped-query attention: k and v may carry fewer heads
+        v may have a head size of its own, ``(B, H, S, Dv)``, and the
+        output has it: latent attention scores at ``D = 192`` and mixes
+        values of ``Dv = 128`` (the kernels are then named
+        ``flash_mla_*``). Grouped-query attention: k and v may carry fewer heads
         ``(B, Hkv, S, D)``, ``H % Hkv == 0``; query head ``h`` reads group
         ``h // (H // Hkv)`` through the kernels' index maps (no repeated
         copy of k, v). The backward kernels write dk, dv per query head
@@ -1409,6 +1424,9 @@ def _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate=0.0,
             "(an int32 scalar; fold in the training step / layer index)")
     _check_score_mask(score_mask, q.shape[2], k.shape[2], key_mask, causal,
                       dropout_rate)
+    if k.shape[3] != q.shape[3]:
+        raise ValueError(f"flash_attention: k's head size {k.shape[3]} is "
+                         f"not q's {q.shape[3]}")
     if use_jnp_fallback(q, k, v, key_mask):
         out = mha_reference(q, k, v, key_mask, causal, scale,
                             dropout_rate, dropout_seed, score_mask)
@@ -1424,7 +1442,7 @@ def _flash_fwd(q, k, v, key_mask, causal, scale, dropout_rate=0.0,
                                has_mask=_has_mask(key_mask, Sk, bk),
                                dropout_rate=dropout_rate, drop_in=drop_in,
                                score_mask=score_mask)
-    return out[:, :, :Sq, :D], lse
+    return out[:, :, :Sq, :v.shape[3]], lse
 
 
 def _name_residuals(out, lse):
@@ -1454,18 +1472,18 @@ def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
     lse cotangent, folded into delta (d lse/d s = p, so
     ds = p * (dP - (rowsum(dO*O) - dlse)))."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(Sq, Sk)
     qp, kp, vp, mask = _pad_inputs(q, k, v, key_mask, bq, bk)
     Sqp = qp.shape[2]
-    Dp = qp.shape[3]
+    Dvp = vp.shape[3]
     drop_in = _drop_input(dropout_rate, dropout_seed, B, H,
                           Sqp, kp.shape[2])
     gp = g
     outp = out
-    if (Sqp, Dp) != (Sq, D):
-        gp = jnp.pad(g, ((0, 0), (0, 0), (0, Sqp - Sq), (0, Dp - D)))
-        outp = jnp.pad(out, ((0, 0), (0, 0), (0, Sqp - Sq), (0, Dp - D)))
+    if (Sqp, Dvp) != (Sq, Dv):
+        gp = jnp.pad(g, ((0, 0), (0, 0), (0, Sqp - Sq), (0, Dvp - Dv)))
+        outp = jnp.pad(out, ((0, 0), (0, 0), (0, Sqp - Sq), (0, Dvp - Dv)))
     # lse was computed on padded shapes in fwd, so it already covers any
     # padded query rows. delta is carried (B, H, 1, Sq) to match lse's
     # Mosaic-friendly layout (size-1 block dims must equal array dims).
@@ -1484,7 +1502,7 @@ def _kernel_bwd(causal, scale, q, k, v, key_mask, out, lse_padded, g,
     dk, dv = _sum_groups(dk, kp), _sum_groups(dv, vp)
     return (match_vma(dq[:, :, :Sq, :D].astype(q.dtype), q),
             match_vma(dk[:, :, :Sk, :D].astype(k.dtype), k),
-            match_vma(dv[:, :, :Sk, :D].astype(v.dtype), v),
+            match_vma(dv[:, :, :Sk, :Dv].astype(v.dtype), v),
             None)
 
 

@@ -77,6 +77,17 @@ window_attention    a window layer's attention: q/k/v/gate    models/afmoe.py
 global_attention    a global layer's attention: the same      models/afmoe.py
                     with no position term and causal flash
 attn_gate           the output gate ``o * sigmoid(g)``        models/afmoe.py
+mla_attention       latent attention: everything below        models/deepseek_v3.py
+mla_q               the query projection                      models/deepseek_v3.py
+mla_kv_down         the kv latent and the shared rotary key:  models/deepseek_v3.py
+                    ``[c | k_r] = x W_kva``, RMSNorm of ``c``
+mla_kv_up           the per-head keys and values from the     models/deepseek_v3.py
+                    latent: ``[k_n | v] = c W_kvb``
+mla_rope            rotary positions on ``q_r`` and ``k_r``,  models/deepseek_v3.py
+                    and q and k assembled per head (``k_r``
+                    broadcast over the heads)
+mla_core            the causal flash call (``flash_mla_*``)   models/deepseek_v3.py
+mla_out             the output projection                     models/deepseek_v3.py
 =================== ======================================== ==========================
 
 The model scopes from ``ssm_in_proj`` down sit INSIDE ``train_fwd_bwd`` (a
@@ -84,10 +95,12 @@ phase reader files their ops by that ancestor); ``attn_qk_norm`` and
 ``attn_rope`` sit inside ``gqa_attention`` (``models/lfm2.py``),
 ``blockdiff_attention`` (``models/sdar.py``) or ``window_attention`` /
 ``global_attention`` (``models/afmoe.py``, which puts ``attn_gate`` inside
-both) besides. ``models/lfm2.py``, ``models/sdar.py`` and
-``models/afmoe.py`` reuse ``lm_head`` / ``lm_loss`` and the ``moe_*``
+both) besides; the six ``mla_*`` scopes sit inside ``mla_attention``.
+``models/lfm2.py``, ``models/sdar.py``, ``models/afmoe.py`` and
+``models/deepseek_v3.py`` reuse ``lm_head`` / ``lm_loss`` and the ``moe_*``
 scopes of the expert layer they share with ``models/nemotron_h.py``
-(``models/afmoe.py`` ``moe_shared`` and ``mlp_dense`` too).
+(``models/afmoe.py`` and ``models/deepseek_v3.py`` ``moe_shared`` and
+``mlp_dense`` too).
 
 Pallas kernels carry a stable ``name=`` that says kernel and direction,
 never the caller (:data:`KERNEL_NAMES`); the name becomes the HLO
@@ -99,7 +112,10 @@ under a ``score_mask`` description carries the description's tag, so that
 a trace tells it from a causal call: ``flash_blockdiff_fwd``,
 ``flash_blockdiff_bwd``, ``flash_blockdiff_bwd_dq``,
 ``flash_blockdiff_bwd_dkv`` and ``flash_window_fwd``, ``flash_window_bwd``,
-``flash_window_bwd_dq``, ``flash_window_bwd_dkv``. The dropless
+``flash_window_bwd_dq``, ``flash_window_bwd_dkv``; a call whose v is
+narrower than q and k (latent attention) carries ``mla``:
+``flash_mla_fwd``, ``flash_mla_bwd``, ``flash_mla_bwd_dq``,
+``flash_mla_bwd_dkv``. The dropless
 expert layer runs the grouped-matmul kernels that ship with JAX
 (``jax.experimental.pallas.ops.tpu.megablox``),
 which name themselves: ``gmm`` (forward and the rows' gradient) and
@@ -221,6 +237,13 @@ DIFFUSION_LOSS = "diffusion_loss"
 WINDOW_ATTENTION = "window_attention"
 GLOBAL_ATTENTION = "global_attention"
 ATTN_GATE = "attn_gate"
+MLA_ATTENTION = "mla_attention"
+MLA_Q = "mla_q"
+MLA_KV_DOWN = "mla_kv_down"
+MLA_KV_UP = "mla_kv_up"
+MLA_ROPE = "mla_rope"
+MLA_CORE = "mla_core"
+MLA_OUT = "mla_out"
 
 STEP_SCOPES = (TRAIN_FWD_BWD, TRAIN_ACCUMULATE, TRAIN_REDUCE, TRAIN_METRICS,
                AMP_SCALE_LOSS, AMP_UNSCALE, AMP_FOUND_INF, AMP_UPDATE_SCALE,
@@ -236,7 +259,8 @@ LAYER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_OUT, MOE_ROUTER,
                 GQA_ATTENTION, ATTN_QK_NORM, ATTN_ROPE, CONV_IN_PROJ,
                 CONV_GATE, CONV_OUT_PROJ, MLP_DENSE, DIFFUSION_NOISE,
                 BLOCKDIFF_ATTENTION, DIFFUSION_LOSS, WINDOW_ATTENTION,
-                GLOBAL_ATTENTION, ATTN_GATE)
+                GLOBAL_ATTENTION, ATTN_GATE, MLA_ATTENTION, MLA_Q,
+                MLA_KV_DOWN, MLA_KV_UP, MLA_ROPE, MLA_CORE, MLA_OUT)
 SCOPES = STEP_SCOPES + OPTIMIZER_SCOPES + DDP_SCOPES + MODEL_SCOPES
 
 # -- step metrics a model reports beside its loss (``has_aux``) ----------------
@@ -257,7 +281,8 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "flash_blockdiff_bwd", "flash_blockdiff_bwd_dq",
                 "flash_blockdiff_bwd_dkv", "flash_window_fwd",
                 "flash_window_bwd", "flash_window_bwd_dq",
-                "flash_window_bwd_dkv")
+                "flash_window_bwd_dkv", "flash_mla_fwd", "flash_mla_bwd",
+                "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
 
 # kernels of a library the train path calls (named by the library)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")

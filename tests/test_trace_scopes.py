@@ -227,14 +227,16 @@ def test_every_train_path_kernel_has_a_stable_name(module):
     calls = len(re.findall(r"pl\.pallas_call\(", text))
     names = re.findall(r'^\s+name="(\w+)",$', text, re.M)
     # a flash call that may carry a mask description is named by kind and,
-    # under a description, by the description's tag as well
-    kinds = re.findall(r'^\s+name=_kernel_name\("(\w+)", score_mask\),$',
-                       text, re.M)
+    # under a description, by the description's tag as well, and by ``mla``
+    # where v's head size is not q's
+    kinds = re.findall(
+        r'^\s+name=_kernel_name\("(\w+)", score_mask, q, v\),$', text,
+        re.M)
     assert calls and len(names) + len(kinds) == calls
     assert bool(kinds) == (module == "flash_attention")
     names += [f"flash_{k}" for k in kinds]
     names += [f"flash_{tag}_{k}" for k in kinds
-              for tag in ("blockdiff", "window")]
+              for tag in ("blockdiff", "window", "mla")]
     assert set(names) <= set(profiler.KERNEL_NAMES)
 
 
